@@ -32,6 +32,8 @@ depends on the mesh path.
 
 from __future__ import annotations
 
+import logging
+import os
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -75,6 +77,8 @@ from bigslice_tpu.utils import faultinject, fileio
 # the fallback executor rather than waiting forever.
 GROUP_WAIT_SECS = 0.25
 
+_log = logging.getLogger("bigslice.meshexec")
+
 
 def _stat_add(stats, key: str, dt: float) -> None:
     """Accumulate one staging-breakdown component (stats is None on
@@ -88,7 +92,7 @@ def _stat_add(stats, key: str, dt: float) -> None:
 # (huge outputs over DCN) is workload-dependent, and an operator must be
 # able to raise the deadline without patching source.
 GATHER_WAIT_SECS = float(
-    __import__("os").environ.get("BIGSLICE_GATHER_WAIT_SECS", 120.0)
+    os.environ.get("BIGSLICE_GATHER_WAIT_SECS", 120.0)
 )
 
 # Starting group capacity for the device Cogroup lowering; the retry
@@ -220,11 +224,6 @@ class _AutoDenseRetry(Exception):
     _execute_group."""
 
 
-# The XLA runtime's exception types, matched by name: the concrete
-# class lives in jaxlib (import-version-dependent), and subclasses
-# (e.g. jax's JaxRuntimeError shim) inherit the name via the MRO walk.
-_INFRA_ERROR_TYPE_NAMES = frozenset({"XlaRuntimeError"})
-
 _INFRA_ERR_MARKERS = (
     "resource_exhausted", "out of memory", "device halted",
     "dma error", "dma failed", "dma timed out",
@@ -233,8 +232,11 @@ _INFRA_ERR_MARKERS = (
 
 
 def _is_infra_error_type(err: BaseException) -> bool:
-    return any(c.__name__ in _INFRA_ERROR_TYPE_NAMES
-               for c in type(err).__mro__)
+    """The XLA runtime's own exception class (compile and execution
+    failures of a device program both raise it)."""
+    import jax
+
+    return isinstance(err, jax.errors.JaxRuntimeError)
 
 
 def _looks_like_infra_error(e: BaseException) -> bool:
@@ -242,7 +244,7 @@ def _looks_like_infra_error(e: BaseException) -> bool:
     'machine lost' class: retryable on the host tier, unlike user-code
     errors (which re-raise identically everywhere). Mirrors the
     driver-side fatal-vs-lost classification of
-    exec/bigmachine.go:441-454. Exception TYPE first (XlaRuntimeError
+    exec/bigmachine.go:441-454. Exception TYPE first (JaxRuntimeError
     anywhere in the chain, subclasses included); the substring scan is
     the fallback for backends that stringify their runtime errors."""
     # contexts=False throughout: an infra error that was CAUGHT AND
@@ -553,8 +555,6 @@ class MeshExecutor:
                  donate_buffers: Optional[bool] = None,
                  subid_split: Optional[bool] = None,
                  staging_arena: Optional[bool] = None):
-        import os
-
         self.mesh = mesh
         self.nmesh = int(mesh.devices.size)
         # Mesh topology (parallel/meshutil.MeshTopology): 1-D flat or
@@ -664,8 +664,8 @@ class MeshExecutor:
         # probing. Default: on everywhere except real TPU hardware,
         # where large irregular scatters are the unproven primitive and
         # the bitonic sort pipeline is the measured-safe default until a
-        # Mosaic hash-table kernel lands (BASELINE.md round-5 A/B shows
-        # the CPU-mesh gap: sorts are ~40x a scatter pass there).
+        # Mosaic hash-table kernel lands (a CPU-mesh A/B showed sorts
+        # at ~40x a scatter pass there; not measured on a chip).
         if hash_aggregate is None:
             env = os.environ.get("BIGSLICE_HASH_AGGREGATE")
             if env:
@@ -1021,8 +1021,6 @@ class MeshExecutor:
     def _spill_store(self) -> store_mod.FileStore:
         with self._lock:
             if self._spill is None:
-                import os
-
                 base = os.environ.get("BIGSLICE_SPILL_DIR")
                 if not base:
                     import tempfile
@@ -1592,6 +1590,11 @@ class MeshExecutor:
                 # converts that to HostLostError → elastic, whose
                 # resize clears this set.)
                 self._spmd_probation.add(_op_base(tasks[0].name.op))
+                _log.warning(
+                    "device path of %s on SPMD probation (host tier "
+                    "until the mesh changes): %r",
+                    _op_base(tasks[0].name.op), e,
+                )
                 # The host-tier resubmission reads this group's dep
                 # outputs through the store bridge; they were likely
                 # device-only under consumer-driven gather. We are on
@@ -1619,10 +1622,13 @@ class MeshExecutor:
                 # and resubmission routes to the host fallback until
                 # probation decays. MAX_CONSECUTIVE_LOST still bounds
                 # pathological loops.
-                import time as _time
-
                 self._probation[_op_base(tasks[0].name.op)] = (
-                    _time.monotonic() + PROBATION_SECS
+                    time.monotonic() + PROBATION_SECS
+                )
+                _log.warning(
+                    "device path of %s on probation for %.0fs (host "
+                    "tier meanwhile): %r",
+                    _op_base(tasks[0].name.op), PROBATION_SECS, e,
                 )
                 for t in claimed:
                     t.mark_lost(e)
@@ -2085,6 +2091,8 @@ class MeshExecutor:
             ) or 4
             if self.multiprocess and not getattr(
                     out.counts, "is_fully_addressable", True):
+                import jax
+
                 rows, indices = self._addressable_counts(out.counts)
                 if rows:
                     hub.record_shuffle(
@@ -3480,8 +3488,8 @@ class MeshExecutor:
             import jax
 
             # Unproven primitive on real TPU hardware (see __init__
-            # rationale); everywhere else the scatter path wins by the
-            # BASELINE.md round-5 A/B.
+            # rationale); everywhere else the scatter path wins by a
+            # CPU-mesh A/B.
             self._use_hashagg = jax.default_backend() != "tpu"
         return self._use_hashagg
 
@@ -3585,7 +3593,7 @@ class MeshExecutor:
         return False
 
     def _maybe_auto_dense(self, task0: Task, inputs, wave: int) -> None:
-        """VERDICT r2 #5: a user with int32 categorical keys who does
+        """A user with int32 categorical keys who does
         not pass dense_keys= should still get the table+collective
         lowering (32-72x the sort path) when a cheap staging-time
         min/max probe shows a dense range. Wave 0 only — declaring

@@ -204,9 +204,33 @@ class Result(Slice):
             )
 
     def discard(self) -> None:
-        """Drop stored task outputs (exec/session.go Discard)."""
+        """Drop this result's own stored task outputs. What it was
+        computed from stays stored: see ``discard_graph``."""
         for t in self.tasks:
             self.session.executor.discard(t)
+
+    def discard_graph(self, keep: Sequence["Result"] = ()) -> None:
+        """Drop the stored outputs of the whole subgraph of tasks this
+        result was computed from (exec/session.go Discard), except the
+        tasks of the ``keep`` Results and everything behind them. A run
+        leaves every intermediate op group's output stored — on the
+        mesh executor, resident in HBM — until the session ends; an
+        iterative driver that reuses one base Result frees each round
+        with ``round_result.discard_graph(keep=[base])``."""
+        seen = set()
+        stack = [t for r in keep for t in r.tasks]
+        while stack:  # the kept subgraphs: visited, never discarded
+            t = stack.pop()
+            if id(t) not in seen:
+                seen.add(id(t))
+                stack.extend(p for d in t.deps for p in d.tasks)
+        stack = list(self.tasks)
+        while stack:
+            t = stack.pop()
+            if id(t) not in seen:
+                seen.add(id(t))
+                self.session.executor.discard(t)
+                stack.extend(p for d in t.deps for p in d.tasks)
 
 
 class Session:

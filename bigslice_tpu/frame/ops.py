@@ -18,12 +18,8 @@ from __future__ import annotations
 import zlib
 from typing import Callable, Dict, Optional
 
+import jax.numpy as jnp
 import numpy as np
-
-try:
-    import jax.numpy as jnp
-except ImportError:  # pragma: no cover - jax is a hard dep in practice
-    jnp = None
 
 from bigslice_tpu.slicetype import ColType
 
@@ -47,7 +43,7 @@ def fmix32(x):
 def _bits32(col):
     """Reinterpret a device column as uint32 lanes for hashing."""
     dt = np.dtype(col.dtype)
-    xp = jnp if (jnp is not None and not isinstance(col, np.ndarray)) else np
+    xp = np if isinstance(col, np.ndarray) else jnp
     if dt.kind in ("i", "u", "b"):
         return col.astype(np.uint32)
     if dt.kind == "f" or dt.name == "bfloat16":

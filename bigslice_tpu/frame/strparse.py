@@ -127,9 +127,35 @@ def _fix_nonascii(joined: bytes, lines, codes, vocab,
     codes[bad] = vocab.encode_extending(fixed)
 
 
+class ParseTiers:
+    """Rows served by each parse tier, counted for a caller that wants
+    to know (the tiers degrade silently by design: no compiler, no
+    ``Python.h``, an ambiguous buffer). Caller-owned; shards parse on
+    concurrent executor threads, hence the lock.
+
+    Tiers: ``c`` (in-process C kernel), ``c_pool`` (C kernel in the
+    parse pool's workers), ``arrow`` / ``arrow_pool`` (the numpy+Arrow
+    chain), ``python`` (the exact per-row path)."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.rows: dict = {}
+
+    def add(self, tier: str, n: int) -> None:
+        with self._lock:
+            self.rows[tier] = self.rows.get(tier, 0) + n
+
+
+def _count(tiers: Optional[ParseTiers], tier: str, n: int) -> None:
+    if tiers is not None:
+        tiers.add(tier, n)
+
+
 def domains_codes_single(lines: Sequence, vocab,
                          fallback_fn: Callable,
-                         max_rows: int = 1 << 20) -> np.ndarray:
+                         max_rows: int = 1 << 20,
+                         tiers: Optional[ParseTiers] = None
+                         ) -> np.ndarray:
     """Single-process vectorized parse+encode (see module doc).
     Inputs beyond ``max_rows`` process in slices so the joined buffer
     stays far from the Arrow int32-offset ceiling."""
@@ -139,11 +165,12 @@ def domains_codes_single(lines: Sequence, vocab,
     if n > max_rows:
         return np.concatenate([
             domains_codes_single(lines[i : i + max_rows], vocab,
-                                 fallback_fn)
+                                 fallback_fn, tiers=tiers)
             for i in range(0, n, max_rows)
         ])
 
     def slow_path():
+        _count(tiers, "python", n)
         out = np.empty(n, dtype=object)
         out[:] = [fallback_fn(u) for u in lines]
         return vocab.encode_extending(out)
@@ -154,6 +181,7 @@ def domains_codes_single(lines: Sequence, vocab,
     # ambiguity → None → Arrow → slow_path.
     native = _native_codes(lines, n, vocab, fallback_fn)
     if native is not None:
+        _count(tiers, "c", n)
         return native
 
     try:
@@ -169,6 +197,7 @@ def domains_codes_single(lines: Sequence, vocab,
         return slow_path()
     codes = _merge_codes(enc, vocab)
     _fix_nonascii(joined, lines, codes, vocab, fallback_fn)
+    _count(tiers, "arrow", n)
     return codes
 
 
@@ -322,12 +351,14 @@ def _worker_parse(args):
 
 def domains_codes(lines: Sequence, vocab,
                   fallback_fn: Optional[Callable] = None,
-                  chunk_rows: int = 1 << 14) -> np.ndarray:
+                  chunk_rows: int = 1 << 14,
+                  tiers: Optional[ParseTiers] = None) -> np.ndarray:
     """Global-vocabulary int32 codes of ``_domain(line)`` per line.
 
     Parses across the host process pool when cores allow (one joined
     buffer per chunk ships to a worker; only per-chunk UNIQUE domains
-    ship back), else the single-process vectorized path.
+    ship back), else the single-process vectorized path. ``tiers``
+    counts the rows each parse tier served.
     """
     if fallback_fn is None:
         from bigslice_tpu.models.urls import _domain as fallback_fn
@@ -335,7 +366,8 @@ def domains_codes(lines: Sequence, vocab,
     n = len(lines)
     pool = _pool() if n >= 2 * chunk_rows else None
     if pool is None:
-        return domains_codes_single(lines, vocab, fallback_fn)
+        return domains_codes_single(lines, vocab, fallback_fn,
+                                    tiers=tiers)
     chunks = [lines[i : i + chunk_rows]
               for i in range(0, n, chunk_rows)]
     jobs = [("\n/".join(ch).encode("utf-8") + b"\n/", len(ch))
@@ -346,16 +378,18 @@ def domains_codes(lines: Sequence, vocab,
                                     pool.map(_worker_parse, jobs)):
         if res is None:
             out[pos : pos + len(ch)] = domains_codes_single(
-                ch, vocab, fallback_fn
+                ch, vocab, fallback_fn, tiers=tiers
             )
         elif res[0] == "native":
             out[pos : pos + len(ch)] = _merge_native(
                 res[1], res[2], ch, vocab, fallback_fn
             )
+            _count(tiers, "c_pool", len(ch))
         else:
             _tag, indices, batch_vocab = res
             codes = _merge_codes_raw(indices, batch_vocab, vocab)
             _fix_nonascii(joined, ch, codes, vocab, fallback_fn)
             out[pos : pos + len(ch)] = codes
+            _count(tiers, "arrow_pool", len(ch))
         pos += len(ch)
     return out

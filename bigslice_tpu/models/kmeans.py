@@ -69,10 +69,20 @@ def mesh_kmeans_step(mesh, k: int, d: int):
     )
 
 
-def kmeans(sess, points: np.ndarray, k: int, iters: int = 10,
-           num_shards: int = 4, seed: int = 0):
-    """k-means through the slice API: demonstrates the iterative session
-    pattern (repeated runs over a reused Result, exec/compile.go:226-261).
+def _init_centroids(points: np.ndarray, k: int, seed: int):
+    rng = np.random.RandomState(seed)
+    return points[rng.choice(len(points), size=k, replace=False)].copy()
+
+
+def kmeans_rounds(sess, points: np.ndarray, k: int,
+                  num_shards: int = 4, seed: int = 0):
+    """k-means through the slice API, one round per ``next()``:
+    demonstrates the iterative session pattern (repeated runs over a
+    reused Result, exec/compile.go:226-261). Yields ``(centroids
+    f32[k, d], counts f32[k])`` after every round — the caller decides
+    how many to take. The points upload once, before the first round.
+    Initial centroids are
+    ``points[RandomState(seed).choice(n, size=k, replace=False)]``.
 
     Points ride as ONE [n, d] float32 vector column (the data plane's
     trailing-dim tier): the per-row assignment is a [d]×[k,d] distance
@@ -82,15 +92,12 @@ def kmeans(sess, points: np.ndarray, k: int, iters: int = 10,
     """
     import bigslice_tpu as bs
 
-    n, d = points.shape
-    rng = np.random.RandomState(seed)
-    centroids = points[rng.choice(n, size=k, replace=False)].copy()
-
+    centroids = _init_centroids(points, k, seed)
     base = sess.run(
-        bs.Const(num_shards, points.astype(np.float32))
+        bs.Const(num_shards, points.astype(np.float32, copy=False))
     )  # materialized once
 
-    for _ in range(iters):
+    while True:
         # _assign_vec/_sum_combine are module-level, and centroids ride
         # as an unbatched Map arg (data, not a trace constant): every
         # iteration reuses the same compiled assignment and reduce
@@ -100,10 +107,28 @@ def kmeans(sess, points: np.ndarray, k: int, iters: int = 10,
         # per-centroid vector sums take the sort-free scatter-table
         # lowering ([k, d] tables instead of sorting n [d]-vectors).
         summed = bs.Reduce(assigned, _sum_combine, dense_keys=k)
-        rows = sess.run(summed).rows()
-        for cid, vec, cnt in rows:
+        res = sess.run(summed)
+        counts = np.zeros(k, np.float32)
+        for cid, vec, cnt in res.rows():
+            counts[int(cid)] = cnt
             if cnt > 0:
                 centroids[int(cid)] = np.asarray(vec, np.float32) / cnt
+        # The assignment group's output is a second copy of the points
+        # in HBM: without this every round would leave one behind.
+        res.discard_graph(keep=[base])
+        yield centroids.copy(), counts
+
+
+def kmeans(sess, points: np.ndarray, k: int, iters: int = 10,
+           num_shards: int = 4, seed: int = 0):
+    """``iters`` rounds of ``kmeans_rounds``; returns the centroids."""
+    import itertools
+
+    centroids = _init_centroids(points, k, seed)
+    for centroids, _ in itertools.islice(
+        kmeans_rounds(sess, points, k, num_shards, seed), iters
+    ):
+        pass
     return centroids
 
 
@@ -129,9 +154,7 @@ def _sum_combine(a, b):
 
 def kmeans_oracle(points: np.ndarray, k: int, iters: int, seed: int = 0):
     """Reference numpy implementation for tests."""
-    n, d = points.shape
-    rng = np.random.RandomState(seed)
-    centroids = points[rng.choice(n, size=k, replace=False)].copy()
+    centroids = _init_centroids(points, k, seed)
     for _ in range(iters):
         d2 = ((points[:, None, :] - centroids[None, :, :]) ** 2).sum(-1)
         assign = d2.argmin(1)

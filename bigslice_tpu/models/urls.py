@@ -55,14 +55,16 @@ def domain_count(num_shards: int, source: Union[str, Callable]) -> bs.Slice:
 
 
 def domain_count_encoded(sess, num_shards: int,
-                         source: Union[str, Callable]
+                         source: Union[str, Callable],
+                         parse_tiers=None
                          ) -> List[Tuple[str, int]]:
     """Count URLs per domain with device-tier counting.
 
     Pass 1 (host, streaming): parse, build the vocabulary, and encode
     in one fused sweep, materializing int32 codes.
     Pass 2 (device): attach unit counts and Reduce over the codes;
-    decode at the edge.
+    decode at the edge. ``parse_tiers`` (a ``strparse.ParseTiers``)
+    counts the rows each host parse tier served.
     """
     from bigslice_tpu.frame import dictenc
 
@@ -78,7 +80,8 @@ def domain_count_encoded(sess, num_shards: int,
     # fallback (and the equivalence oracle in tests).
     def parse_encode(f):
         return (strparse.domains_codes(f.cols[0], vocab,
-                                       fallback_fn=_domain),)
+                                       fallback_fn=_domain,
+                                       tiers=parse_tiers),)
 
     corpus = sess.run(bs.MapBatches(lines, parse_encode, out=[np.int32]))
     try:

@@ -184,8 +184,8 @@ def routing_tables(K: int, nparts: int, seed: int) -> Tuple[np.ndarray, int]:
     """Static slot routing: ``slot_table[p]`` lists the keys owned by
     partition p (padded with the ``K`` sentinel), under the SAME
     hash-routing contract as the sorting shuffle (partition_ids with
-    the stock XLA path — bit-identical to the Pallas tier by the
-    mosaic gate). Returns (slot_table int32[nparts, maxc], maxc)."""
+    the stock XLA path — bit-identical to the Pallas tier, checked on
+    the chip by chip_smoke.py's kernels phase). Returns (slot_table int32[nparts, maxc], maxc)."""
     from bigslice_tpu.parallel import shuffle as shuffle_mod
 
     keys = np.arange(K, dtype=np.int32)

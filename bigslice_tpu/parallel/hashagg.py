@@ -11,7 +11,7 @@ keep the sort+segmented-scan path, which honors them exactly).
 
 Why it exists: the sort-based pipeline's roofline is the multi-operand
 stable sort — ~40x the cost of a scatter pass at the sizes the shuffle
-runs (BASELINE.md round-5 A/B). Hash aggregation replaces every sort in
+runs (a CPU-mesh A/B). Hash aggregation replaces every sort in
 the Reduce/JoinAggregate pipeline with O(rows) scatter/gather passes:
 
   map side     claim cascade + one scatter-accumulate  (was: sort)

@@ -37,20 +37,16 @@ def donation_supported() -> bool:
     if _DONATION_OK is None:
         import warnings
 
-        try:
-            import jax
-            import jax.numpy as jnp
+        import jax
+        import jax.numpy as jnp
 
-            x = jnp.zeros(8, np.int32)
-            with warnings.catch_warnings():
-                warnings.simplefilter("ignore")
-                jax.jit(
-                    lambda v: v + np.int32(1), donate_argnums=(0,)
-                )(x).block_until_ready()
-            _DONATION_OK = bool(getattr(x, "is_deleted",
-                                        lambda: False)())
-        except Exception:  # no backend / ancient jax: stay undonated
-            _DONATION_OK = False
+        x = jnp.zeros(8, np.int32)
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")
+            jax.jit(
+                lambda v: v + np.int32(1), donate_argnums=(0,)
+            )(x).block_until_ready()
+        _DONATION_OK = bool(x.is_deleted())
     return _DONATION_OK
 
 

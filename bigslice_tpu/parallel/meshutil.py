@@ -179,15 +179,12 @@ def shape_device_mesh(devices=None,
 
 
 def get_shard_map():
-    """shard_map with the pre-0.9 keyword surface (check_rep) adapted."""
+    """``jax.shard_map`` under the keyword surface the call sites use
+    (``check_rep``, which jax now spells ``check_vma``)."""
     import jax
 
-    if hasattr(jax, "shard_map"):
-        def wrap(f, mesh, in_specs, out_specs, check_rep=False):
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check_rep)
+    def wrap(f, mesh, in_specs, out_specs, check_rep=False):
+        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=check_rep)
 
-        return wrap
-    from jax.experimental.shard_map import shard_map  # pragma: no cover
-
-    return shard_map
+    return wrap

@@ -18,8 +18,9 @@ normalized), matching frame/ops.py ``_bits32`` bit-for-bit — the pallas
 path and the stock-XLA path must route every key identically.
 
 On CPU (tests, virtual mesh) the kernels run in interpreter mode;
-Mosaic compiles them natively on TPU (bench.py runs a TPU-gated
-equivalence check).
+Mosaic compiles them natively on TPU (chip_smoke.py's ``kernels``
+phase checks them there against the stock-XLA paths; tests/test_aot.py
+compiles them for a described v5e).
 """
 
 from __future__ import annotations
@@ -35,6 +36,12 @@ _GOLDEN32 = 0x9E3779B9
 
 SUPPORTED_KEY_DTYPES = ("int32", "uint32", "float32")
 
+#: Stable kernel names: they reach the compiled program's text and the
+#: profiler trace, so a check (chip_smoke.py) or a trace reduction can
+#: find each kernel by name.
+HASH_PARTITION_KERNEL = "bigslice_hash_partition"
+HASH_AGGREGATE_KERNEL = "bigslice_hash_aggregate"
+
 
 def _interpret() -> bool:
     import jax
@@ -48,15 +55,18 @@ def interpret_capable() -> bool:
     kernel at all (interpret mode off-TPU, Mosaic on TPU)? Probed once
     per process with a trivial kernel; tier-1 tests skip-gate on it so
     a jax build without a working pallas stack reads as SKIPPED, not
-    as a red the suite carries forever."""
+    as a red the suite carries forever. On a TPU backend a failing
+    probe RAISES: there the kernels are the product, and returning
+    False would let the kernel selector drop its Pallas core in
+    silence."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    def kernel(x_ref, o_ref):
+        o_ref[:] = x_ref[:] + jnp.int32(1)
+
     try:
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental import pallas as pl
-
-        def kernel(x_ref, o_ref):
-            o_ref[:] = x_ref[:] + jnp.int32(1)
-
         x = jnp.zeros((8, LANES), jnp.int32)
         out = pl.pallas_call(
             kernel,
@@ -65,6 +75,8 @@ def interpret_capable() -> bool:
         )(x)
         return bool(np.asarray(out)[0, 0] == 1)
     except Exception:
+        if not _interpret():
+            raise
         return False
 
 
@@ -129,7 +141,7 @@ def _build_hash_partition(nparts: int, block_rows: int, seed32: int,
         if counts_ref is not None:
             # Per-block histogram. All-pairs compare per 128-lane chunk
             # of the histogram, in 3D (block_rows, LANES, LANES) — no
-            # reshapes/relayouts, which Mosaic rejects (a (8,128)→
+            # reshapes/re-layouts, which Mosaic rejects (a (8,128)→
             # (1024,1) shape cast fails infer-vector-layout on real
             # hardware). The drop lane id == nparts never matches a
             # counted lane (counts are sliced to [:nparts]); invalid
@@ -167,6 +179,7 @@ def _build_hash_partition(nparts: int, block_rows: int, seed32: int,
             out_specs=out_specs,
             out_shape=out_shape,
             interpret=interpret,
+            name=HASH_PARTITION_KERNEL,
         )(mask2d, *keys2d)
         return out if with_counts else (out[0], None)
 
@@ -232,8 +245,8 @@ def hash_partition(keys, nparts: int, seed: int = 0,
 # key-compare -> combine cascade fused into one sequential insert pass
 # per row. The XLA path lowers the same cascade to HBM scatter rounds
 # (scatter-min claim + scatter-accumulate), which is exactly the
-# lowering that loses to the sort path on real TPU (BASELINE.md round-5
-# cost stats); here every probe touches VMEM only.
+# lowering XLA's cost analysis for a v5e target prices far above the
+# sort path (AOT_TPU.json); here every probe touches VMEM only.
 #
 # Layout: tables are (T // 128, 128) planes — slot s lives at sublane
 # s // 128, lane s % 128. Probing needs dynamic SUBLANE indexing only
@@ -320,8 +333,11 @@ def _build_hash_aggregate(nparts: int, R: int, block_rows: int,
 
         def get(ref, sub, ln):
             # Scalar gather with a dynamic sublane index + iota-masked
-            # lane select. Float payloads bitcast through int32 so the
-            # masked-sum extraction is bit-exact (-0.0, NaN).
+            # lane select. Float payloads extract through int32 so the
+            # masked sum is bit-exact (-0.0, NaN) and come back as a
+            # lane-BROADCAST (1, 128) vector, never a scalar: Mosaic's
+            # tpu.bitcast takes vectors only. ``put``/``combine``
+            # blend per lane, so they take either form.
             row = ref[pl.ds(sub, 1), :]
             f32 = _is_f32(ref.dtype)
             if f32:
@@ -330,15 +346,18 @@ def _build_hash_aggregate(nparts: int, R: int, block_rows: int,
                 row = row.astype(jnp.int32)
             v = jnp.sum(jnp.where(lane == ln, row, jnp.int32(0)))
             if f32:
-                return jax.lax.bitcast_convert_type(v, jnp.float32)
+                return jax.lax.bitcast_convert_type(
+                    jnp.full((1, LANES), v, jnp.int32), jnp.float32
+                )
             return v.astype(ref.dtype)
 
-        def put(ref, sub, ln, scalar):
+        def put(ref, sub, ln, value):
             # Read-modify-write one (1, 128) row, blending the target
-            # lane — the dynamic-lane scatter Mosaic lacks.
+            # lane — the dynamic-lane scatter Mosaic lacks. ``value``
+            # is a scalar or a lane-broadcast row (float payloads).
             row = ref[pl.ds(sub, 1), :]
             ref[pl.ds(sub, 1), :] = jnp.where(
-                lane == ln, jnp.asarray(scalar, ref.dtype), row
+                lane == ln, jnp.asarray(value, ref.dtype), row
             )
 
         def combine(op, cur, new):
@@ -428,6 +447,7 @@ def _build_hash_aggregate(nparts: int, R: int, block_rows: int,
             out_specs=out_specs,
             out_shape=out_shape,
             interpret=interpret,
+            name=HASH_AGGREGATE_KERNEL,
         )(mask2d, off2d, stride2d, base2d, *cols2d)
 
     return run

@@ -101,7 +101,7 @@ def route_to_buckets(dest, cols, ndest: int, sortless: bool,
     - SORTLESS (one-hot cumsum): a row's slot is its running count
       among same-destination rows — order-preserving, no sort; the
       CPU-mesh default (a 3-operand sort costs ~40× a linear pass
-      there, BASELINE.md round 5; see sortless_routing_default for the
+      there, a CPU-mesh A/B; see sortless_routing_default for the
       TPU gate).
     - SORT: rows reorder by destination (payload follows via the
       carried permutation); slots are arange minus bucket starts.
@@ -181,9 +181,9 @@ def sortless_routing_default() -> bool:
     of the routing sort. Default: on everywhere except real TPU
     hardware — same rationale and knob convention as the hash-aggregate
     lowering (exec/meshexec.py BIGSLICE_HASH_AGGREGATE): the ~40x
-    sort-vs-linear-pass gap is a CPU-mesh measurement (BASELINE.md
-    round 5), while on TPU the [size, ndest] one-hot cumsum multiplies
-    HBM traffic and the bitonic sort is the measured-safe default.
+    sort-vs-linear-pass gap is a CPU-mesh measurement, while on TPU
+    the [size, ndest] one-hot cumsum multiplies HBM traffic and the
+    bitonic sort is the assumed-safe default (not measured).
     Override with BIGSLICE_SORTLESS_SHUFFLE=1/0."""
     import os
 

@@ -68,7 +68,7 @@ def device_sort_default() -> bool:
     """Whether device-schema frames sort with the jitted ``lax.sort``.
     On real TPU that keeps rows on-chip and rides the fast XLA sort;
     on CPU backends the XLA sort is the measured ~40×-slow primitive
-    (BASELINE.md round 5), so frames route to the host lexsort
+    (a CPU-mesh A/B), so frames route to the host lexsort
     instead — same per-backend knob convention as the hash-aggregate
     and sortless-shuffle lowerings. Override with
     BIGSLICE_DEVICE_SORT=1/0."""

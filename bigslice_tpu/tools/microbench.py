@@ -160,19 +160,18 @@ def bench_device_reduce(n: int = 1 << 19):
 def main(argv=None) -> int:
     import os
 
-    # The wave-stress bench needs a multi-device mesh even on a CPU
-    # fallback: force 8 virtual host devices BEFORE jax initializes
-    # (no-op for real TPU backends — the flag only shapes the host
-    # platform). Keeps BASELINE.md's recorded shapes reproducible by
-    # running this module with no extra flags.
+    # The wave-stress bench needs a multi-device mesh even when the
+    # CPU is pinned: force 8 virtual host devices BEFORE jax
+    # initializes (no-op for real TPU backends — the flag only shapes
+    # the host platform).
     flag = "--xla_force_host_platform_device_count=8"
     if flag not in os.environ.get("XLA_FLAGS", ""):
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "") + " " + flag
         ).strip()
-    from bigslice_tpu.utils.hermetic import ensure_usable_backend
+    from bigslice_tpu.utils.hermetic import accelerator_or_pinned_cpu
 
-    ensure_usable_backend()
+    accelerator_or_pinned_cpu("microbench")
     argv = argv if argv is not None else sys.argv[1:]
     quick = "--quick" in argv
     scale = 4 if quick else 1

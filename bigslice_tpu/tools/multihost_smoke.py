@@ -2,8 +2,11 @@
 
 Spawns N Python processes on localhost, each a jax.distributed
 participant with its own CPU device(s); together they form one global
-mesh. The smoke run exercises, across actual process boundaries (the
-DCN shape of a TPU pod):
+mesh. This is a CPU simulation of a multi-host gang — every process
+pins the CPU platform, because a chip belongs to one process and on a
+host with chips one process drives all of them. The smoke run
+exercises, across actual process boundaries (the DCN shape of a TPU
+pod):
 
 - distributed bootstrap + Func-registry digest verification,
 - a data-parallel psum step (mesh k-means),

@@ -28,10 +28,13 @@ builds a Session over the global mesh with the SPMD dispatch contract
 (exec/spmd.py). Driver-only side effects (writing result files,
 printing) belong under ``spmd.is_coordinator()``.
 
-**Off-platform / simulation**: `-launch N` starts N local processes of
+**Simulation on the CPU**: `-launch N` starts N local processes of
 the identical command wired together over a loopback coordinator —
-the single-host stand-in for a pod launch (on CPU each process
-contributes its own devices to the global mesh):
+the single-host stand-in for a pod launch (each process contributes
+its own virtual CPU devices to the global mesh). It requires
+``JAX_PLATFORMS=cpu``: a chip belongs to one process, so on a host
+with chips one process drives all of them and N would fight over
+them:
 
     JAX_PLATFORMS=cpu python -m bigslice_tpu.tools.run -launch 2 \\
         program.py
@@ -50,6 +53,10 @@ import subprocess
 import sys
 
 from bigslice_tpu import sliceconfig
+from bigslice_tpu.utils.hermetic import (
+    configure_compile_cache,
+    is_cpu_pinned,
+)
 
 
 def current_session():
@@ -70,6 +77,13 @@ def launch(n: int, argv) -> int:
     when the whole gang succeeded, else the first failure's (with
     signal deaths shell-normalized to 128+signum so they can't read
     as success)."""
+    if not is_cpu_pinned():
+        # A chip belongs to one process: N local processes can only
+        # ever be the CPU simulation of a gang. On a host with chips
+        # ONE process drives all of them (-spmd without -launch).
+        print("-launch N simulates a multi-host gang on the CPU: set "
+              "JAX_PLATFORMS=cpu", file=sys.stderr)
+        return 2
     port = _free_port()
     procs = [
         subprocess.Popen(
@@ -121,6 +135,7 @@ def main(argv=None):
     n, argv = _extract_launch(argv)
     if n is not None:
         return launch(n, argv)
+    configure_compile_cache()
     sess, rest = sliceconfig.parse(argv)
     if not rest:
         print("usage: python -m bigslice_tpu.tools.run [flags] "

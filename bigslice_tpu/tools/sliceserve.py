@@ -143,6 +143,9 @@ def main(argv=None) -> int:
                     help="live status lines on stderr")
     args = ap.parse_args(argv)
 
+    from bigslice_tpu.utils.hermetic import configure_compile_cache
+
+    configure_compile_cache()
     server = build_server(
         port=args.port, slots=args.slots, queue=args.queue,
         tenant_quota=args.tenant_quota,

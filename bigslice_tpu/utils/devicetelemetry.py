@@ -35,6 +35,7 @@ under ``/debug/metrics``, and its instant events as the
 from __future__ import annotations
 
 import hashlib
+import logging
 import threading
 import time
 from typing import Dict, List, Optional
@@ -134,9 +135,10 @@ class _InstrumentedProgram:
     compiles on first call per input signature (recording wall time +
     cost/memory analysis into the recorder), reuses the held executable
     after (recording cache hits). Any AOT-path surprise — an argument
-    aval/sharding the baked executable rejects, an ancient jax without
-    the AOT API — permanently falls back to the plain jitted callable
-    for this wrapper (correctness never depends on instrumentation).
+    aval/sharding the baked executable rejects, a lowering quirk —
+    permanently falls back to the plain jitted callable for this
+    wrapper, with one WARNING (correctness never depends on
+    instrumentation).
 
     When the program carries a cross-session digest (``serve_key`` —
     the serving plane's content-fingerprinted identity from
@@ -197,15 +199,17 @@ class _InstrumentedProgram:
                     # probe must fall back, never crash the wave.
                     sig = tuple(_arg_signature(a) for a in args)
                     compiled = self._compiled.get(sig)
-                except Exception:
+                except Exception as e:
                     compiled = None
-                    self._fall_back_locked()
+                    self._fall_back_locked(e)
                 if compiled is None and not self._fell_back:
                     if len(self._compiled) >= MAX_SIGNATURES:
                         # Signature churn the executor's cache key
                         # should have prevented: stop holding
                         # executables, keep running.
-                        self._fall_back_locked()
+                        self._fall_back_locked(
+                            f"more than {MAX_SIGNATURES} signatures"
+                        )
                     else:
                         compiled = self._serve_probe(sig)
                         if compiled is not None:
@@ -227,12 +231,12 @@ class _InstrumentedProgram:
             return self._fn(*args)
         try:
             return compiled(*args)
-        except (TypeError, ValueError):
+        except (TypeError, ValueError) as e:
             # Baked-executable argument rejection (aval/sharding/layout
             # mismatch our signature missed) — raised before execution,
             # args intact: run the flexible jit path instead, for good.
             with self._lock:
-                self._fall_back_locked()
+                self._fall_back_locked(e)
             return self._fn(*args)
 
     def _serve_probe(self, sig):
@@ -257,9 +261,10 @@ class _InstrumentedProgram:
         t0 = time.perf_counter()
         try:
             compiled = self._fn.lower(*args).compile()
-        except Exception:
-            # No AOT API / lowering quirk: plain jit from here on.
-            self._fall_back_locked()
+        except Exception as e:
+            # Lowering quirk: plain jit from here on (a genuine compile
+            # error raises again there, into the executor's ladder).
+            self._fall_back_locked(e)
             return None
         wall = time.perf_counter() - t0
         self._rec.record_compile(
@@ -279,13 +284,20 @@ class _InstrumentedProgram:
                 pass
         return compiled
 
-    def _fall_back_locked(self) -> None:
+    def _fall_back_locked(self, why) -> None:
         """Permanently route this wrapper to the plain jit, releasing
         every held executable (a fallen-back wrapper must not pin AOT
         programs the jit path will recompile on its own). Signatures
         this wrapper had taken from the cross-session cache are
         invalidated there too — an executable this process just
-        rejected must not keep fanning out to future sessions."""
+        rejected must not keep fanning out to future sessions. Logged
+        once per wrapper: compiles from here on are invisible to the
+        recorder, and an operator must be able to see why."""
+        if not self._fell_back:
+            logging.getLogger("bigslice.devicetelemetry").warning(
+                "AOT seam of %s (%s) fell back to plain jit: %r",
+                self._op, self._kind, why,
+            )
         self._fell_back = True
         if self._serve_key is not None and self._cross:
             try:

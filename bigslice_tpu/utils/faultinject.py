@@ -131,7 +131,7 @@ def sites() -> Dict[str, dict]:
     return dict(SITES)
 
 
-# -- faults and injected-exception taxonomy --------------------------------
+# -- faults and injected-exception classes ---------------------------------
 
 class Fault(NamedTuple):
     site: str

@@ -1,4 +1,4 @@
-"""Automatic dense-key discovery (VERDICT r2 #5): an undeclared
+"""Automatic dense-key discovery: an undeclared
 Reduce/Fold over dense int32 keys takes the table+collective lowering
 via a staging-time min/max probe; misprobes (keys a later wave never
 showed wave 0) retract through the badrange signal and re-run on the

@@ -60,7 +60,7 @@ def test_instrumented_program_new_shape_new_compile():
     assert len(s["programs"]) == 2
 
 
-def test_instrumented_program_falls_back_without_aot_api():
+def test_instrumented_program_falls_back_without_aot_api(caplog):
     """A callable with no .lower (or any AOT surprise) must run
     correctly through the plain path — instrumentation can never be
     load-bearing."""
@@ -72,9 +72,14 @@ def test_instrumented_program_falls_back_without_aot_api():
         return x * 3
 
     prog = _InstrumentedProgram(plain, dev, "op_c", None, "group", "k")
-    assert prog(7) == 21
-    assert prog(7) == 21
+    with caplog.at_level("WARNING", logger="bigslice.devicetelemetry"):
+        assert prog(7) == 21
+        assert prog(7) == 21
     assert prog._fell_back
+    # Loud exactly once, naming the op and the error.
+    warned = [r.getMessage() for r in caplog.records]
+    assert len(warned) == 1
+    assert "op_c" in warned[0] and "lower" in warned[0]
     assert len(calls) == 2
     # The abandonment itself is recorded (the counter that keeps
     # 'compiles == 0' serving claims honest); no compiles, no hits.

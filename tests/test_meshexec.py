@@ -534,16 +534,13 @@ def test_wave_partitioned_reshuffle_roundtrip(mesh):
     assert sess.executor.device_group_count() >= 1
 
 
-def test_infra_error_probation_falls_back_then_recovers(mesh):
+def test_infra_error_probation_falls_back_then_recovers(mesh, caplog):
     """XLA-runtime failures are the 'machine lost' class (SURVEY §5.3):
     the op's tasks go LOST (not ERR), the evaluator resubmits, and the
     op's device path sits on probation so the retry runs on the host
     fallback — then re-engages the device once probation decays
     (exec/slicemachine.go probation analog)."""
-    from bigslice_tpu.exec import meshexec as mx
-
-    class XlaRuntimeError(RuntimeError):
-        pass
+    import jax
 
     ex = MeshExecutor(mesh)
     sess = Session(executor=ex)
@@ -553,7 +550,7 @@ def test_infra_error_probation_falls_back_then_recovers(mesh):
     def flaky(key, tasks):
         if fails["n"] == 0:
             fails["n"] += 1
-            raise XlaRuntimeError("device halted: injected")
+            raise jax.errors.JaxRuntimeError("INTERNAL: injected")
         return real(key, tasks)
 
     ex._execute_group = flaky
@@ -569,10 +566,16 @@ def test_infra_error_probation_falls_back_then_recovers(mesh):
         # here so probation (keyed by op) covers the retry.
         return bs.Reduce(bs.Const(8, keys, vals), add)
 
-    got = dict(sess.run(build()).rows())
+    with caplog.at_level("WARNING", logger="bigslice.meshexec"):
+        got = dict(sess.run(build()).rows())
     assert got == {i: 10 if i < 1 else (10 if i < 64 % 7 else 9)
                    for i in range(7)}
     assert fails["n"] == 1
+    # Leaving the device is loud: ONE warning naming op and error.
+    warned = [r.getMessage() for r in caplog.records
+              if "on probation" in r.getMessage()]
+    assert len(warned) == 1 and "INTERNAL: injected" in warned[0]
+    assert any(op in warned[0] for op in ex._probation)
     # The failed op retried on the host fallback and is on probation
     # (other groups in the graph may still run on device).
     assert ex._probation, "op should be on probation"
@@ -889,35 +892,34 @@ def test_daemon_pool_recycles_and_survives_exceptions():
 
 # ------------------------------------------- error classification
 
-class _FakeXlaRuntimeError(RuntimeError):
-    """Stands in for jaxlib's XlaRuntimeError: classification matches
-    by type NAME through the MRO, so a same-named class (or subclass)
-    is exactly what the real one looks like to the classifier."""
+def _runtime_error(msg: str):
+    """The XLA runtime's exception as the installed jax raises it."""
+    import jax
 
-
-_FakeXlaRuntimeError.__name__ = "XlaRuntimeError"
-
-
-class _XlaSubclass(_FakeXlaRuntimeError):
-    """A subclass keeps matching via the MRO walk (jax wraps the
-    jaxlib type in version-specific shims)."""
-
-
-_XlaSubclass.__name__ = "JaxBackendError"
+    return jax.errors.JaxRuntimeError(msg)
 
 
 def test_infra_error_classified_by_type():
     from bigslice_tpu.exec.meshexec import _looks_like_infra_error
 
-    assert _looks_like_infra_error(_FakeXlaRuntimeError("boom"))
-    assert _looks_like_infra_error(_XlaSubclass("wrapped boom"))
+    import jax
+
+    class Sub(jax.errors.JaxRuntimeError):
+        """Subclasses classify too (isinstance)."""
+
+    assert _looks_like_infra_error(_runtime_error("boom"))
+    assert _looks_like_infra_error(Sub("wrapped boom"))
+    # A class that merely shares the old jaxlib name is not it.
+    assert not _looks_like_infra_error(
+        type("XlaRuntimeError", (RuntimeError,), {})("boom")
+    )
     # ...anywhere in the failure chain, not just at the top: the new
     # seams (instrumented programs, staging retries) re-raise with
     # context.
     try:
         try:
-            raise _FakeXlaRuntimeError("device died")
-        except _FakeXlaRuntimeError as inner:
+            raise _runtime_error("device died")
+        except RuntimeError as inner:
             raise ValueError("wrapper") from inner
     except ValueError as outer:
         assert _looks_like_infra_error(outer)
@@ -986,5 +988,5 @@ def test_task_error_cause_is_walked():
     from bigslice_tpu.exec.task import TaskError, TaskName
 
     t = types.SimpleNamespace(name=TaskName(1, "op", 0, 1))
-    err = TaskError(t, _FakeXlaRuntimeError("oom"))
+    err = TaskError(t, _runtime_error("oom"))
     assert _looks_like_infra_error(err)
