@@ -32,8 +32,11 @@ depends on the mesh path.
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import logging
 import os
+import re
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -71,6 +74,8 @@ from bigslice_tpu.parallel.meshutil import (
 )
 from bigslice_tpu.parallel import shuffle as shuffle_mod
 from bigslice_tpu.utils import faultinject, fileio
+from bigslice_tpu.utils import trace as trace_mod
+from bigslice_tpu.utils.trace import span
 
 # Group-completion watchdog: if the evaluator hands us only part of an op
 # group (other shards already OK from a prior run), run the stragglers on
@@ -78,6 +83,31 @@ from bigslice_tpu.utils import faultinject, fileio
 GROUP_WAIT_SECS = 0.25
 
 _log = logging.getLogger("bigslice.meshexec")
+
+
+@functools.lru_cache(maxsize=None)
+def _program_name(kind: str, stage_kinds: Tuple[str, ...] = ()) -> str:
+    """The name a program is jitted under (``_named``) — what the
+    profiler's ``XLA Modules`` line shows as ``jit_<name>(...)`` and the
+    ``dispatch`` span carries: ``bs_<kind>`` plus, for a group, its
+    stage kinds. A pure function of the program's kind and structure on
+    purpose: JAX's persistent-cache key starts with the module's name,
+    so an op index, an invocation number or a counter in it would make
+    every process compile cold."""
+    name = "_".join(("bs", kind) + tuple(stage_kinds))
+    return re.sub(r"[^A-Za-z0-9_]", "_", name)[:64]
+
+
+def _named(fn, kind: str, stage_kinds: Tuple[str, ...] = ()):
+    """``fn`` renamed to its program name, to be jitted under it."""
+    fn.__name__ = fn.__qualname__ = _program_name(kind, stage_kinds)
+    return fn
+
+
+def _nbytes(cols, counts) -> int:
+    """Bytes of a staged wave's device arrays."""
+    return sum(int(getattr(a, "nbytes", 0) or 0)
+               for a in list(cols) + [counts])
 
 
 def _stat_add(stats, key: str, dt: float) -> None:
@@ -292,6 +322,10 @@ class DeviceGroupOutput:
         self.subid = subid
         self._chunks = None
         self._chunks_lock = threading.Lock()
+        # Bytes the host-chunk readback moved device → host (what
+        # crossed, not what was valid), until a ``readback`` span
+        # counts them.
+        self.readback_nbytes = 0
         # Per-consumer-wave device views of a subid output (the
         # one-pass subid split, _subid_wave_view): wave w's rows
         # pre-compacted so waved consumers stop re-scanning the full
@@ -350,9 +384,12 @@ class DeviceGroupOutput:
                         "(device-only by plan); host read would need "
                         "an unplanned collective gather"
                     )
+                crossed: List[int] = []
                 self._chunks = shuffle_mod.unshard_columns(
-                    self.cols, np.asarray(self.counts), self.capacity
+                    self.cols, np.asarray(self.counts), self.capacity,
+                    crossed=crossed,
                 )
+                self.readback_nbytes = sum(crossed)
             return self._chunks
 
     def drop_device(self) -> None:
@@ -1639,14 +1676,22 @@ class MeshExecutor:
     # -- the SPMD program --------------------------------------------------
 
     def _execute_group(self, key, tasks: List[Task]) -> None:
-        try:
-            self._execute_group_inner(key, tasks)
-        except _AutoDenseRetry:
-            # Deterministic across processes: the badrange signal is a
-            # collective output, so every process retracts and re-runs
-            # identically. Nothing was committed (outputs assign only
-            # on success).
-            self._execute_group_inner(key, tasks)
+        # A worker thread's first span: a child of its invocation's
+        # open ``evaluate`` span, whose thread only waits meanwhile.
+        rec = self._span_recorder()
+        name = tasks[0].name
+        with span("group", rec=rec,
+                  parent=rec and rec.adopter(name.inv_index),
+                  inv=name.inv_index, op=name.op,
+                  waves=-(-len(tasks) // self.nmesh)):
+            try:
+                self._execute_group_inner(key, tasks)
+            except _AutoDenseRetry:
+                # Deterministic across processes: the badrange signal
+                # is a collective output, so every process retracts and
+                # re-runs identically. Nothing was committed (outputs
+                # assign only on success).
+                self._execute_group_inner(key, tasks)
 
     def _execute_group_inner(self, key, tasks: List[Task]) -> None:
         task0 = tasks[0]
@@ -1662,7 +1707,8 @@ class MeshExecutor:
         # unset: the chicken-bit contract.
         plan = inputs0 = None
         if task0.num_partition > 1 or len(tasks) > self.nmesh:
-            plan, inputs0 = self._shuffle_plan(task0, wave_tasks)
+            with span("shuffle_plan"):
+                plan, inputs0 = self._shuffle_plan(task0, wave_tasks)
         if task0.num_partition > 1 and plan is not None:
             if plan.kind == "spill":
                 out = self._execute_group_spill(task0, wave_tasks,
@@ -1751,16 +1797,8 @@ class MeshExecutor:
                 else:
                     est = None
             if est is None:
-                t0 = time.perf_counter()
-                stats0: dict = {}
-                inputs0 = self._group_inputs(wave_tasks[0], 0,
-                                             stats=stats0)
-                dur = time.perf_counter() - t0
-                self._telemetry_staging(task0, 0, dur, dur, stats0)
-                wave_bytes = sum(
-                    int(getattr(a, "nbytes", 0) or 0)
-                    for i in inputs0 for a in list(i[0]) + [i[1]]
-                )
+                inputs0 = self._stage_exposed(wave_tasks[0], 0)
+                wave_bytes = sum(_nbytes(i[0], i[1]) for i in inputs0)
                 est = wave_bytes * len(wave_tasks)
         plan = shuffleplan_mod.choose(mode, est, budget, ineligible)
         return plan, inputs0
@@ -1862,6 +1900,12 @@ class MeshExecutor:
     def _telemetry_hub(self):
         sess = getattr(self, "session", None)
         return getattr(sess, "telemetry", None)
+
+    def _span_recorder(self):
+        """The session's span recorder (utils/trace.py), for the first
+        span of a thread; None without a session (the spans are then
+        profiler annotations only)."""
+        return getattr(getattr(self, "session", None), "spans", None)
 
     def _device_telemetry(self):
         return getattr(self._telemetry_hub(), "device", None)
@@ -2246,13 +2290,9 @@ class MeshExecutor:
         the store so device residency never spans waves); the return
         value is then []."""
         if inputs0 is None:
-            t0 = time.perf_counter()
-            stats0: dict = {}
-            inputs0 = self._group_inputs(wave_tasks[0], 0, stats=stats0)
-            stage0 = time.perf_counter() - t0
             # Wave 0 staging is exposed by construction (nothing
             # computes yet for prefetch to hide behind).
-            self._telemetry_staging(task0, 0, stage0, stage0, stats0)
+            inputs0 = self._stage_exposed(wave_tasks[0], 0)
         depth = self._effective_prefetch_depth(task0, inputs0,
                                                len(wave_tasks))
         if depth == 0:
@@ -2299,6 +2339,8 @@ class MeshExecutor:
         staged: "queue_mod.Queue" = queue_mod.Queue(maxsize=depth)
         stop = threading.Event()
 
+        group_span = trace_mod.current()
+
         def stage():
             for w in range(1, nwaves):
                 if stop.is_set():
@@ -2307,13 +2349,12 @@ class MeshExecutor:
                     # Read-ahead hints stay just ahead of staging (the
                     # store's warm cache is small — hinting every wave
                     # upfront would evict entries before their read).
-                    t0 = time.perf_counter()
-                    self._hint_store_prefetch(wave_tasks, w + 1,
-                                              w + 1 + depth)
-                    wstats: dict = {}
-                    item = (self._group_inputs(wave_tasks[w], w,
-                                               stats=wstats), None,
-                            time.perf_counter() - t0, wstats)
+                    inputs, dur, wstats = self._stage(
+                        wave_tasks[w], w, cause=group_span,
+                        before=lambda: self._hint_store_prefetch(
+                            wave_tasks, w + 1, w + 1 + depth),
+                    )
+                    item = (inputs, None, dur, wstats)
                     self._emit_phase(task0, PHASE_WAVE_PREFETCH, w)
                 except BaseException as e:  # noqa: BLE001 — re-raised
                     item = (None, e, 0.0, None)  # in wave order on the
@@ -2346,10 +2387,14 @@ class MeshExecutor:
         outs: List[DeviceGroupOutput] = []
         inflight: "deque" = deque()
         def settle_one():
+            # Dispatch→settle wall time: with in-flight overlap this
+            # over-counts queue time per wave, but the SUM is the true
+            # device-busy window the staging overlap hides behind.
             entry, wv, t_disp = inflight.popleft()
-            return wv, self._settle_wave(entry), t_disp
+            out = self._settle_wave(entry)
+            return wv, out, (trace_mod.now_ns() - t_disp) * 1e-9
 
-        def deliver(wv, out, t_disp):
+        def deliver(wv, out, dur):
             # OUTSIDE the wave mutex: the sink (spill readback + store
             # write) is host work that must not hold the collective
             # slot against concurrent evaluations or this pipeline's
@@ -2358,11 +2403,7 @@ class MeshExecutor:
                 sink(wv, out)
             else:
                 outs.append(out)
-            # Dispatch→settle wall time: with in-flight overlap this
-            # over-counts queue time per wave, but the SUM is the true
-            # device-busy window the staging overlap hides behind.
-            self._telemetry_compute(task0, wv,
-                                    time.perf_counter() - t_disp)
+            self._telemetry_compute(task0, wv, dur)
 
         try:
             for w in range(nwaves):
@@ -2373,17 +2414,16 @@ class MeshExecutor:
                 if w == 0:
                     inputs = inputs0
                 else:
-                    t0 = time.perf_counter()
-                    inputs, err, stage_dur, wstats = staged.get()
-                    wait = time.perf_counter() - t0
+                    with span("stage_wait", wave=w) as waited:
+                        inputs, err, stage_dur, wstats = staged.get()
                     if err is not None:
                         raise err
                     # Exposed staging: the part of the stager's work
                     # this thread actually sat waiting on. Hidden =
                     # stage_dur - exposed is the pipeline's win.
-                    self._telemetry_staging(task0, w, stage_dur,
-                                            min(wait, stage_dur),
-                                            wstats)
+                    self._telemetry_staging(
+                        task0, w, stage_dur,
+                        min(waited.seconds, stage_dur), wstats)
                 self._emit_phase(task0, PHASE_WAVE_COMPUTE, w)
                 # Wave-slot atomicity: on the CPU backend window == 0,
                 # so dispatch + settle happen inside ONE mutex hold —
@@ -2395,18 +2435,18 @@ class MeshExecutor:
                 # slot across the in-flight window isn't needed — the
                 # mutex only makes each dispatch/settle step atomic.
                 settled = []
-                with self._wave_mutex:
+                with self._wave_slot():
+                    t_disp = trace_mod.now_ns()
                     inflight.append(
                         (self._dispatch_wave(wave_tasks[w], w,
-                                             inputs), w,
-                         time.perf_counter())
+                                             inputs), w, t_disp)
                     )
                     while len(inflight) > window:
                         settled.append(settle_one())
                 for s in settled:
                     deliver(*s)
             while inflight:
-                with self._wave_mutex:
+                with self._wave_slot():
                     s = settle_one()
                 deliver(*s)
             return outs
@@ -2486,29 +2526,58 @@ class MeshExecutor:
         return (tasks, wave, inputs,
                 self._dispatch_wave_on(tasks, wave, inputs))
 
+    @contextlib.contextmanager
+    def _wave_slot(self):
+        """Hold the wave mutex; the wait for it is the ``mutex_wait``
+        span."""
+        with span("mutex_wait"):
+            self._wave_mutex.acquire()
+        try:
+            yield
+        finally:
+            self._wave_mutex.release()
+
+    def _stage(self, tasks: List[Task], wave: int, cause=None,
+               before=None):
+        """Stage one wave's inputs under its ``stage`` span, on
+        whichever thread calls: ``(inputs, seconds, breakdown)``. On the
+        prefetch thread ``cause`` is the group's span, which this one
+        runs beside, and ``before`` issues the read-ahead hints first."""
+        stats: dict = {}
+        with span("stage", rec=self._span_recorder(), cause=cause,
+                  wave=wave) as staged:
+            if before is not None:
+                before()
+            inputs = self._group_inputs(tasks, wave, stats=stats)
+        return inputs, staged.seconds, stats
+
+    def _stage_exposed(self, tasks: List[Task], wave: int):
+        """Stage a wave inline on the compute thread: all of it is
+        exposed (``stage_wait``), nothing overlaps it."""
+        with span("stage_wait", wave=wave):
+            inputs, dur, stats = self._stage(tasks, wave)
+        self._telemetry_staging(tasks[0], wave, dur, dur, stats)
+        return inputs
+
     def _settle_wave(self, entry) -> DeviceGroupOutput:
         tasks, wave, inputs, disp = entry
         if tasks is None:  # settled at dispatch (budget split)
             return disp
         return self._execute_wave_on(
             tasks, wave, inputs, first=disp,
-            restage=lambda: self._group_inputs(tasks, wave),
+            restage=lambda: self._stage(tasks, wave)[0],
         )
 
     def _execute_wave(self, tasks: List[Task], wave: int,
                       inputs=None) -> DeviceGroupOutput:
         task0 = tasks[0]
         if inputs is None:
-            t0 = time.perf_counter()
-            wstats: dict = {}
-            inputs = self._group_inputs(tasks, wave, stats=wstats)
-            dur = time.perf_counter() - t0
             # Serial staging: fully exposed (nothing overlapped it).
-            self._telemetry_staging(task0, wave, dur, dur, wstats)
-        t_run = time.perf_counter()
+            inputs = self._stage_exposed(tasks, wave)
+        t_run = trace_mod.now_ns()
         # One wave slot: probe + (split) dispatch + signal sync are
         # atomic against concurrent evaluations on this executor.
-        with self._wave_mutex:
+        with self._wave_slot():
             self._maybe_auto_dense(task0, inputs, wave)
             budget, adaptive_budget = self._wave_budget(task0)
             out = None
@@ -2534,10 +2603,10 @@ class MeshExecutor:
             if out is None:
                 out = self._execute_wave_on(
                     tasks, wave, inputs,
-                    restage=lambda: self._group_inputs(tasks, wave),
+                    restage=lambda: self._stage(tasks, wave)[0],
                 )
         self._telemetry_compute(task0, wave,
-                                time.perf_counter() - t_run)
+                                (trace_mod.now_ns() - t_run) * 1e-9)
         return out
 
     def _splittable_chain(self, task0: Task) -> bool:
@@ -2652,7 +2721,7 @@ class MeshExecutor:
             return subn.reshape(1), sub
 
         prog = jax.jit(shard_map(
-            stepped, mesh=self.mesh,
+            _named(stepped, "rowslice"), mesh=self.mesh,
             in_specs=(P(), P(axis)) + tuple(P(axis) for _ in range(ncols)),
             out_specs=(P(axis), tuple(P(axis) for _ in range(ncols))),
             check_rep=False,
@@ -2717,20 +2786,27 @@ class MeshExecutor:
         pipeline settles signals later (_execute_wave_on with
         ``first=``); serial and retry paths keep their blocking loop."""
         task0 = tasks[0]
-        caps, counts_list, cols_flat, subids, donate = (
-            self._wave_arrays(inputs)
-        )
-        slack = self._wave_slack(task0)
-        program, stages = self._program(task0, caps, slack,
-                                        subids=subids, donate=donate)
-        extras = [
-            np.asarray(a)
-            for kind, _, s in stages if kind == "map"
-            for a in s.args
-        ]
-        raw = program(np.int32(wave), *counts_list, *cols_flat, *extras)
-        if any(k == "shuffle" for k, _, _ in stages):
-            self._telemetry_exchange(task0, wave, inputs, slack)
+        with span("dispatch", wave=wave) as sp:
+            caps, counts_list, cols_flat, subids, donate = (
+                self._wave_arrays(inputs)
+            )
+            slack = self._wave_slack(task0)
+            program, stages = self._program(task0, caps, slack,
+                                            subids=subids,
+                                            donate=donate)
+            sp.set(program=_program_name(
+                "group", tuple(k for k, _, _ in stages)))
+            extras = [
+                np.asarray(a)
+                for kind, _, s in stages if kind == "map"
+                for a in s.args
+            ]
+            raw = program(np.int32(wave), *counts_list, *cols_flat,
+                          *extras)
+            if any(k == "shuffle" for k, _, _ in stages):
+                # Every dispatched attempt (first run and slack retries
+                # alike) put its buckets on the wire.
+                self._telemetry_exchange(task0, wave, inputs, slack)
         return raw, stages, slack
 
     @staticmethod
@@ -2778,51 +2854,41 @@ class MeshExecutor:
     def _execute_wave_on_locked(self, tasks, wave, inputs, first,
                                 restage, task0, out_subid, ndest
                                 ) -> DeviceGroupOutput:
+        from bigslice_tpu.ops.cogroup import Cogroup as _Cogroup
+
+        is_cogroup = isinstance(task0.chain[-1], _Cogroup)
         while True:
-            if first is not None:
-                # Settling a pipeline-dispatched attempt: sync ITS
-                # signals first; the loop below only re-runs on retry.
-                (out_counts, overflow, badrange, gbover, hashov,
-                 out_cols), stages, slack = first
-                first = None
-            else:
+            if first is None:
                 if restage is not None and self._inputs_consumed(inputs):
                     # The failed attempt donated (and so consumed) the
                     # staged buffers: re-stage before retrying.
                     inputs = restage()
-                caps, counts_list, cols_flat, subids, donate = (
-                    self._wave_arrays(inputs)
-                )
-                slack = self._wave_slack(task0)
-                program, stages = self._program(task0, caps, slack,
-                                                subids=subids,
-                                                donate=donate)
-                extras = [
-                    np.asarray(a)
-                    for kind, _, s in stages if kind == "map"
-                    for a in s.args
-                ]
-                (out_counts, overflow, badrange, gbover, hashov,
-                 out_cols) = program(
-                    np.int32(wave), *counts_list, *cols_flat, *extras
-                )
-                if any(k == "shuffle" for k, _, _ in stages):
-                    # Every dispatched attempt (first run and slack
-                    # retries alike) put its buckets on the wire.
-                    self._telemetry_exchange(task0, wave, inputs,
-                                             slack)
+                first = self._dispatch_wave_on(tasks, wave, inputs)
+            # Sync THIS attempt's signals (a pipeline-dispatched one on
+            # the first pass); the loop only re-runs on retry.
+            (out_counts, overflow, badrange, gbover, hashov,
+             out_cols), stages, slack = first
+            first = None
             has_shuffle = any(k == "shuffle" for k, _, _ in stages)
-            if int(np.asarray(gbover)) > 0:
+            with span("settle", wave=wave):
+                # The first read waits for the wave's program; this is
+                # where the host is blocked on the device.
+                gbover = int(np.asarray(gbover))
+                badrange = int(np.asarray(badrange))
+                hashov = int(np.asarray(hashov))
+                overflow = (int(np.asarray(overflow))
+                            if has_shuffle or is_cogroup else 0)
+            if gbover > 0:
                 # Checked BEFORE badrange: a strict capacity overflow
                 # must never trigger the auto-dense retraction path.
                 raise ValueError(
                     f"groupbykey: group(s) exceed the declared "
-                    f"capacity by up to {int(np.asarray(gbover))} "
+                    f"capacity by up to {gbover} "
                     f"rows in group {task0.name.op} "
                     f"(on_overflow='error'); raise capacity or use "
                     f"Cogroup for discovered capacities"
                 )
-            if int(np.asarray(badrange)) > 0:
+            if badrange > 0:
                 auto = self._declared_auto(task0)
                 if auto is not None:
                     # Our probe was wrong (a later wave holds keys wave
@@ -2848,21 +2914,16 @@ class MeshExecutor:
                     f"declared dense_keys range, in group "
                     f"{task0.name.op}"
                 )
-            from bigslice_tpu.ops.cogroup import Cogroup as _Cogroup
-
-            if (isinstance(task0.chain[-1], _Cogroup)
-                    and int(np.asarray(overflow)) > 0):
+            if is_cogroup and overflow > 0:
                 # Cogroup capacity deficit (collective pmax — identical
                 # on every process): grow to the observed max group
                 # size and recompile. The failed attempt IS the
                 # segmented-count probe; one retry converges.
                 base = _op_base(task0.name.op)
                 cur = self._cogroup_caps.get(base, COGROUP_DEFAULT_CAP)
-                self._cogroup_caps[base] = bucket_size(
-                    cur + int(np.asarray(overflow))
-                )
+                self._cogroup_caps[base] = bucket_size(cur + overflow)
                 continue
-            if int(np.asarray(hashov)) > 0:
+            if hashov > 0:
                 # Hash-aggregate claim cascade failed (load factor ~1 /
                 # adversarial keys): the result is discarded and the op
                 # permanently rebuilds on the sort path, which handles
@@ -2870,7 +2931,7 @@ class MeshExecutor:
                 # the hash lowering ignores.
                 self._hash_off.add(_op_base(task0.name.op))
                 continue
-            if not has_shuffle or int(np.asarray(overflow)) == 0:
+            if not has_shuffle or overflow == 0:
                 break
             # slack == ndest makes overflow impossible (a source can
             # send at most `capacity` rows to one destination lane).
@@ -2917,6 +2978,11 @@ class MeshExecutor:
         holds at most one row per key before any consumer reads it."""
         if len(outs) == 1:
             return outs[0]
+        with span("merge", waves=len(outs)):
+            return self._merge_waves(outs, task0)
+
+    def _merge_waves(self, outs: List[DeviceGroupOutput],
+                     task0: Task) -> DeviceGroupOutput:
         # Wave-partitioned outputs carry a leading subid column beyond
         # the schema; merge whatever columns the outputs actually have.
         ncols = len(outs[0].cols)
@@ -2982,7 +3048,7 @@ class MeshExecutor:
             col = P(axis)
             prog = jit_maybe_donate(
                 shard_map(
-                    stepped, mesh=self.mesh,
+                    _named(stepped, "merge"), mesh=self.mesh,
                     in_specs=tuple(col for _ in range(W))
                     + tuple(col for _ in range(W * ncols)),
                     out_specs=(col, tuple(col for _ in range(ncols))),
@@ -3096,7 +3162,8 @@ class MeshExecutor:
             return sel.sum(0).astype(np.int32)  # [W] per device
 
         prog = jax.jit(shard_map(
-            body, mesh=self.mesh, in_specs=(P(axis), P(axis)),
+            _named(body, "subid_count"), mesh=self.mesh,
+            in_specs=(P(axis), P(axis)),
             out_specs=P(axis), check_rep=False,
         ))
         prog = self._obs_program(prog, "subid_count", (W, cap),
@@ -3157,7 +3224,7 @@ class MeshExecutor:
 
         col = P(axis)
         prog = jax.jit(shard_map(
-            body, mesh=self.mesh,
+            _named(body, "subid_split"), mesh=self.mesh,
             in_specs=(col,) + tuple(col for _ in range(npay + 1)),
             out_specs=tuple(col for _ in range(W))
             + tuple(col for _ in range(W * npay)),
@@ -3188,17 +3255,17 @@ class MeshExecutor:
             # Host source: drain each shard's reader (inline — user
             # reader thread-safety is not assumed), then fast-assemble.
             schema = task0.chain[-1].schema
-            t0 = time.perf_counter()
-            with codec_mod.decode_clock() as ck:
-                shard_lists = [
-                    [f.to_host()
-                     for f in t.chain[-1].reader(t.name.shard, [])
-                     if len(f)]
-                    for t in tasks
-                ]
+            with span("read", wave=wave) as reading:
+                with codec_mod.decode_clock() as ck:
+                    shard_lists = [
+                        [f.to_host()
+                         for f in t.chain[-1].reader(t.name.shard, [])
+                         if len(f)]
+                        for t in tasks
+                    ]
+                reading.charge("decode", ck.seconds)
             _stat_add(stats, "decode_s", ck.seconds)
-            _stat_add(stats, "read_s",
-                      time.perf_counter() - t0 - ck.seconds)
+            _stat_add(stats, "read_s", reading.seconds - ck.seconds)
             return [self._stage_upload(shard_lists, schema, stats)]
         return [self._dep_input(tasks, i, wave, stats)
                 for i in range(len(task0.deps))]
@@ -3329,16 +3396,19 @@ class MeshExecutor:
                         raise DepLost(p) from e
             return frames, ck.seconds
 
-        t0 = time.perf_counter()
-        results = staging_mod.map_shards(read_shard, tasks,
-                                         self.stage_threads)
-        elapsed = time.perf_counter() - t0
-        # Per-worker decode clocks sum CPU-ish time across overlapped
-        # pool threads; cap at the wall elapsed so the breakdown stays
-        # in wall-clock units (components never exceed the stage).
-        decode_s = min(sum(r[1] for r in results), elapsed)
+        with span("read", wave=wave) as reading:
+            results = staging_mod.map_shards(read_shard, tasks,
+                                             self.stage_threads)
+            # Per-worker decode clocks sum CPU-ish time across
+            # overlapped pool threads; cap at the wall elapsed so the
+            # breakdown stays in wall-clock units (components never
+            # exceed the stage).
+            decode_s = min(sum(r[1] for r in results),
+                           (trace_mod.now_ns() - reading.t0) * 1e-9)
+            reading.charge("decode", decode_s)
         _stat_add(stats, "decode_s", decode_s)
-        _stat_add(stats, "read_s", max(0.0, elapsed - decode_s))
+        _stat_add(stats, "read_s",
+                  max(0.0, reading.seconds - decode_s))
         schema = tasks[0].deps[dep_idx].tasks[0].schema
         return self._stage_upload([r[0] for r in results], schema,
                                   stats)
@@ -3364,49 +3434,69 @@ class MeshExecutor:
                 self.staging_arena.mode = staging_mod.staging_mode(
                     self.mesh
                 )
-            t0 = time.perf_counter()
             try:
                 # retry_transient: a transient staging failure (chaos
                 # seam or a real flaky host) re-runs the assembly —
                 # both calls fail at entry or are functional over
                 # their inputs, so a retry is side-effect-safe.
-                host_cols, counts, capacity, bufs = fileio.retry_transient(
-                    lambda: staging_mod.assemble(
-                        shard_lists, schema, self.nmesh,
-                        self.staging_arena,
-                    ),
-                    "staging.assemble",
-                )
+                with span("assemble") as assembling:
+                    host_cols, counts, capacity, bufs = (
+                        fileio.retry_transient(
+                            lambda: staging_mod.assemble(
+                                shard_lists, schema, self.nmesh,
+                                self.staging_arena,
+                            ),
+                            "staging.assemble",
+                        ))
             except staging_mod.StagingFallback:
                 pass
             else:
-                _stat_add(stats, "assemble_s",
-                          time.perf_counter() - t0)
-                t1 = time.perf_counter()
-                cols, counts_arr = fileio.retry_transient(
-                    lambda: shuffle_mod.place_global_columns(
-                        self.mesh, host_cols, counts
-                    ),
-                    "shuffle.upload",
-                )
-                if self.staging_arena.mode == "recycle":
-                    # The transfer detaches from the host buffers
-                    # (probed): settle it, then recycle the arena slots
-                    # for the next wave (donated waves recycle the same
-                    # way — donation consumes the DEVICE buffers, the
-                    # host slot is ours). In zerocopy mode the device
-                    # arrays own the buffers for life and nothing
-                    # blocks here.
-                    import jax
+                _stat_add(stats, "assemble_s", assembling.seconds)
+                with span("upload") as uploading:
+                    cols, counts_arr = fileio.retry_transient(
+                        lambda: shuffle_mod.place_global_columns(
+                            self.mesh, host_cols, counts
+                        ),
+                        "shuffle.upload",
+                    )
+                    if self.staging_arena.mode == "recycle":
+                        # The transfer detaches from the host buffers
+                        # (probed): settle it, then recycle the arena
+                        # slots for the next wave (donated waves
+                        # recycle the same way — donation consumes the
+                        # DEVICE buffers, the host slot is ours). In
+                        # zerocopy mode the device arrays own the
+                        # buffers for life and nothing blocks here.
+                        import jax
 
-                    jax.block_until_ready(list(cols) + [counts_arr])
-                    self.staging_arena.release(bufs)
-                _stat_add(stats, "upload_s", time.perf_counter() - t1)
+                        jax.block_until_ready(list(cols) + [counts_arr])
+                        self.staging_arena.release(bufs)
+                    uploading.set(bytes=_nbytes(cols, counts_arr))
+                _stat_add(stats, "upload_s", uploading.seconds)
                 # owned=True: placed for this wave alone — nothing else
                 # holds them, so the wave program may donate them.
                 return cols, counts_arr, capacity, False, True
         # Legacy path: concat per shard, pad, per-column placement.
-        t0 = time.perf_counter()
+        with span("assemble") as assembling:
+            per_shard_cols, counts, capacity = (
+                self._assemble_legacy(shard_lists, schema))
+        _stat_add(stats, "assemble_s", assembling.seconds)
+        with span("upload") as uploading:
+            cols, counts_arr = fileio.retry_transient(
+                lambda: shuffle_mod.shard_columns(
+                    self.mesh, per_shard_cols, counts, capacity
+                ),
+                "shuffle.upload",
+            )
+            uploading.set(bytes=_nbytes(cols, counts_arr))
+        _stat_add(stats, "upload_s", uploading.seconds)
+        # owned=True: these arrays were placed for this wave alone —
+        # nothing else holds them, so the wave program may donate them.
+        return cols, counts_arr, capacity, False, True
+
+    def _assemble_legacy(self, shard_lists, schema):
+        """Concat per shard and pad to the mesh: ``(per-shard columns,
+        counts, capacity)``."""
         if schema is None:
             first = next((f for fl in shard_lists for f in fl), None)
             if first is None:
@@ -3426,18 +3516,7 @@ class MeshExecutor:
             [f.cols[j] for f in frames] for j in range(ncols)
         ]
         capacity = bucket_size(max(counts + [1]))
-        _stat_add(stats, "assemble_s", time.perf_counter() - t0)
-        t1 = time.perf_counter()
-        cols, counts_arr = fileio.retry_transient(
-            lambda: shuffle_mod.shard_columns(
-                self.mesh, per_shard_cols, counts, capacity
-            ),
-            "shuffle.upload",
-        )
-        _stat_add(stats, "upload_s", time.perf_counter() - t1)
-        # owned=True: these arrays were placed for this wave alone —
-        # nothing else holds them, so the wave program may donate them.
-        return cols, counts_arr, capacity, False, True
+        return per_shard_cols, counts, capacity
 
     # -- automatic dense-key discovery ---------------------------------
 
@@ -3670,7 +3749,8 @@ class MeshExecutor:
                                   lax.pmax(kmax, axis)])
 
             prog = jax.jit(shard_map(
-                body, mesh=self.mesh, in_specs=(P(axis), P(axis)),
+                _named(body, "keyrange"), mesh=self.mesh,
+                in_specs=(P(axis), P(axis)),
                 out_specs=P(), check_rep=False,
             ))
             prog = self._obs_program(prog, "keyrange",
@@ -4364,8 +4444,11 @@ class MeshExecutor:
                     donate_argnums.extend(range(off, off + nc))
                 off += nc
         prog = jit_maybe_donate(
-            shard_map(stepped, mesh=self.mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False),
+            shard_map(
+                _named(stepped, "group",
+                       tuple(k for k, _, _ in stages)),
+                mesh=self.mesh, in_specs=in_specs,
+                out_specs=out_specs, check_rep=False),
             tuple(donate_argnums),
         )
         # Compile-telemetry seam: the op's SPMD group program, keyed by
@@ -4499,6 +4582,19 @@ class MeshExecutor:
         with self._lock:
             return name in self._task_index
 
+    def _readback(self, out: DeviceGroupOutput, task: Task):
+        """``out``'s host chunks for a store-bridge read; the one read
+        that moves them device → host is the ``readback`` span."""
+        if out._chunks is not None:
+            return out._chunks
+        with span("readback", rec=self._span_recorder(),
+                  inv=task.name.inv_index) as sp:
+            chunks = out.host_chunks()
+            with out._chunks_lock:  # counted once, by whoever moved it
+                moved, out.readback_nbytes = out.readback_nbytes, 0
+            sp.set(bytes=moved)
+        return chunks
+
     def _frames_by_name(self, name: TaskName,
                         partition: int) -> Optional[List[Frame]]:
         with self._lock:
@@ -4557,12 +4653,12 @@ class MeshExecutor:
             if partition != 0:
                 return []
             wout = out.waves[shard // out.nmesh]
-            chunks = wout.host_chunks()
+            chunks = self._readback(wout, task)
             cols = [c[shard % out.nmesh] for c in chunks]
             if not len(cols[0]):
                 return []
             return [frame_for(cols)]
-        chunks = out.host_chunks()
+        chunks = self._readback(out, task)
         if out.partitioned:
             # Post-shuffle: device p holds partition p merged over
             # sources; attribute it all to producer shard 0 so the union

@@ -21,6 +21,7 @@ from bigslice_tpu.exec import compile as compile_mod
 from bigslice_tpu.exec.evaluate import evaluate
 from bigslice_tpu.exec.task import Task, TaskState
 from bigslice_tpu.utils import metrics as metrics_mod
+from bigslice_tpu.utils import trace as trace_mod
 
 
 def _is_gang_loss(e: BaseException) -> bool:
@@ -271,11 +272,9 @@ class Session:
                  status: bool = False, eventer=None,
                  machine_combiners: bool = False,
                  debug_port: Optional[int] = None,
-                 xprof_dir: Optional[str] = None,
                  elastic: int = 0, mesh_provider=None,
                  fleet_dir: Optional[str] = None):
         from bigslice_tpu.utils import status as status_mod
-        from bigslice_tpu.utils import trace as trace_mod
 
         if executor is None:
             from bigslice_tpu.exec.local import LocalExecutor
@@ -348,6 +347,9 @@ class Session:
                 self.fleet.start()
             except Exception:  # telemetry must never break the run
                 self.fleet = None
+        # Where this session's spans go (utils/trace.span): the hub's
+        # table and, with trace_path, the tracer.
+        self.spans = trace_mod.SpanRecorder(self.telemetry, self.tracer)
         self.status = status_mod.Status()
         self.status.set_telemetry(self.telemetry)
         stats_fn = getattr(self.executor, "resource_stats", None)
@@ -373,28 +375,13 @@ class Session:
             from bigslice_tpu.utils.debughttp import DebugServer
 
             self.debug = DebugServer(self, debug_port)
-        # XLA-level profiling (SURVEY.md §5.1 mapping), now windowed
-        # and on-demand (utils/xprof.py): /debug/profile?seconds=N on
-        # the DebugServer traces a live session's next N seconds with
-        # no restart. The ``xprof_dir`` spelling (kwarg or the
-        # BIGSLICE_XPROF_DIR env var) is DEPRECATED but kept working —
-        # it now means "profile every evaluation into this dir",
-        # reimplemented through the same single-profiler gate.
+        # XLA-level profiling (SURVEY.md §5.1 mapping) is windowed and
+        # on-demand (utils/xprof.py): /debug/profile?seconds=N on the
+        # DebugServer traces a live session's next N seconds with no
+        # restart; the program's spans (utils/trace.span) are in it.
         from bigslice_tpu.utils import xprof as xprof_mod
 
-        if xprof_dir is None:
-            xprof_dir = os.environ.get("BIGSLICE_XPROF_DIR") or None
-        if xprof_dir:
-            import logging
-
-            logging.getLogger("bigslice.session").info(
-                "xprof_dir is deprecated: every evaluation will be "
-                "profiled into %s; prefer the on-demand "
-                "/debug/profile?seconds=N window (docs/observability"
-                ".md, Device plane)", xprof_dir,
-            )
-        self.xprof_dir = xprof_dir
-        self.profiler = xprof_mod.Profiler(every_run_dir=xprof_dir)
+        self.profiler = xprof_mod.Profiler()
         # Slice/callable runs draw from the SAME process-global counter
         # as Func invocations (ops/func._invocation_counter): two
         # counters would collide on index, merging distinct invocations
@@ -538,12 +525,27 @@ class Session:
                 "run: expected Func, Slice, or callable, got %s",
                 type(func).__name__,
             )
+        corr = corr or f"inv{inv_index}"
+        with trace_mod.span("session.run", rec=self.spans,
+                            inv=inv_index):
+            tasks = self._run_invocation(
+                slice_, inv_index, exclusive, corr, args, deadline,
+                deadline_s,
+            )
+        res = Result(self, slice_, tasks)
+        res.corr = corr
+        return res
+
+    def _run_invocation(self, slice_, inv_index: int, exclusive: bool,
+                        corr: str, args, deadline, deadline_s):
+        """Compile ``slice_`` and evaluate its tasks (the body of
+        ``run``, under its ``session.run`` span); returns the root
+        tasks."""
         # Invocation record for the offline trace analyzer
         # (cmd/slicetrace invocation-category events: index, caller
         # location, stringified args). Built only when something
         # consumes events; reprlib bounds the arg stringification
         # (repr(huge_list)[:64] would materialize the whole string).
-        corr = corr or f"inv{inv_index}"
         if self.eventer is not None or self.tracer is not None:
             import reprlib
 
@@ -557,15 +559,17 @@ class Session:
             )
         from bigslice_tpu.exec import shuffleplan as shuffleplan_mod
 
-        tasks = compile_mod.Compiler(
-            inv_index, machine_combiners=self.machine_combiners,
-            mesh_signature=self._mesh_signature(),
-            shuffle_mode=shuffleplan_mod.plan_mode() or "",
-            kernel_select_mode=(self.kernel_select.mode
-                                if self.kernel_select is not None
-                                else None),
-            coded=self.coded,
-        ).compile(slice_)
+        with trace_mod.span("compile_tasks") as sp:
+            tasks = compile_mod.Compiler(
+                inv_index, machine_combiners=self.machine_combiners,
+                mesh_signature=self._mesh_signature(),
+                shuffle_mode=shuffleplan_mod.plan_mode() or "",
+                kernel_select_mode=(self.kernel_select.mode
+                                    if self.kernel_select is not None
+                                    else None),
+                coded=self.coded,
+            ).compile(slice_)
+            sp.set(tasks=len(tasks))  # root tasks
         if self.debug is not None:
             self.debug.register_roots(tasks)
         # Exclusive invocations evaluate in isolation from concurrent
@@ -575,20 +579,17 @@ class Session:
             attempts = 0
             while True:
                 run_token = self._plan_run(tasks)
-                # Deprecated profile-every-evaluation mode: one active
-                # trace at a time (concurrent runs — and /debug/profile
-                # windows — skip), start/stop failures never fail the
-                # run (utils/xprof.Profiler holds the gate).
-                xprof = self.profiler.trace_run()
                 err = None
                 try:
-                    evaluate(self.executor, tasks, monitor=self.monitor,
-                             deadline=deadline)
+                    # ``adopts``: the executor's worker threads run this
+                    # invocation's groups while this thread waits; their
+                    # ``group`` spans are this span's children.
+                    with trace_mod.span("evaluate", adopts=True):
+                        evaluate(self.executor, tasks,
+                                 monitor=self.monitor, deadline=deadline)
                 except Exception as e:  # noqa: BLE001
                     err = e
                 finally:
-                    if xprof is not None:
-                        xprof.close()
                     # finish_run BEFORE the retry decision: it flushes
                     # an aborted run's parked tasks to the fallback so
                     # they settle (the recover step waits for them).
@@ -672,9 +673,7 @@ class Session:
                     self.fleet.export()
                 except Exception:
                     pass
-        res = Result(self, slice_, tasks)
-        res.corr = corr
-        return res
+        return tasks
 
     def _record_deadline(self, outcome: str, deadline_s) -> None:
         """Attribute a deadline outcome to the telemetry hub's deadline

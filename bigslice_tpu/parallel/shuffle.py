@@ -706,7 +706,9 @@ def place_global_columns(mesh, globs: Sequence[np.ndarray], counts):
     return [place(g) for g in globs], place(counts_host)
 
 
-def unshard_columns(cols: Sequence, counts, capacity: int) -> List[List[np.ndarray]]:
+def unshard_columns(cols: Sequence, counts, capacity: int,
+                    crossed: Optional[List[int]] = None
+                    ) -> List[List[np.ndarray]]:
     """Inverse of shard_columns: global padded arrays → per-shard valid
     host chunks.
 
@@ -715,13 +717,19 @@ def unshard_columns(cols: Sequence, counts, capacity: int) -> List[List[np.ndarr
     don't thrash the compile cache): combiner outputs are typically far
     smaller than their padded capacity, and on TPU the readback rides
     the host link — moving ``capacity`` rows to read ``count`` is the
-    difference between a result scan and a full-buffer download."""
+    difference between a result scan and a full-buffer download.
+
+    ``crossed``, when given, collects the bytes of every array brought
+    to the host (the bucketed prefixes, not the valid rows alone)."""
     counts = np.asarray(counts)
     nshards = len(counts)
-    return [_valid_chunks(c, counts, capacity, nshards) for c in cols]
+    return [_valid_chunks(c, counts, capacity, nshards, crossed)
+            for c in cols]
 
 
-def _valid_chunks(c, counts, capacity: int, nshards: int) -> List[np.ndarray]:
+def _valid_chunks(c, counts, capacity: int, nshards: int,
+                  crossed: Optional[List[int]] = None
+                  ) -> List[np.ndarray]:
     import jax
 
     from bigslice_tpu.parallel.jitutil import bucket_size
@@ -749,17 +757,23 @@ def _valid_chunks(c, counts, capacity: int, nshards: int) -> List[np.ndarray]:
                     continue
                 if device_slice:
                     b = min(capacity, bucket_size(k))
-                    chunks.append(np.asarray(by_row[s][:b])[:k])
+                    host = np.asarray(by_row[s][:b])
+                    chunks.append(host[:k])
                 else:
                     # .copy(): np.asarray over a CPU shard is zero-copy
                     # and a view would pin the whole capacity-row
                     # buffer in memoized chunk storage past
                     # drop_device().
-                    chunks.append(np.asarray(by_row[s])[:k].copy())
+                    host = np.asarray(by_row[s])
+                    chunks.append(host[:k].copy())
+                if crossed is not None:
+                    crossed.append(host.nbytes)
             return chunks
     # Host columns / multi-process gathers (already numpy) / unexpected
     # layouts: the plain full-copy path.
     c = np.asarray(c)
+    if crossed is not None:
+        crossed.append(c.nbytes)
     return [c[s * capacity : s * capacity + int(counts[s])]
             for s in range(nshards)]
 

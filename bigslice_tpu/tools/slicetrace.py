@@ -68,6 +68,8 @@ import re
 import sys
 from typing import Dict, List
 
+from bigslice_tpu.utils.trace import SPAN_PID
+
 # Straggler flagging threshold for the offline report — mirrors the
 # live hub's default (utils/telemetry.py DEFAULT_STRAGGLER_FACTOR).
 STRAGGLER_FACTOR = 3.0
@@ -147,6 +149,7 @@ def _print_inv(out: List[str], inv, summary: dict, tasks: List[dict],
     _print_adaptive(out, inv, telem.get("adaptive", ()))
     _print_kernels(out, inv, telem.get("kernels", ()))
     _print_coded(out, inv, telem.get("coded", ()))
+    _print_spans(out, inv, telem.get("spans", ()))
     out.append("")
 
 
@@ -474,6 +477,26 @@ def _print_kernels(out: List[str], inv, events):
                    f"{evidence}")
 
 
+def _print_spans(out: List[str], inv, events):
+    """The program's spans (utils/trace.span: ``X`` events of pid
+    "spans") by name: how often, how long in all, and how long outside
+    their children (docs/observability.md, Spans)."""
+    if not events:
+        return
+    rows: Dict[str, List[float]] = {}
+    for ev in events:
+        row = rows.setdefault(str(ev.get("name")), [0, 0.0, 0.0])
+        row[0] += 1
+        row[1] += ev.get("dur", 0.0) / 1e3
+        row[2] += ev.get("args", {}).get("self_us", 0.0) / 1e3
+    out.append(f"# inv{inv}:spans (host time by layer boundary)")
+    out.append(f"  {'span':<16} {'n':>5} {'total_ms':>10} "
+               f"{'self_ms':>10}")
+    for name, (n, total, self_ms) in rows.items():
+        out.append(f"  {name[:16]:<16} {n:>5} {total:>10.2f} "
+                   f"{self_ms:>10.2f}")
+
+
 def analyze(path: str) -> str:
     with open(path) as fp:
         doc = json.load(fp)
@@ -497,8 +520,12 @@ def analyze(path: str) -> str:
     n_tasks = n_instants = 0
     for ev in doc.get("traceEvents", []):
         if ev.get("ph") == "X":
-            n_tasks += 1
             inv = ev.get("args", {}).get("inv")
+            if ev.get("pid") == SPAN_PID:
+                telem_by_inv.setdefault(inv, {}).setdefault(
+                    "spans", []).append(ev)
+                continue
+            n_tasks += 1
             tasks_by_inv.setdefault(inv, []).append(ev)
         elif ev.get("ph") == "i":
             n_instants += 1
@@ -566,6 +593,8 @@ def _scan_rank(doc: dict):
     telem: Dict[object, Dict[str, List[dict]]] = {}
     for ev in doc.get("traceEvents", []):
         if ev.get("ph") == "X":
+            if ev.get("pid") == SPAN_PID:
+                continue  # the program's spans are not task runs
             tasks.setdefault(
                 ev.get("args", {}).get("inv"), []
             ).append(ev)

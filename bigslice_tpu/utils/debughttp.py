@@ -11,8 +11,8 @@ duration quantiles, wave overlap-efficiency gauges — for scrape-based
 production monitoring), ``/debug/device`` (the device-plane summary:
 compile/cost/memory attribution, HBM watermarks, donation
 effectiveness), and ``/debug/profile?seconds=N`` (a windowed on-demand
-``jax.profiler`` trace of the live session — the replacement for the
-session-long ``xprof_dir`` hook).
+``jax.profiler`` trace of the live session, the program's spans in
+it).
 
 The request plumbing here — threaded HTTP server, GET/POST dispatch
 through an overridable route method, in-flight tracking with a

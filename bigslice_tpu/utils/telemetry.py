@@ -248,6 +248,12 @@ class TelemetryHub:
         # Drain-timeout census (exec/evaluate._drain's wedged report).
         self._drain_timeouts = 0
         self._drain_wedged: List[dict] = []
+        # Span table (utils/trace.span): name -> [count, total ns,
+        # self ns, bytes or None]. Session-level and monotonic, not
+        # per-op, so MAX_OPS eviction never makes a window's delta
+        # negative. Its own lock: spans close on every executor thread.
+        self._spans: Dict[str, list] = {}
+        self._span_lock = threading.Lock()
         self._eventer = eventer
         # Flight recorder: every event _emit sends (wave staging/
         # compute, shuffle sizes, compile, hbm, recovery...) also lands
@@ -710,6 +716,33 @@ class TelemetryHub:
         self._emit("bigslice:waveRun", op=op, inv=inv, wave=wave,
                    ms=round(dur_s * 1e3, 3))
 
+    def record_span(self, name: str, total_ns: int, self_ns: int,
+                    nbytes: Optional[int] = None) -> None:
+        """One closed span (utils/trace.span) into the session's table."""
+        with self._span_lock:
+            row = self._spans.get(name)
+            if row is None:
+                row = self._spans[name] = [0, 0, 0, None]
+            row[0] += 1
+            row[1] += total_ns
+            row[2] += self_ns
+            if nbytes is not None:
+                row[3] = (row[3] or 0) + int(nbytes)
+
+    def span_table(self) -> Dict[str, dict]:
+        """``summary()["spans"]``: per span name ``count``, ``total_s``,
+        ``self_s`` and, where the boundary counts them, ``bytes`` —
+        sums since the session began."""
+        with self._span_lock:
+            rows = {k: list(v) for k, v in self._spans.items()}
+        out = {}
+        for name, (count, total_ns, self_ns, nbytes) in rows.items():
+            out[name] = {"count": count, "total_s": total_ns * 1e-9,
+                         "self_s": self_ns * 1e-9}
+            if nbytes is not None:
+                out[name]["bytes"] = nbytes
+        return out
+
     # -- queries ----------------------------------------------------------
 
     def skew_of_op(self, op: str) -> Optional[dict]:
@@ -884,6 +917,7 @@ class TelemetryHub:
                     "timeouts": self._drain_timeouts,
                     "wedged": list(self._drain_wedged),
                 }
+        out["spans"] = self.span_table()
         plan = faultinject.active_plan()
         if plan is not None:
             snap = plan.snapshot()

@@ -1,24 +1,14 @@
 """Windowed on-demand XLA profiling (jax.profiler plumbing).
 
-The session's original hook was all-or-nothing: ``Session(xprof_dir=)``
-wrapped EVERY evaluation in a ``jax.profiler.trace`` for the session's
-whole life — the right tool for a one-shot bench, the wrong one for a
-long-lived serving session where the interesting window is "the last
-30 seconds, now". ``Profiler`` carries both modes behind one gate:
+``Profiler.window(seconds)`` starts a trace now, holds it for the
+window, stops, and reports the trace directory + files. This is what
+``/debug/profile?seconds=N`` (utils/debughttp.py) serves: profile a
+live production session on demand, no restart, no session-long
+overhead. The program's spans (utils/trace.span) are in the trace, on
+host lines beside the device ops.
 
-- ``window(seconds)`` — start a trace now, hold it for the window,
-  stop, and report the trace directory + files. This is what
-  ``/debug/profile?seconds=N`` (utils/debughttp.py) serves: profile a
-  live production session on demand, no restart, no session-long
-  overhead.
-- ``trace_run()`` — the legacy per-evaluation context used when an
-  every-run directory is configured (the deprecated
-  ``Session(xprof_dir=...)`` spelling, kept working: it now means
-  "profile every evaluation into this dir").
-
-One gate for both: jax supports a single live profiler per process, so
-a window request while an evaluation trace is active (or vice versa)
-is skipped/rejected rather than crashing the run.
+jax supports a single live profiler per process, so a window request
+while another is live is rejected rather than crashing the run.
 """
 
 from __future__ import annotations
@@ -30,20 +20,18 @@ from typing import List, Optional
 
 
 class ProfilerBusy(RuntimeError):
-    """A profiling window was requested while another trace (window or
-    per-evaluation) is live — jax allows one profiler per process."""
+    """A profiling window was requested while another is live — jax
+    allows one profiler per process."""
 
 
 class Profiler:
-    """Session-scoped profiler gate. ``every_run_dir`` enables the
-    legacy profile-every-evaluation mode (deprecated spelling)."""
+    """Session-scoped profiler gate."""
 
     # Window clamp: long windows pin the (single) process-wide
     # profiler and grow the trace unboundedly.
     MAX_WINDOW_SECS = 120.0
 
-    def __init__(self, every_run_dir: Optional[str] = None):
-        self.every_run_dir = every_run_dir
+    def __init__(self):
         self._lock = threading.Lock()
 
     # -- on-demand window -------------------------------------------------
@@ -62,8 +50,8 @@ class Profiler:
             out_dir = tempfile.mkdtemp(prefix="bigslice-xprof-")
         if not self._lock.acquire(blocking=False):
             raise ProfilerBusy(
-                "another profiling window or evaluation trace is "
-                "already running (one jax profiler per process)"
+                "another profiling window is already running (one "
+                "jax profiler per process)"
             )
         try:
             import jax
@@ -90,46 +78,3 @@ class Profiler:
                     os.path.join(root, n), out_dir
                 ))
         return sorted(files)
-
-    # -- legacy per-evaluation mode ---------------------------------------
-
-    def trace_run(self):
-        """Context manager wrapping one evaluation in a profiler trace
-        into ``every_run_dir`` — or None when the mode is off or
-        another trace is live (concurrent runs skip; a failure to
-        start must never fail the evaluation)."""
-        if not self.every_run_dir:
-            return None
-        if not self._lock.acquire(blocking=False):
-            return None
-        try:
-            import jax
-
-            ctx = jax.profiler.trace(self.every_run_dir)
-            ctx.__enter__()
-        except Exception:
-            self._lock.release()
-            return None
-        return _RunTrace(ctx, self._lock)
-
-
-class _RunTrace:
-    """The live per-evaluation trace handle: ``close()`` is idempotent
-    and never raises (profiler teardown must not mask the run's own
-    error)."""
-
-    def __init__(self, ctx, lock):
-        self._ctx = ctx
-        self._lock = lock
-        self._closed = False
-
-    def close(self) -> None:
-        if self._closed:
-            return
-        self._closed = True
-        try:
-            self._ctx.__exit__(None, None, None)
-        except Exception:
-            pass
-        finally:
-            self._lock.release()

@@ -167,26 +167,3 @@ def test_profiler_busy_rejects_second_window(tmp_path):
         prof.window(0.1, out_dir=str(tmp_path / "b"))
     t.join()
     assert done
-
-
-def test_profiler_trace_run_legacy_mode(tmp_path):
-    """The deprecated xprof_dir spelling still produces per-evaluation
-    XPlane traces through the shared gate."""
-    import glob
-
-    import jax
-    import jax.numpy as jnp
-
-    from bigslice_tpu.utils.xprof import Profiler
-
-    d = str(tmp_path / "runs")
-    prof = Profiler(every_run_dir=d)
-    handle = prof.trace_run()
-    assert handle is not None
-    jax.block_until_ready(jnp.arange(128).sum())
-    handle.close()
-    handle.close()  # idempotent
-    assert glob.glob(d + "/**/*.xplane.pb", recursive=True)
-    # Gate released: a window can start now.
-    out = prof.window(0.05, out_dir=str(tmp_path / "w"))
-    assert out["files"]

@@ -1,0 +1,453 @@
+"""The program's one span vocabulary (utils/trace.span): self-time
+arithmetic and parent links, the clock it shares with the jax profiler,
+the spans a waved Reduce leaves on the CPU mesh and their accounting,
+stable program names, and the Chrome trace slicetrace still loads."""
+
+import glob
+import json
+import threading
+import time
+
+import numpy as np
+import pytest
+
+import jax
+from jax.sharding import Mesh
+
+import bigslice_tpu as bs
+from bigslice_tpu.exec.meshexec import MeshExecutor, _program_name
+from bigslice_tpu.exec.session import Session
+from bigslice_tpu.utils import trace as trace_mod
+from bigslice_tpu.utils.telemetry import TelemetryHub
+from bigslice_tpu.utils.trace import SpanRecorder, Tracer, span
+
+#: Every span of docs/observability.md's table.
+TABLE = ("session.run", "compile_tasks", "evaluate", "group",
+         "shuffle_plan", "stage", "read", "decode", "assemble", "upload",
+         "stage_wait", "mutex_wait", "dispatch", "settle", "merge",
+         "readback")
+WAVES = 4
+
+
+def recorder():
+    return SpanRecorder(TelemetryHub(), Tracer())
+
+
+def events(rec):
+    return {e["name"]: e for e in rec.tracer.events()}
+
+
+def mesh_session(**kw):
+    ex = MeshExecutor(Mesh(np.array(jax.devices()), ("shards",)))
+    return Session(executor=ex, **kw)
+
+
+def waved_reduce(sess, seed=0):
+    """A Reduce of WAVES waves on the 8-device mesh whose every key is
+    on every shard already, so no wave overflows its slack and retries;
+    scanned, so the result is read back."""
+    n = len(jax.devices()) * WAVES
+    keys = np.tile(np.arange(64, dtype=np.int32), n * 4)
+    vals = np.random.default_rng(seed).integers(
+        1, 9, len(keys)).astype(np.int32)
+    res = sess.run(bs.Reduce(bs.Const(n, keys, vals), lambda a, b: a + b))
+    assert sum(r[1] for r in res.rows()) == int(vals.sum())
+    return res
+
+
+# ------------------------------------------------- (i) self time, links
+
+def test_nested_and_sibling_spans_self_time_and_parents():
+    rec = recorder()
+    with span("outer", rec=rec, inv=7) as outer:
+        with span("first") as first:
+            time.sleep(0.01)
+        with span("second") as second:
+            with span("inner") as inner:
+                time.sleep(0.01)
+    assert (first.parent, second.parent, inner.parent) == \
+        (outer, outer, second)
+    assert outer.parent is None and inner.inv == 7
+    ev = events(rec)
+    assert ev["first"]["args"]["parent"] == outer.id
+    assert ev["inner"]["args"]["parent"] == second.id
+    assert "parent" not in ev["outer"]["args"]
+    assert len({e["args"]["id"] for e in ev.values()}) == 4
+    table = rec.hub.span_table()
+    dur = {k: v["total_s"] for k, v in table.items()}
+    assert table["outer"]["self_s"] == pytest.approx(
+        dur["outer"] - dur["first"] - dur["second"], abs=1e-9)
+    assert table["second"]["self_s"] == pytest.approx(
+        dur["second"] - dur["inner"], abs=1e-9)
+    assert table["inner"]["self_s"] == dur["inner"] >= 0.01
+    assert {v["count"] for v in table.values()} == {1}
+    assert trace_mod.current() is None
+
+
+def test_adopted_children_leave_their_union_not_their_sum():
+    """Two overlapping ``group`` children on other threads: ``evaluate``
+    keeps what neither covers."""
+    rec = recorder()
+    bounds = {}
+    a_open, b_inside = threading.Event(), threading.Event()
+
+    def group(name):
+        if name == "b":
+            assert a_open.wait(10)
+        with span("group", rec=rec, parent=rec.adopter(3), op=name) as g:
+            if name == "a":
+                a_open.set()
+                assert b_inside.wait(10)   # b sleeps 30 ms inside a
+            else:
+                time.sleep(0.03)
+                b_inside.set()
+                time.sleep(0.01)
+        bounds[name] = (g.t0, g.t1)
+        assert g.parent is evaluating and g.inv == 3
+
+    with span("evaluate", rec=rec, adopts=True, inv=3) as evaluating:
+        assert rec.adopter(3) is evaluating
+        threads = [threading.Thread(target=group, args=(n,))
+                   for n in "ab"]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=10)
+            assert not t.is_alive()
+    assert rec.adopter(3) is None
+    table = rec.hub.span_table()
+    union = (max(b[1] for b in bounds.values())
+             - min(b[0] for b in bounds.values())) * 1e-9
+    total = table["group"]["total_s"]
+    assert total >= union + 0.03           # they did overlap
+    assert table["evaluate"]["self_s"] == pytest.approx(
+        table["evaluate"]["total_s"] - union, abs=1e-9)
+    assert table["group"]["self_s"] == total
+
+
+def test_a_stage_beside_its_group_names_it_and_is_subtracted_from_nobody():
+    rec = recorder()
+    staged = {}
+
+    def prefetch(cause):
+        with span("stage", rec=rec, cause=cause, wave=1) as s:
+            with span("upload") as up:
+                time.sleep(0.02)
+                up.set(bytes=4096)
+        staged["stage"] = s
+
+    with span("group", rec=rec, inv=5) as group:
+        t = threading.Thread(target=prefetch, args=(group,))
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive()
+    s = staged["stage"]
+    assert s.parent is None and s.cause is group and s.inv == 5
+    ev = events(rec)
+    assert ev["stage"]["args"]["cause"] == group.id
+    assert "parent" not in ev["stage"]["args"]
+    assert ev["upload"]["args"]["parent"] == s.id
+    table = rec.hub.span_table()
+    assert table["group"]["self_s"] == table["group"]["total_s"] >= 0.02
+    assert table["upload"]["bytes"] == 4096
+    assert "bytes" not in table["stage"]
+
+
+def test_a_charged_child_counts_in_the_table_and_leaves_self_time():
+    rec = recorder()
+    with span("read", rec=rec) as reading:
+        time.sleep(0.02)
+        reading.charge("decode", 0.015)
+    table = rec.hub.span_table()
+    assert table["decode"] == {"count": 1,
+                               "total_s": pytest.approx(0.015),
+                               "self_s": pytest.approx(0.015)}
+    assert table["read"]["self_s"] == pytest.approx(
+        table["read"]["total_s"] - 0.015, abs=1e-9)
+    assert events(rec)["decode"]["args"]["parent"] == reading.id
+
+
+def test_without_a_recorder_a_span_is_an_annotation_only():
+    with span("group", rec=None, parent=None, op="x") as g:
+        with span("dispatch", wave=0) as d:
+            pass
+    assert d.parent is g and g.rec is None and g.id == 0
+    assert SpanRecorder().adopter(1) is None
+
+
+# ------------------------------------------------- (ii) the shared clock
+
+def test_span_is_in_the_profilers_trace_on_the_profilers_clock(tmp_path):
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    spans = []
+    try:
+        for wave in range(5):
+            with span("unit", wave=wave) as sp:
+                time.sleep(0.002)
+            spans.append(sp)
+    finally:
+        jax.profiler.stop_trace()
+    (xplane,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+    profile = ProfileData.from_file(xplane)
+    # Event starts are relative to the trace's start, which the "Task
+    # Environment" plane carries in unix-epoch nanoseconds.
+    started = [dict(pl.stats)["profile_start_time"]
+               for pl in profile.planes if pl.name == "Task Environment"]
+    found = sorted((ev for pl in profile.planes for ln in pl.lines
+                    for ev in ln.events if ev.name == "bigslice:unit"),
+                   key=lambda ev: ev.start_ns)
+    assert len(started) == 1 and len(found) == 5
+    assert [dict(ev.stats) for ev in found] == [
+        {"wave": w} for w in range(5)]
+    # The annotation opens just before the span's own stamp; a loaded
+    # machine may preempt the thread between the two once, not always.
+    apart = [abs(started[0] + ev.start_ns
+                 - trace_mod.CLOCK.to_unix_ns(sp.t0))
+             for ev, sp in zip(found, spans)]
+    assert min(apart) < 1e6 and sorted(apart)[2] < 1e6
+    assert min(abs(ev.duration_ns - (sp.t1 - sp.t0))
+               for ev, sp in zip(found, spans)) < 1e6
+
+
+# --------------------------------- (iii), (iv), (vi): a waved run's spans
+
+@pytest.fixture(scope="module")
+def waved(tmp_path_factory):
+    """Two waved Reduce jobs in one traced session: (summary, events)."""
+    path = str(tmp_path_factory.mktemp("spans") / "trace.json")
+    sess = mesh_session(trace_path=path)
+    try:
+        waved_reduce(sess, seed=1)
+        waved_reduce(sess, seed=2)
+        summary = sess.telemetry_summary()
+    finally:
+        sess.shutdown()
+    with open(path) as fp:
+        doc = json.load(fp)
+    return summary, doc, path
+
+
+def test_waved_reduce_leaves_every_span_of_the_table(waved):
+    summary, _, _ = waved
+    spans = summary["spans"]
+    assert set(spans) == set(TABLE)
+    jobs, groups = 2, 2 * 2               # map side + reduce side a job
+    waves = groups * WAVES
+    want = {"session.run": jobs, "compile_tasks": jobs, "evaluate": jobs,
+            "group": groups, "shuffle_plan": groups, "merge": jobs,
+            "stage": waves, "stage_wait": waves, "dispatch": waves,
+            "settle": waves,
+            # Only the map side reads its rows from the host.
+            "read": jobs * WAVES, "decode": jobs * WAVES,
+            "assemble": jobs * WAVES, "upload": jobs * WAVES,
+            # The scanned result is one readback a wave.
+            "readback": jobs * WAVES}
+    assert {k: spans[k]["count"] for k in want} == want
+    assert spans["mutex_wait"]["count"] >= waves
+    assert spans["upload"]["bytes"] > 0 and spans["readback"]["bytes"] > 0
+    assert all(v["self_s"] <= v["total_s"] + 1e-12 for v in spans.values())
+
+
+def test_staging_records_equal_what_the_spans_summed(waved):
+    summary, doc, _ = waved
+    spans = summary["spans"]
+    phases = {"read_s": 0.0, "decode_s": 0.0, "assemble_s": 0.0,
+              "upload_s": 0.0}
+    exposed = staging = 0.0
+    for op in summary["ops"].values():
+        waves = op.get("waves", {})
+        for k, v in waves.get("staging_breakdown", {}).items():
+            phases[k] += v
+        exposed += waves.get("exposed_s", 0.0)
+        staging += waves.get("staging_s", 0.0)
+    tol = dict(abs=2e-5)                  # the records round to 1 us each
+    assert phases["read_s"] == pytest.approx(spans["read"]["self_s"],
+                                             **tol)
+    assert phases["decode_s"] == pytest.approx(
+        spans["decode"]["total_s"], **tol)
+    assert phases["assemble_s"] == pytest.approx(
+        spans["assemble"]["total_s"], **tol)
+    assert phases["upload_s"] == pytest.approx(
+        spans["upload"]["total_s"], **tol)
+    assert staging == pytest.approx(spans["stage"]["total_s"], **tol)
+    # Exposed: a wave's ``stage_wait``, capped by its ``stage``.
+    by_wave = {}
+    for e in doc["traceEvents"]:
+        if e.get("pid") == trace_mod.SPAN_PID \
+                and e["name"] in ("stage", "stage_wait"):
+            a = e["args"]
+            by_wave.setdefault((a["inv"], a["wave"], e["name"]),
+                               []).append(e)
+    # Both groups of an invocation have a wave w: pair them in order.
+    capped = 0.0
+    for (inv, wave, name), waits in by_wave.items():
+        if name != "stage_wait":
+            continue
+        stages = by_wave[(inv, wave, "stage")]
+        assert len(stages) == len(waits)
+        for w, s in zip(sorted(waits, key=lambda e: e["ts"]),
+                        sorted(stages, key=lambda e: e["ts"])):
+            capped += min(w["dur"], s["dur"]) * 1e-6
+    assert exposed == pytest.approx(capped, **tol)
+    assert exposed <= spans["stage_wait"]["total_s"] + 2e-5
+
+
+def test_accounting_identity_of_an_invocation(waved):
+    """session.run = the self time of session.run, compile_tasks and
+    evaluate + the union of the invocation's groups; a group = its self
+    time + its same-thread children."""
+    _, doc, _ = waved
+    sp = [e for e in doc["traceEvents"]
+          if e.get("pid") == trace_mod.SPAN_PID]
+    by_id = {e["args"]["id"]: e for e in sp}
+    runs = [e for e in sp if e["name"] == "session.run"]
+    assert len(runs) == 2
+    for run in runs:
+        inv = run["args"]["inv"]
+        mine = [e for e in sp if e["args"]["inv"] == inv]
+        self_us = sum(e["args"]["self_us"] for e in mine
+                      if e["name"] in ("session.run", "compile_tasks",
+                                       "evaluate"))
+        groups = sorted((e["ts"], e["ts"] + e["dur"]) for e in mine
+                        if e["name"] == "group")
+        union, end = 0.0, 0.0
+        for a, b in groups:
+            a = max(a, end)
+            if b > a:
+                union, end = union + b - a, b
+        assert run["dur"] == pytest.approx(self_us + union, rel=0.01)
+        evaluate = [e for e in mine if e["name"] == "evaluate"]
+        for g in (e for e in mine if e["name"] == "group"):
+            assert g["args"]["parent"] == evaluate[0]["args"]["id"]
+            kids = sum(e["dur"] for e in mine
+                       if e["args"].get("parent") == g["args"]["id"])
+            assert g["dur"] == pytest.approx(
+                g["args"]["self_us"] + kids, rel=1e-6)
+    for e in sp:                          # a stage beside its group
+        if e["name"] == "stage" and "cause" in e["args"]:
+            assert by_id[e["args"]["cause"]]["name"] == "group"
+            assert "parent" not in e["args"]
+
+
+def test_trace_file_has_span_events_slicetrace_still_loads(waved, capsys):
+    from bigslice_tpu.tools import slicetrace
+
+    _, doc, path = waved
+    sp = [e for e in doc["traceEvents"]
+          if e["ph"] == "X" and e.get("pid") == trace_mod.SPAN_PID]
+    assert {e["name"] for e in sp} == set(TABLE)
+    assert all({"id", "inv", "self_us"} <= set(e["args"]) for e in sp)
+    assert doc["otherData"]["clock_unix_ns"] == trace_mod.CLOCK.unix_ns
+    tasks = [e for e in doc["traceEvents"]
+             if e["ph"] == "X" and e.get("pid") == "tasks"]
+    # Task events and span events are on one clock: a task runs inside
+    # its invocation's session.run span.
+    runs = {e["args"]["inv"]: e for e in sp if e["name"] == "session.run"}
+    for t in tasks:
+        run = runs[t["args"]["inv"]]
+        assert run["ts"] <= t["ts"]
+        assert t["ts"] + t["dur"] <= run["ts"] + run["dur"] + 1.0
+    assert slicetrace.main([path]) == 0
+    out = capsys.readouterr().out
+    assert f"{len(tasks)} task runs" in out   # spans are not task runs
+    assert ":spans" in out and "settle" in out
+    assert ":overlap" in out and ":staging" in out
+
+
+def test_no_hub_leaves_the_annotation_and_drops_the_table(monkeypatch):
+    monkeypatch.setenv("BIGSLICE_TELEMETRY", "0")
+    sess = mesh_session()
+    try:
+        assert sess.telemetry is None and sess.spans.hub is None
+        waved_reduce(sess)
+        assert sess.telemetry_summary() == {}
+    finally:
+        sess.shutdown()
+
+
+# ------------------------------------------- (v) stable program names
+
+def lowered_names(ex):
+    """kind -> module name of every program ``ex`` built."""
+    names = {}
+    for key, (prog, _) in ex._programs.items():
+        fn = getattr(prog, "_fn", prog)    # through the telemetry seam
+        kind = key[0] if isinstance(key[0], str) else "group"
+        names.setdefault(kind, set()).add(fn.__name__)
+    return names
+
+
+def run_every_program_kind():
+    """One executor driven through group, merge, rowslice, subid_count,
+    subid_split and keyrange programs."""
+    sess = mesh_session()
+    ex = sess.executor
+    try:
+        waved_reduce(sess)                 # group, merge, subid_*
+        inputs = ex._group_inputs(
+            bs_tasks(sess, bs.Const(8, np.arange(64, dtype=np.int32))))
+        cols, counts, cap, _, _ = inputs[0]
+        ex._slice_wave_program(("int32",), cap, cap // 2)(
+            np.int32(0), counts, *cols)
+        ex._key_range(cols, counts, cap, False)
+        return lowered_names(ex)
+    finally:
+        sess.shutdown()
+
+
+def bs_tasks(sess, slice_):
+    from bigslice_tpu.exec import compile as compile_mod
+
+    return compile_mod.Compiler(10 ** 6).compile(slice_)
+
+
+def test_every_program_kind_lowers_under_a_stable_name():
+    first, second = run_every_program_kind(), run_every_program_kind()
+    assert first == second                 # two fresh executors
+    kinds = {"group", "merge", "rowslice", "subidcount", "subidsplit",
+             "keyrange"}
+    assert set(first) == kinds
+    assert first["merge"] == {"bs_merge"}
+    assert first["rowslice"] == {"bs_rowslice"}
+    assert first["subidcount"] == {"bs_subid_count"}
+    assert first["subidsplit"] == {"bs_subid_split"}
+    assert first["keyrange"] == {"bs_keyrange"}
+    assert all(n.startswith("bs_group_") for n in first["group"])
+
+
+def test_group_program_name_is_its_structure_not_its_op_index():
+    """Two jobs whose ops differ in index (``reduce@...#1``) lower to
+    the same module name — the persistent cache's key begins with it."""
+    sess = mesh_session()
+    try:
+        lowered = []
+        for seed in (1, 2):
+            waved_reduce(sess, seed)
+            ex = sess.executor
+            for key, (prog, _) in list(ex._programs.items()):
+                if not isinstance(key[0], str):
+                    fn = getattr(prog, "_fn", prog)
+                    lowered.append(fn.__name__)
+        ops = [op for op in sess.telemetry_summary()["ops"]]
+        assert any("#" in op for op in ops)    # indices did differ
+        assert len(set(lowered)) == 2          # map side, reduce side
+    finally:
+        sess.shutdown()
+    name = _program_name("group", ("map", "shuffle"))
+    assert name == "bs_group_map_shuffle"
+    assert _program_name("group", ("a-b", "c d")) == "bs_group_a_b_c_d"
+    assert len(_program_name("group", ("shuffle",) * 40)) == 64
+
+    def stepped(x):
+        return x + 1
+
+    from bigslice_tpu.exec.meshexec import _named
+
+    text = jax.jit(_named(stepped, "group", ("map", "shuffle"))).lower(
+        np.int32(1)).as_text()
+    assert "module @jit_bs_group_map_shuffle" in text
