@@ -323,8 +323,7 @@ class DeviceGroupOutput:
         self._chunks = None
         self._chunks_lock = threading.Lock()
         # Bytes the host-chunk readback moved device → host (what
-        # crossed, not what was valid), until a ``readback`` span
-        # counts them.
+        # crossed, not what was valid).
         self.readback_nbytes = 0
         # Per-consumer-wave device views of a subid output (the
         # one-pass subid split, _subid_wave_view): wave w's rows
@@ -370,26 +369,7 @@ class DeviceGroupOutput:
         # Memoized: every (task, partition) read would otherwise pull the
         # whole global output device→host again.
         with self._chunks_lock:
-            if self._chunks is None:
-                if self.cols and not getattr(
-                    self.cols[0], "is_fully_addressable", True
-                ):
-                    # Multiprocess output that consumer-driven gather
-                    # marked device-only: a lazy host read cannot run
-                    # the collective (nondeterministic order across
-                    # processes). Settle the reader as a classified
-                    # error; the retry/elastic ladder recomputes.
-                    raise UngatheredOutputError(
-                        "device group output is mesh-resident "
-                        "(device-only by plan); host read would need "
-                        "an unplanned collective gather"
-                    )
-                crossed: List[int] = []
-                self._chunks = shuffle_mod.unshard_columns(
-                    self.cols, np.asarray(self.counts), self.capacity,
-                    crossed=crossed,
-                )
-                self.readback_nbytes = sum(crossed)
+            _fill_host_chunks([self])
             return self._chunks
 
     def drop_device(self) -> None:
@@ -415,6 +395,40 @@ class DeviceGroupOutput:
             self._chunks = None
         with self._views_lock:
             self._wave_views = None
+
+
+def _fill_host_chunks(outs: Sequence[DeviceGroupOutput]) -> int:
+    """Host chunks for every output of ``outs`` that has none yet, all
+    brought device → host by ONE batched unshard
+    (``shuffle.unshard_many``); the caller holds their
+    ``_chunks_lock``s. Returns how many arrays were fetched (counts
+    included)."""
+    missing = [o for o in outs if o._chunks is None]
+    for o in missing:
+        if o.cols and not getattr(
+            o.cols[0], "is_fully_addressable", True
+        ):
+            # Multiprocess output that consumer-driven gather marked
+            # device-only: a lazy host read cannot run the collective
+            # (nondeterministic order across processes). Settle the
+            # reader as a classified error; the retry/elastic ladder
+            # recomputes.
+            raise UngatheredOutputError(
+                "device group output is mesh-resident "
+                "(device-only by plan); host read would need "
+                "an unplanned collective gather"
+            )
+    if not missing:
+        return 0
+    crossed: List[List[int]] = [[] for _ in missing]
+    read = shuffle_mod.unshard_many(
+        [(o.cols, o.counts, o.capacity) for o in missing],
+        crossed=crossed,
+    )
+    for o, chunks, moved in zip(missing, read, crossed):
+        o._chunks = chunks
+        o.readback_nbytes = sum(moved)
+    return len(missing) + sum(len(moved) for moved in crossed)
 
 
 class _BridgedStore(store_mod.MemoryStore):
@@ -4582,18 +4596,28 @@ class MeshExecutor:
         with self._lock:
             return name in self._task_index
 
-    def _readback(self, out: DeviceGroupOutput, task: Task):
-        """``out``'s host chunks for a store-bridge read; the one read
-        that moves them device → host is the ``readback`` span."""
-        if out._chunks is not None:
-            return out._chunks
-        with span("readback", rec=self._span_recorder(),
-                  inv=task.name.inv_index) as sp:
-            chunks = out.host_chunks()
-            with out._chunks_lock:  # counted once, by whoever moved it
-                moved, out.readback_nbytes = out.readback_nbytes, 0
-            sp.set(bytes=moved)
-        return chunks
+    def _readback(self, out, task: Task) -> None:
+        """Bring a group output's host chunks — EVERY wave of a waved
+        output — to the host for a store-bridge read. The one batched
+        read that moves them device → host is the ``readback`` span;
+        later reads (other shards, concurrent readers) find every wave
+        memoized."""
+        waves = out.waves if isinstance(out, WavedGroupOutput) else [out]
+        if all(w._chunks is not None for w in waves):
+            return
+        with contextlib.ExitStack() as held:
+            # Wave order: the one order every reader takes them in
+            # (host_chunks() holds one at a time).
+            for w in waves:
+                held.enter_context(w._chunks_lock)
+            missing = [w for w in waves if w._chunks is None]
+            if not missing:
+                return
+            with span("readback", rec=self._span_recorder(),
+                      inv=task.name.inv_index) as sp:
+                arrays = _fill_host_chunks(missing)
+                sp.set(bytes=sum(w.readback_nbytes for w in missing),
+                       waves=len(missing), arrays=arrays)
 
     def _frames_by_name(self, name: TaskName,
                         partition: int) -> Optional[List[Frame]]:
@@ -4652,13 +4676,14 @@ class MeshExecutor:
         if isinstance(out, WavedGroupOutput):
             if partition != 0:
                 return []
-            wout = out.waves[shard // out.nmesh]
-            chunks = self._readback(wout, task)
+            self._readback(out, task)
+            chunks = out.waves[shard // out.nmesh]._chunks
             cols = [c[shard % out.nmesh] for c in chunks]
             if not len(cols[0]):
                 return []
             return [frame_for(cols)]
-        chunks = self._readback(out, task)
+        self._readback(out, task)
+        chunks = out._chunks
         if out.partitioned:
             # Post-shuffle: device p holds partition p merged over
             # sources; attribute it all to producer shard 0 so the union

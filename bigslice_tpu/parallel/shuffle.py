@@ -22,6 +22,7 @@ capacity — the recompile-averse bucketing strategy).
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -706,76 +707,159 @@ def place_global_columns(mesh, globs: Sequence[np.ndarray], counts):
     return [place(g) for g in globs], place(counts_host)
 
 
+# Most bytes of bucketed prefixes one readback keeps in flight to the
+# host at a time: a result of a few KB is one batch, one of gigabytes a
+# short pipeline that never asks the host for all of it at once.
+READBACK_BATCH_BYTES = 64 << 20
+
+
 def unshard_columns(cols: Sequence, counts, capacity: int,
                     crossed: Optional[List[int]] = None
                     ) -> List[List[np.ndarray]]:
     """Inverse of shard_columns: global padded arrays → per-shard valid
-    host chunks.
+    host chunks (``[ncols][nshards]``); ``unshard_many`` of one output.
+
+    ``crossed``, when given, collects the bytes of every array brought
+    to the host (the bucketed prefixes, not the valid rows alone)."""
+    per: List[List[int]] = [[]]
+    (chunks,) = unshard_many([(cols, counts, capacity)], per)
+    if crossed is not None:
+        crossed.extend(per[0])
+    return chunks
+
+
+def unshard_many(outputs: Sequence[Tuple[Sequence, object, int]],
+                 crossed: Optional[List[List[int]]] = None
+                 ) -> List[List[List[np.ndarray]]]:
+    """``unshard_columns`` of many outputs ``(cols, counts, capacity)``
+    at once — the waves of a waved group output — as two overlapped
+    device→host reads instead of one blocking read an array.
 
     Device-resident columns transfer only each shard's valid prefix
     (rounded up to a power-of-two bucket so the tiny slice programs
     don't thrash the compile cache): combiner outputs are typically far
     smaller than their padded capacity, and on TPU the readback rides
     the host link — moving ``capacity`` rows to read ``count`` is the
-    difference between a result scan and a full-buffer download.
+    difference between a result scan and a full-buffer download. A
+    round trip to the device costs about the same whatever it carries,
+    so: (1) ONE ``jax.device_get`` of every output's counts (it starts
+    every copy before it waits for any); (2) with the counts on the
+    host, every non-empty (output, shard) has its columns' prefixes
+    sliced on the device by one ``bs_prefix`` call without being read
+    (dispatch is asynchronous), and they are fetched by one
+    ``device_get`` a batch of at most ``READBACK_BATCH_BYTES``, in
+    output order.
 
-    ``crossed``, when given, collects the bytes of every array brought
-    to the host (the bucketed prefixes, not the valid rows alone)."""
-    counts = np.asarray(counts)
-    nshards = len(counts)
-    return [_valid_chunks(c, counts, capacity, nshards, crossed)
-            for c in cols]
-
-
-def _valid_chunks(c, counts, capacity: int, nshards: int,
-                  crossed: Optional[List[int]] = None
-                  ) -> List[np.ndarray]:
+    ``crossed[i]``, when given, collects the bytes of every array of
+    output ``i`` brought to the host."""
     import jax
 
     from bigslice_tpu.parallel.jitutil import bucket_size
 
-    shards = getattr(c, "addressable_shards", None)
-    if shards is not None and len(shards) == nshards:
-        # On TPU, slicing the valid prefix on-device before readback is
-        # the point of this path (see unshard_columns); on CPU backends
-        # a whole-shard np.asarray is a plain copy that costs less than
-        # dispatching a device slice program, so slice host-side.
-        device_slice = jax.default_backend() == "tpu"
-        by_row = {}
-        for sh in shards:
-            start = sh.index[0].start or 0
-            if start % capacity == 0:
-                by_row[start // capacity] = sh.data
-        if set(by_row) == set(range(nshards)):
-            chunks = []
-            for s in range(nshards):
-                k = int(counts[s])
-                if k == 0:
-                    chunks.append(np.empty(
-                        (0,) + tuple(c.shape[1:]), c.dtype
-                    ))
-                    continue
-                if device_slice:
-                    b = min(capacity, bucket_size(k))
-                    host = np.asarray(by_row[s][:b])
-                    chunks.append(host[:k])
-                else:
-                    # .copy(): np.asarray over a CPU shard is zero-copy
-                    # and a view would pin the whole capacity-row
-                    # buffer in memoized chunk storage past
-                    # drop_device().
-                    host = np.asarray(by_row[s])
-                    chunks.append(host[:k].copy())
+    counts_host = [np.asarray(c) for c in
+                   jax.device_get([counts for _, counts, _ in outputs])]
+    device_slice = _slices_on_device()
+    result: List[List[List[np.ndarray]]] = []
+    batch: list = []  # (device array, chunk list, shard, k, output)
+    batch_bytes = 0
+
+    def collect():
+        nonlocal batch_bytes
+        hosts = jax.device_get([a for a, *_ in batch])
+        for host, (_, chunks, s, k, i) in zip(hosts, batch):
+            # .copy() on CPU: np.asarray over a CPU shard is zero-copy
+            # and a view would pin the whole capacity-row buffer in
+            # memoized chunk storage past drop_device().
+            chunks[s] = host[:k] if device_slice else host[:k].copy()
+            if crossed is not None:
+                crossed[i].append(host.nbytes)
+        batch.clear()
+        batch_bytes = 0
+
+    for i, ((cols, _, capacity), counts) in enumerate(
+            zip(outputs, counts_host)):
+        nshards = len(counts)
+        out_chunks: List[list] = []
+        resident = []  # (shard arrays, chunk list) a device column
+        for c in cols:
+            by_row = _shard_rows(c, capacity, nshards)
+            if by_row is None:
+                # Host columns / multi-process gathers (already numpy)
+                # / unexpected layouts: the plain full-copy path.
+                c = np.asarray(c)
                 if crossed is not None:
-                    crossed.append(host.nbytes)
-            return chunks
-    # Host columns / multi-process gathers (already numpy) / unexpected
-    # layouts: the plain full-copy path.
-    c = np.asarray(c)
-    if crossed is not None:
-        crossed.append(c.nbytes)
-    return [c[s * capacity : s * capacity + int(counts[s])]
-            for s in range(nshards)]
+                    crossed[i].append(c.nbytes)
+                out_chunks.append(
+                    [c[s * capacity : s * capacity + int(counts[s])]
+                     for s in range(nshards)])
+                continue
+            out_chunks.append(
+                [np.empty((0,) + tuple(c.shape[1:]), c.dtype)
+                 if counts[s] == 0 else None for s in range(nshards)])
+            resident.append((by_row, out_chunks[-1]))
+        result.append(out_chunks)
+        if not resident:
+            continue
+        for s in range(nshards):
+            k = int(counts[s])
+            if k == 0:
+                continue
+            arrays = [by_row[s] for by_row, _ in resident]
+            b = min(capacity, bucket_size(k)) if device_slice \
+                else capacity
+            nbytes = sum(a.nbytes for a in arrays) // capacity * b
+            if batch and batch_bytes + nbytes > READBACK_BATCH_BYTES:
+                collect()
+            if b < capacity:
+                arrays = _prefix_program()(b, *arrays)
+            batch.extend((a, chunks, s, k, i)
+                         for a, (_, chunks) in zip(arrays, resident))
+            batch_bytes += nbytes
+    if batch:
+        collect()
+    return result
+
+
+@functools.lru_cache(maxsize=None)
+def _prefix_program():
+    """``bs_prefix(b, *cols)``: the first ``b`` rows of every column of
+    one shard, in one device program — keyed (by jit) on the bucket and
+    the columns' shapes, never on how many outputs are read or on their
+    counts. One call a shard, not an eager ``c[:b]`` a column: an eager
+    slice is a ``dynamic_slice`` whose start index is put on the device
+    at every call, 0.66 ms of host time each on a v5e's host."""
+    import jax
+
+    def bs_prefix(b, *cols):
+        return tuple(c[:b] for c in cols)
+
+    return jax.jit(bs_prefix, static_argnums=0)
+
+
+def _slices_on_device() -> bool:
+    """On TPU, slicing the valid prefix on-device before readback is
+    the point of ``unshard_many``; on CPU backends a whole-shard
+    np.asarray is a plain copy that costs less than dispatching a
+    device slice program, so slice host-side."""
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
+def _shard_rows(c, capacity: int, nshards: int) -> Optional[list]:
+    """Shard s's single-device array of row-sharded ``c``, for every s;
+    None when ``c`` is not device-resident in that layout."""
+    shards = getattr(c, "addressable_shards", None)
+    if shards is None or len(shards) != nshards:
+        return None
+    by_row = {}
+    for sh in shards:
+        start = sh.index[0].start or 0
+        if start % capacity == 0:
+            by_row[start // capacity] = sh.data
+    if set(by_row) != set(range(nshards)):
+        return None
+    return [by_row[s] for s in range(nshards)]
 
 
 def partition_cols(chunks: Sequence[Sequence[np.ndarray]], partition: int,

@@ -461,6 +461,84 @@ def test_wave_unpartitioned_root(mesh):
     assert got19 == [3 * i for i in range(190, 200)]
 
 
+def waved_root(mesh):
+    """A scanned-by-nobody 20-shard map chain: (session, result, its
+    3-wave output on the 8-device mesh)."""
+    from bigslice_tpu.exec.meshexec import WavedGroupOutput
+
+    sess = Session(executor=MeshExecutor(mesh))
+    res = sess.run(bs.Map(bs.Const(20, np.arange(200, dtype=np.int32)),
+                          lambda x: x * 3))
+    ex = sess.executor
+    with ex._lock:
+        key, _ = ex._task_index[res.tasks[0].name]
+        out = ex._outputs[key]
+    assert isinstance(out, WavedGroupOutput) and len(out.waves) == 3
+    return sess, res, out
+
+
+def test_waved_readback_fills_each_wave_as_host_chunks_would(mesh):
+    """The one batched read of a waved output leaves every wave the
+    chunks its own ``host_chunks()`` reads, and its crossed bytes
+    counted by the span."""
+    from bigslice_tpu.exec.meshexec import DeviceGroupOutput
+
+    sess, res, out = waved_root(mesh)
+    assert list(res.reader(7, ()))
+    span = sess.telemetry_summary()["spans"]["readback"]
+    moved = 0
+    for w in out.waves:
+        alone = DeviceGroupOutput(w.cols, w.counts, w.capacity,
+                                  w.schema, False, nmesh=w.nmesh)
+        want = alone.host_chunks()
+        moved += alone.readback_nbytes
+        assert w.readback_nbytes == alone.readback_nbytes > 0
+        assert len(w._chunks) == len(want)
+        for got_col, want_col in zip(w._chunks, want):
+            for g, c in zip(got_col, want_col):
+                assert g.dtype == c.dtype
+                np.testing.assert_array_equal(g, c)
+    assert (span["count"], span["bytes"]) == (1, moved)
+    assert sorted(res.rows()) == [(3 * i,) for i in range(200)]
+
+
+def test_waved_readback_moves_only_waves_not_yet_on_the_host(mesh):
+    """A wave read through its own ``host_chunks()`` first (the spill
+    sink's and ``drop_device``'s entry) stays as read; the output's
+    readback moves the others."""
+    sess, res, out = waved_root(mesh)
+    early = out.waves[1].host_chunks()
+    out.waves[2].drop_device()
+    assert out.waves[2].cols is None
+    assert "readback" not in sess.telemetry_summary()["spans"]
+    assert sorted(res.rows()) == [(3 * i,) for i in range(200)]
+    assert out.waves[1]._chunks is early
+    assert sess.telemetry_summary()["spans"]["readback"]["count"] == 1
+    assert out.waves[1].host_chunks() is early
+
+
+def test_ungathered_wave_raises_before_anything_is_read(mesh):
+    """A wave that is not fully addressable fails the whole read as a
+    classified error, with no wave's arrays fetched."""
+    from bigslice_tpu.exec import meshexec
+
+    sess, res, out = waved_root(mesh)
+
+    class DeviceOnly:
+        is_fully_addressable = False
+
+    out.waves[2].cols = [DeviceOnly()]
+    with pytest.raises(meshexec.UngatheredOutputError):
+        meshexec._fill_host_chunks(out.waves)
+    assert all(w._chunks is None for w in out.waves)
+    # Through the store bridge: the retriable ``Missing`` contract.
+    from bigslice_tpu.exec.store import Missing
+
+    with pytest.raises(Missing):
+        sess.executor.store.read(res.tasks[0].name, 0)
+    assert all(w._chunks is None for w in out.waves)
+
+
 def test_wave_aligned_chain(mesh):
     """Waved producer feeding an aligned waved consumer (materialize
     boundary): per-wave zero-copy chaining."""

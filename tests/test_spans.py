@@ -42,16 +42,17 @@ def mesh_session(**kw):
     return Session(executor=ex, **kw)
 
 
-def waved_reduce(sess, seed=0):
+def waved_reduce(sess, seed=0, scan=True):
     """A Reduce of WAVES waves on the 8-device mesh whose every key is
     on every shard already, so no wave overflows its slack and retries;
-    scanned, so the result is read back."""
+    scanned, unless ``scan`` is false, so the result is read back."""
     n = len(jax.devices()) * WAVES
     keys = np.tile(np.arange(64, dtype=np.int32), n * 4)
     vals = np.random.default_rng(seed).integers(
         1, 9, len(keys)).astype(np.int32)
     res = sess.run(bs.Reduce(bs.Const(n, keys, vals), lambda a, b: a + b))
-    assert sum(r[1] for r in res.rows()) == int(vals.sum())
+    if scan:
+        assert sum(r[1] for r in res.rows()) == int(vals.sum())
     return res
 
 
@@ -245,12 +246,118 @@ def test_waved_reduce_leaves_every_span_of_the_table(waved):
             # Only the map side reads its rows from the host.
             "read": jobs * WAVES, "decode": jobs * WAVES,
             "assemble": jobs * WAVES, "upload": jobs * WAVES,
-            # The scanned result is one readback a wave.
-            "readback": jobs * WAVES}
+            # The scanned result is ONE readback, of every wave.
+            "readback": jobs}
     assert {k: spans[k]["count"] for k in want} == want
     assert spans["mutex_wait"]["count"] >= waves
     assert spans["upload"]["bytes"] > 0 and spans["readback"]["bytes"] > 0
     assert all(v["self_s"] <= v["total_s"] + 1e-12 for v in spans.values())
+
+
+def scanned_output(sess, res):
+    """The executor's waved group output behind a scanned result."""
+    from bigslice_tpu.exec.meshexec import WavedGroupOutput
+
+    ex = sess.executor
+    with ex._lock:
+        key, _ = ex._task_index[res.tasks[0].name]
+        out = ex._outputs[key]
+    assert isinstance(out, WavedGroupOutput) and len(out.waves) == WAVES
+    return out
+
+
+def readbacks(sess):
+    return sess.telemetry_summary()["spans"].get(
+        "readback", {"count": 0, "bytes": 0})
+
+
+def test_one_readback_an_output_carries_every_waves_bytes(tmp_path):
+    from bigslice_tpu.parallel import shuffle as shuffle_mod
+
+    path = str(tmp_path / "trace.json")
+    sess = mesh_session(trace_path=path)
+    try:
+        out = scanned_output(sess, waved_reduce(sess))
+        crossed = []
+        for w in out.waves:   # what each wave moves, read on its own
+            shuffle_mod.unshard_columns(w.cols, w.counts, w.capacity,
+                                        crossed=crossed)
+        got = readbacks(sess)
+        assert (got["count"], got["bytes"]) == (1, sum(crossed))
+    finally:
+        sess.shutdown()
+    with open(path) as fp:
+        (ev,) = [e for e in json.load(fp)["traceEvents"]
+                 if e.get("pid") == trace_mod.SPAN_PID
+                 and e["name"] == "readback"]
+    assert ev["args"]["waves"] == WAVES
+    assert ev["args"]["bytes"] == sum(crossed)
+    # Every wave's counts, and a prefix a (column, non-empty shard).
+    assert ev["args"]["arrays"] == WAVES + len(crossed)
+
+
+def test_reading_one_shard_memoizes_every_wave():
+    sess = mesh_session()
+    try:
+        res = waved_reduce(sess, scan=False)
+        out = scanned_output(sess, res)
+        assert all(w._chunks is None for w in out.waves)
+        assert readbacks(sess)["count"] == 0
+        first = list(res.reader(3, ()))
+        assert readbacks(sess)["count"] == 1
+        assert all(w._chunks is not None for w in out.waves)
+        moved = readbacks(sess)["bytes"]
+        # A shard of another wave, then the whole scan: no new span.
+        list(res.reader(res.num_shards - 1, ()))
+        rows = res.rows()
+        got = readbacks(sess)
+        assert (got["count"], got["bytes"]) == (1, moved)
+        assert first and len(rows) == 64
+    finally:
+        sess.shutdown()
+
+
+def test_concurrent_shard_readers_share_one_readback():
+    """More reader threads than cores, each on a shard of its own, all
+    let go at once: one span between them, every shard's rows right."""
+    import sys
+
+    sess = mesh_session()
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        res = waved_reduce(sess, scan=False)
+        out = scanned_output(sess, res)
+        nshards = res.num_shards
+        gate = threading.Barrier(nshards)
+        got, errors = {}, []
+
+        def read(shard):
+            try:
+                gate.wait(30)
+                got[shard] = [r for f in res.reader(shard, ())
+                              for r in f.rows()]
+            except BaseException as e:   # reported by the assert below
+                errors.append(e)
+
+        threads = [threading.Thread(target=read, args=(s,))
+                   for s in range(nshards)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        assert not errors and not any(t.is_alive() for t in threads)
+        assert readbacks(sess)["count"] == 1
+        for shard in range(nshards):
+            w = out.waves[shard // out.nmesh]
+            want = list(zip(*(c[shard % out.nmesh].tolist()
+                              for c in w._chunks)))
+            assert got[shard] == want
+        assert sorted(r for rows in got.values() for r in rows) \
+            == sorted(res.rows())
+    finally:
+        sys.setswitchinterval(old)
+        sess.shutdown()
 
 
 def test_staging_records_equal_what_the_spans_summed(waved):
