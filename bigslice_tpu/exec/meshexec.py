@@ -35,6 +35,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import logging
+import math
 import os
 import re
 import threading
@@ -227,6 +228,23 @@ def _op_base(op: str) -> str:
     slack adaptation describe the pipeline SITE (file:line op), which
     iterative drivers re-invoke under fresh suffixed names each run."""
     return op.split("#", 1)[0]
+
+
+#: Rungs of the shuffle's slack ladder in each octave.
+_SLACK_RUNGS_PER_OCTAVE = 32
+
+
+def _slack_rung(slack: float, need: float) -> float:
+    """The smallest rung of the shuffle's slack ladder that holds
+    ``need`` and lies above ``slack``. Rungs divide each octave evenly
+    (1, 1.03125, 1.0625, ... 2, 2.0625, 2.125, ...): a bounded set of
+    programs however the keys are skewed, and fine enough that a shard
+    whose buckets run over by a few rows pays for 3 % more receive
+    buffer, not for the next power of two."""
+    octave = 2.0 ** math.floor(math.log2(max(need, slack, 1.0)))
+    step = octave / _SLACK_RUNGS_PER_OCTAVE
+    rung = octave + step * math.ceil((need - octave) / step)
+    return rung if rung > slack else slack + step
 
 
 def _fleet_aot_enabled() -> bool:
@@ -2083,6 +2101,7 @@ class MeshExecutor:
                     flat_dcn_messages=N * (D - 1) * I,
                     flat_dcn_bytes=N * (D - 1) * I * flat_cap
                     * (rowbytes + (4 if waved else 0)),
+                    slack=slack,
                 )
             else:
                 msgs = N * (N - 1)
@@ -2091,6 +2110,7 @@ class MeshExecutor:
                     ici_messages=msgs,
                     ici_bytes=msgs * flat_cap
                     * (rowbytes + (4 if waved else 0)),
+                    slack=slack,
                 )
         except Exception:
             pass
@@ -2793,14 +2813,19 @@ class MeshExecutor:
             _op_base(task0.name.op), 1.0 if has_combiner else 2.0
         )
 
-    def _dispatch_wave_on(self, tasks: List[Task], wave: int, inputs):
+    def _dispatch_wave_on(self, tasks: List[Task], wave: int, inputs,
+                          attempt: int = 0):
         """Run the wave's compiled program ONCE with the currently
         adapted state and return the unsynced results — XLA dispatch is
         async, so this returns while the device still computes. The
         pipeline settles signals later (_execute_wave_on with
-        ``first=``); serial and retry paths keep their blocking loop."""
+        ``first=``); serial and retry paths keep their blocking loop.
+        ``attempt`` > 0 marks a wave dispatched again after a retry
+        signal (the span carries it)."""
         task0 = tasks[0]
         with span("dispatch", wave=wave) as sp:
+            if attempt:
+                sp.set(attempt=attempt)
             caps, counts_list, cols_flat, subids, donate = (
                 self._wave_arrays(inputs)
             )
@@ -2871,13 +2896,16 @@ class MeshExecutor:
         from bigslice_tpu.ops.cogroup import Cogroup as _Cogroup
 
         is_cogroup = isinstance(task0.chain[-1], _Cogroup)
+        attempt = 0
         while True:
             if first is None:
                 if restage is not None and self._inputs_consumed(inputs):
                     # The failed attempt donated (and so consumed) the
                     # staged buffers: re-stage before retrying.
                     inputs = restage()
-                first = self._dispatch_wave_on(tasks, wave, inputs)
+                first = self._dispatch_wave_on(tasks, wave, inputs,
+                                               attempt)
+            attempt += 1
             # Sync THIS attempt's signals (a pipeline-dispatched one on
             # the first pass); the loop only re-runs on retry.
             (out_counts, overflow, badrange, gbover, hashov,
@@ -2962,8 +2990,29 @@ class MeshExecutor:
                     f"mesh shuffle overflow in group {task0.name.op} "
                     f"even at full slack"
                 )
-            slack = min(slack * 4, full_slack)
-            self._slack_memo[_op_base(task0.name.op)] = slack
+            if self.topo.is_hier:
+                slack = min(slack * 4, full_slack)
+            else:
+                # The signal is psum(max(counts) − send_cap): an upper
+                # bound on the rows the fullest bucket lacked, so the
+                # next rung is sized from it instead of jumping to the
+                # worst-case-skew buffers (uniform keys that miss
+                # slack 1.0 by a few rows settle a rung above it, not
+                # on ndest).
+                cap = max(i[2] for i in inputs)
+                send_cap = shuffle_mod.send_capacity(cap, ndest, slack)
+                slack = min(_slack_rung(
+                    slack, slack * (send_cap + overflow) / send_cap
+                ), full_slack)
+            # A wave dispatched before an earlier one's retry raised
+            # the op's slack must not lower it again.
+            base = _op_base(task0.name.op)
+            slack = max(slack, self._slack_memo.get(base, slack))
+            self._slack_memo[base] = slack
+            dev = self._device_telemetry()
+            if dev is not None:
+                dev.record_exchange_retry(task0.name.op,
+                                          task0.name.inv_index)
         # Donation effectiveness: how much of what this wave handed to
         # XLA under donate_argnums was actually consumed (aliased).
         self._telemetry_donation(task0, inputs)

@@ -346,6 +346,11 @@ class _OpDeviceRecord:
         self.ici_bytes = 0
         self.flat_dcn_messages = 0
         self.flat_dcn_bytes = 0
+        # the bucket slack of the op's last dispatched wave (settled,
+        # once no wave overflows) and the waves dispatched again after
+        # an overflow
+        self.exchange_slack = 0.0
+        self.exchange_retries = 0
         # shuffle-plan attribution (exec/shuffleplan.py): per-boundary
         # exchange choice + the spill path's written bytes/partitions
         # and its map-wave / reduce-sub-wave schedule.
@@ -589,7 +594,8 @@ class DeviceTelemetry:
                         dcn_messages: int = 0, dcn_bytes: int = 0,
                         ici_messages: int = 0, ici_bytes: int = 0,
                         flat_dcn_messages: int = 0,
-                        flat_dcn_bytes: int = 0) -> None:
+                        flat_dcn_bytes: int = 0,
+                        slack: float = 0.0) -> None:
         """One wave's collective-exchange plan, split by interconnect
         axis kind: messages/bytes the shuffle's all_to_all buckets put
         on the slow DCN axis vs the fast ICI axis (derived from the
@@ -598,10 +604,12 @@ class DeviceTelemetry:
         ``flat_dcn_*`` is the counterfactual a single flat all_to_all
         over the same (D, I) topology would have crossed DCN with —
         the denominator of the I-fold reduction column. 1-D meshes
-        record everything as ICI with dcn = 0."""
+        record everything as ICI with dcn = 0. ``slack`` is the bucket
+        slack the wave was dispatched at."""
         with self._lock:
             rec = self._op(op, inv)
             rec.exchange_waves += 1
+            rec.exchange_slack = float(slack)
             rec.dcn_messages += max(0, int(dcn_messages))
             rec.dcn_bytes += max(0, int(dcn_bytes))
             rec.ici_messages += max(0, int(ici_messages))
@@ -615,6 +623,14 @@ class DeviceTelemetry:
                    ici_bytes=int(ici_bytes),
                    flat_dcn_messages=int(flat_dcn_messages),
                    flat_dcn_bytes=int(flat_dcn_bytes))
+
+    def record_exchange_retry(self, op: str,
+                              inv: Optional[int]) -> None:
+        """A wave whose buckets overflowed and whose program is
+        dispatched again at a larger slack (in the trace: the
+        ``dispatch`` span with ``attempt``)."""
+        with self._lock:
+            self._op(op, inv).exchange_retries += 1
 
     # -- shuffle-plan attribution (out-of-core spill exchange) ------------
 
@@ -811,6 +827,8 @@ class DeviceTelemetry:
                         "dcn_bytes": rec.dcn_bytes,
                         "ici_messages": rec.ici_messages,
                         "ici_bytes": rec.ici_bytes,
+                        "slack": rec.exchange_slack,
+                        "retries": rec.exchange_retries,
                     }
                     if rec.flat_dcn_messages:
                         entry["flat_dcn_messages"] = rec.flat_dcn_messages

@@ -816,7 +816,16 @@ class TelemetryHub:
 
     def summary(self) -> dict:
         """The ``Session.telemetry_summary()`` payload: per-op skew /
-        straggler / wave sections plus session-wide rollups."""
+        straggler / wave / exchange sections plus session-wide
+        rollups."""
+        # Device plane first (its own lock): the per-op ``exchange``
+        # blocks below join its collective plan to this hub's rows a
+        # device.
+        try:
+            device = self.device.summary()
+        except Exception:
+            device = {}
+        exchanged = device.get("exchange", {})
         with self._lock:
             ops = {}
             total_staging = total_hidden = 0.0
@@ -896,6 +905,16 @@ class TelemetryHub:
                         }
                     total_staging += rec.staging_s
                     total_hidden += hidden
+                ex = exchanged.get(op)
+                if ex and ex["ici_messages"] + ex["dcn_messages"]:
+                    # A shuffle whose collective moved something (a
+                    # mesh of one exchanges nothing and has no block).
+                    entry["exchange"] = {
+                        **{k: ex[k] for k in (
+                            "waves", "ici_bytes", "ici_messages",
+                            "slack", "retries")},
+                        "recv_rows": list(rec.part_rows),
+                    }
                 ops[op] = entry
             states: Dict[str, int] = {}
             for (_, st), n in self._state_counts.items():
@@ -931,10 +950,7 @@ class TelemetryHub:
         # effectiveness (utils/devicetelemetry.py). Always present so
         # consumers need no existence dance; empty sub-dicts mean "no
         # device work observed".
-        try:
-            out["device"] = self.device.summary()
-        except Exception:
-            out["device"] = {}
+        out["device"] = device
         # Cross-Session compiled-program cache (serve/programcache.py):
         # process-scope, so the numbers cover every session this
         # process ever ran — the serving plane's zero-recompile
