@@ -322,7 +322,8 @@ class DeviceGroupOutput:
 
     def __init__(self, cols, counts, capacity: int, schema,
                  partitioned: bool, subid: bool = False,
-                 nmesh: Optional[int] = None):
+                 nmesh: Optional[int] = None,
+                 subid_ordered: bool = False):
         self.cols = cols
         self.counts = counts
         self.capacity = capacity
@@ -338,6 +339,10 @@ class DeviceGroupOutput:
         # an int32 subid as cols[0]: partition p lives on device
         # p % nmesh with subid p // nmesh.
         self.subid = subid
+        # The valid rows are grouped by subid (ascending, stable): what
+        # the cross-wave merge leaves, so the subid split cuts its
+        # regions out as slices without ordering the rows again.
+        self.subid_ordered = bool(subid and subid_ordered)
         self._chunks = None
         self._chunks_lock = threading.Lock()
         # Bytes the host-chunk readback moved device → host (what
@@ -666,7 +671,7 @@ class MeshExecutor:
         self.donate_buffers = bool(donate_buffers)
         # Subid pre-split (the wave pipeline's consumer-side half): a
         # wave-partitioned output read by a waved device consumer is
-        # split by subid ONCE (one linear scatter pass) into per-wave
+        # split by subid ONCE (one stable sort + slices) into per-wave
         # compacted views, so consumer wave w processes only its own
         # partition's rows instead of masking the FULL receive buffer —
         # O(data) total consumer input instead of O(data × waves).
@@ -3029,10 +3034,14 @@ class MeshExecutor:
     def _merge_outputs(self, outs: List[DeviceGroupOutput],
                        task0: Task) -> DeviceGroupOutput:
         """Merge all waves' partitioned outputs per device in ONE W-way
-        concat + recompact program (O(W·cap) data movement, one
-        compilation per (shape, W)). Consumers treat the merged rows as
-        multiple producer contributions — combiner-bearing consumers
-        re-combine, concat consumers concat.
+        concat + recompact program (one compilation per (shape, W)).
+        Consumers treat the merged rows as multiple producer
+        contributions — combiner-bearing consumers re-combine, concat
+        consumers concat. Wave-partitioned outputs (a leading subid
+        column) come out grouped by subid — one stable single-key sort
+        (segment.group_by_lane) in place of the cumsum + scatter
+        compaction — so the reduce side's per-wave views are slices of
+        the merged output (_subid_split_program).
 
         Machine-combined producers (combine_key with a device combiner)
         additionally RE-COMBINE across waves here — the mesh analog of
@@ -3041,8 +3050,10 @@ class MeshExecutor:
         holds at most one row per key before any consumer reads it."""
         if len(outs) == 1:
             return outs[0]
-        with span("merge", waves=len(outs)):
-            return self._merge_waves(outs, task0)
+        with span("merge", waves=len(outs)) as sp:
+            out = self._merge_waves(outs, task0)
+            sp.set(ordered=out.subid_ordered)
+            return out
 
     def _merge_waves(self, outs: List[DeviceGroupOutput],
                      task0: Task) -> DeviceGroupOutput:
@@ -3065,9 +3076,8 @@ class MeshExecutor:
         # merge donates them wholesale: the W-way concat reuses their
         # HBM instead of holding W waves + the merge result live.
         donate = self._donation_on()
-        key = ("merge", ncols, caps, dtypes, donate,
-               (id(fc.fn), fc.nkeys, fc.nvals, has_subid)
-               if mc else None)
+        key = ("merge", ncols, caps, dtypes, donate, has_subid,
+               (id(fc.fn), fc.nkeys, fc.nvals) if mc else None)
         with self._lock:
             cached = self._programs.get(key)
         if cached is not None:
@@ -3105,6 +3115,14 @@ class MeshExecutor:
                         mask, tuple(merged[:nk]), tuple(merged[nk:])
                     )
                     merged = list(keys) + list(vals)
+                if has_subid and not mc:
+                    n, lane, rest = segment.group_by_lane(
+                        mask, merged[0], merged[1:]
+                    )
+                    return n.reshape(1), (lane,) + rest
+                # With a subid the re-combine above sorted by
+                # (validity, subid, keys) and this compaction is
+                # stable: grouped by subid as well.
                 n, packed = segment.compact_by_mask(mask, merged)
                 return n.reshape(1), tuple(packed)
 
@@ -3122,13 +3140,13 @@ class MeshExecutor:
             # Kind-level attribution: shape-keyed shared cache (see
             # the rowslice note). The machine-combining variant closes
             # over the user combine fn — content-fingerprinted for the
-            # cross-session key (plus its nkeys/nvals/subid config,
-            # which the trace branches on).
+            # cross-session key (plus its nkeys/nvals config, which
+            # the trace branches on).
             prog = self._obs_program(
-                prog, "merge", (ncols, caps, dtypes, donate, bool(mc)),
+                prog, "merge",
+                (ncols, caps, dtypes, donate, bool(mc), bool(has_subid)),
                 fns=(fc.fn,) if mc else (),
-                extra=(fc.nkeys, fc.nvals, bool(has_subid))
-                if mc else None,
+                extra=(fc.nkeys, fc.nvals) if mc else None,
             )
             with self._lock:
                 self._programs[key] = (prog, ())
@@ -3140,7 +3158,8 @@ class MeshExecutor:
         )
         return DeviceGroupOutput(
             list(cols), counts, sum(caps), task0.schema,
-            partitioned=True, subid=outs[0].subid, nmesh=self.nmesh,
+            partitioned=True, subid=has_subid, nmesh=self.nmesh,
+            subid_ordered=has_subid,
         )
 
     # -- subid pre-split (consumer half of the wave pipeline) -----------
@@ -3148,14 +3167,14 @@ class MeshExecutor:
     def _subid_wave_view(self, out: DeviceGroupOutput, task0: Task,
                          wave: int):
         """Consumer wave ``wave``'s compacted device view of a
-        wave-partitioned output: built ONCE per output by a single
-        linear scatter pass (no sorts — the one-hot-cumsum slotting the
-        sortless shuffle routing uses), then chained zero-copy by every
-        wave. Without it each of the W consumer waves re-reads the full
-        receive buffer and pays its whole masking/compaction/combine
-        pipeline on W× the rows it keeps. Returns None when the view
-        doesn't apply (resized output, W=1) — caller falls back to the
-        subid-filtering program."""
+        wave-partitioned output: built ONCE per output — one stable
+        sort by subid (the cross-wave merge's own; an output that is
+        not merged is ordered here) + one slice a region — then chained
+        zero-copy by every wave. Without it each of the W consumer
+        waves re-reads the full receive buffer and pays its whole
+        masking/compaction/combine pipeline on W× the rows it keeps.
+        Returns None when the view doesn't apply (resized output, W=1)
+        — caller falls back to the subid-filtering program."""
         W = (task0.name.num_shard + self.nmesh - 1) // self.nmesh
         if W <= 1 or out.cols is None or out.nmesh != self.nmesh:
             return None
@@ -3192,9 +3211,11 @@ class MeshExecutor:
             ) or 4
             if 2 * W * capr * rowbytes > budget:
                 return None
-        flat = self._subid_split_program(dtypes, W, cap, capr)(
-            out.counts, *out.cols
-        )
+        with span("split", waves=W, capr=capr,
+                  presorted=out.subid_ordered):
+            flat = self._subid_split_program(
+                dtypes, W, cap, capr, out.subid_ordered
+            )(out.counts, *out.cols)
         views = []
         for w in range(W):
             cols_w = list(flat[W + w * npay : W + (w + 1) * npay])
@@ -3238,18 +3259,21 @@ class MeshExecutor:
         return prog
 
     def _subid_split_program(self, dtypes: Tuple[str, ...], W: int,
-                             cap: int, capr: int):
-        """One pass: scatter each valid row to region subid*capr + its
-        running rank within that subid (one-hot cumsum slotting), then
-        emit the W regions as separate per-wave (counts, cols) outputs
-        — proper global arrays each consumer wave chains zero-copy."""
-        key = ("subidsplit", dtypes, W, cap, capr)
+                             cap: int, capr: int, presorted: bool):
+        """The W regions of a subid-grouped buffer as separate per-wave
+        (counts, cols) outputs — proper global arrays each consumer
+        wave chains zero-copy. Region w is ONE slice: the rows of subid
+        w are contiguous once the valid rows are grouped by subid,
+        which the cross-wave merge leaves them (``presorted``) and
+        segment.group_by_lane makes them otherwise."""
+        key = ("subidsplit", dtypes, W, cap, capr, presorted)
         with self._lock:
             cached = self._programs.get(key)
         if cached is not None:
             return cached[0]
         import jax
         import jax.numpy as jnp
+        from jax import lax
         from jax.sharding import PartitionSpec as P
 
         axis = mesh_axis(self.mesh)
@@ -3260,30 +3284,38 @@ class MeshExecutor:
             subid = cols[0]
             payload = cols[1:]
             valid = jnp.arange(cap, dtype=np.int32) < counts[0]
+            if not presorted:
+                # Front-packed already: the same rows stay valid.
+                _, subid, payload = segment.group_by_lane(
+                    valid, subid, payload
+                )
             lane = jnp.where(valid, subid, np.int32(W))
-            sel = lane[:, None] == jnp.arange(W, dtype=np.int32)
-            csum = jnp.cumsum(sel.astype(np.int32), axis=0)
-            wcounts = csum[-1]
-            off = jnp.take_along_axis(
-                csum, jnp.minimum(lane, np.int32(W - 1))[:, None],
-                axis=1,
-            )[:, 0] - 1
-            ok = valid & (lane < W) & (off < capr)
-            dest = jnp.where(ok, lane * np.int32(capr) + off,
-                             np.int32(W * capr))
-            bufs = []
-            for c in payload:
-                buf = jnp.zeros((W * capr + 1,) + c.shape[1:], c.dtype)
-                bufs.append(buf.at[dest].set(c, mode="drop"))
-            wave_counts = tuple(
-                jnp.minimum(wcounts[w], np.int32(capr)).reshape(1)
-                for w in range(W)
-            )
-            wave_cols = tuple(
-                bufs[j][w * capr : (w + 1) * capr]
-                for w in range(W) for j in range(npay)
-            )
-            return wave_counts + wave_cols
+            # Region w starts at the first row whose lane reaches w:
+            # W + 1 binary searches, not a [cap, W] scan.
+            starts = jnp.searchsorted(
+                lane, jnp.arange(W + 1, dtype=np.int32)
+            ).astype(np.int32)
+            wcounts = jnp.minimum(starts[1:] - starts[:-1],
+                                  np.int32(capr))
+            # capr rows of zeros behind the buffer: a slice never
+            # clamps (capr is a bucket, it may exceed cap).
+            padded = [
+                jnp.concatenate(
+                    [c, jnp.zeros((capr,) + c.shape[1:], c.dtype)]
+                )
+                for c in payload
+            ]
+            row = jnp.arange(capr, dtype=np.int32)
+            wave_cols = []
+            for w in range(W):
+                live = row < wcounts[w]
+                for c in padded:
+                    wave_cols.append(segment.zero_rows_unless(
+                        live, lax.dynamic_slice_in_dim(c, starts[w], capr)
+                    ))
+            return tuple(
+                wcounts[w].reshape(1) for w in range(W)
+            ) + tuple(wave_cols)
 
         col = P(axis)
         prog = jax.jit(shard_map(
@@ -3294,7 +3326,8 @@ class MeshExecutor:
             check_rep=False,
         ))
         prog = self._obs_program(prog, "subid_split",
-                                 (dtypes, W, cap, capr), fns=())
+                                 (dtypes, W, cap, capr, presorted),
+                                 fns=())
         with self._lock:
             self._programs[key] = (prog, ())
             while len(self._programs) > _PROGRAM_CACHE_MAX:

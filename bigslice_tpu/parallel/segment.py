@@ -114,6 +114,41 @@ def compact_by_mask(mask, cols):
     return mask.sum().astype(np.int32), tuple(out)
 
 
+#: Sort key of a row outside the mask in ``group_by_lane``: above every
+#: lane a valid row can carry, so those rows go last.
+_NO_LANE = np.iinfo(np.int32).max
+
+
+def zero_rows_unless(live, col):
+    """``col`` with the rows outside the bool[n] mask ``live`` read as
+    zeros (trailing dims follow their row)."""
+    import jax.numpy as jnp
+
+    return jnp.where(
+        live.reshape(live.shape + (1,) * (col.ndim - 1)), col,
+        jnp.zeros_like(col),
+    )
+
+
+def group_by_lane(mask, lane, payload):
+    """Front-pack the rows selected by ``mask`` GROUPED by the int32
+    column ``lane`` (ascending), order kept inside a lane: ONE stable
+    single-key sort with the payload riding along (vector columns by
+    permutation, ``sort_with_payload``). Returns (count, lane,
+    payload) — what ``compact_by_mask`` leaves (count = selected rows,
+    the tail reads as zeros) with every lane's rows contiguous, so a
+    consumer cuts a lane out as a slice instead of scattering to it."""
+    import jax.numpy as jnp
+
+    key = jnp.where(mask, lane, _NO_LANE)
+    (s_key,), s_payload = sort_with_payload((key,), 1, payload)
+    count = mask.sum().astype(np.int32)
+    live = jnp.arange(key.shape[0], dtype=np.int32) < count
+    return count, zero_rows_unless(live, s_key), tuple(
+        zero_rows_unless(live, c) for c in s_payload
+    )
+
+
 def segmented_combine(diff, s_vals, cfn):
     """Apply an associative combine within each segment of sorted rows.
 

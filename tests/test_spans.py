@@ -25,7 +25,7 @@ from bigslice_tpu.utils.trace import SpanRecorder, Tracer, span
 TABLE = ("session.run", "compile_tasks", "evaluate", "group",
          "shuffle_plan", "stage", "read", "decode", "assemble", "upload",
          "stage_wait", "mutex_wait", "dispatch", "settle", "merge",
-         "readback")
+         "split", "readback")
 WAVES = 4
 
 
@@ -241,6 +241,8 @@ def test_waved_reduce_leaves_every_span_of_the_table(waved):
     waves = groups * WAVES
     want = {"session.run": jobs, "compile_tasks": jobs, "evaluate": jobs,
             "group": groups, "shuffle_plan": groups, "merge": jobs,
+            # The reduce side's views of the merged output, built once.
+            "split": jobs,
             "stage": waves, "stage_wait": waves, "dispatch": waves,
             "settle": waves,
             # Only the map side reads its rows from the host.

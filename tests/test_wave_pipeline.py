@@ -333,20 +333,25 @@ def test_hash_reduce_kernel_matches_sort_kernel(mesh):
         assert cols3[0].is_deleted() and cols3[1].is_deleted()
 
 
-def test_subid_split_parity_and_engagement(mesh):
+@pytest.mark.parametrize("ndev,shards", [(8, 32), (4, 20)])
+def test_subid_split_parity_and_engagement(ndev, shards):
     """The one-pass subid pre-split (consumer waves chain on their own
     compacted partition rows instead of subid-filtering the full
     receive buffer) changes nothing observable: split on/off produce
     identical rows, and the split views actually engage (the producer's
-    wave-partitioned output grows per-wave views)."""
+    wave-partitioned output grows per-wave views). 8 devices run 4
+    waves, 4 devices 5."""
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(jax.devices()[:ndev]), ("shards",))
     rng = np.random.RandomState(31)
-    keys = rng.randint(0, 1 << 14, 32 * 80).astype(np.int32)
-    vals = rng.randint(1, 5, 32 * 80).astype(np.int32)
+    keys = rng.randint(0, 1 << 14, shards * 80).astype(np.int32)
+    vals = rng.randint(1, 5, shards * 80).astype(np.int32)
 
     def run(split):
         ex = MeshExecutor(mesh, prefetch_depth=1, subid_split=split)
         sess = Session(executor=ex)
-        res = sess.run(bs.Reduce(bs.Const(32, keys, vals),
+        res = sess.run(bs.Reduce(bs.Const(shards, keys, vals),
                                  lambda a, b: a + b))
         rows = sorted(res.rows())
         views = [
@@ -385,3 +390,269 @@ def test_subid_split_declines_under_budget(mesh):
         views = getattr(o, "_wave_views", None)
         if views is not None:
             assert views[1] is None  # declined, decline cached
+
+
+# ----------------------------------------------------------------------
+# The merged map-side output is grouped by subid by ONE stable sort and
+# the reduce side's views are slices of it: checked against a plain
+# numpy split, on wave outputs built by hand.
+
+from types import SimpleNamespace
+
+from bigslice_tpu.exec.meshexec import DeviceGroupOutput
+from bigslice_tpu.parallel import segment
+from bigslice_tpu.parallel import shuffle as shuffle_mod
+from bigslice_tpu.parallel.jitutil import bucket_size
+
+
+def _ct(dtype, shape=()):
+    return SimpleNamespace(dtype=np.dtype(dtype), shape=shape)
+
+
+def _task0(schema, combiner=None):
+    return SimpleNamespace(
+        schema=schema,
+        partitioner=SimpleNamespace(
+            combiner=combiner,
+            combine_key="mc" if combiner is not None else "",
+        ),
+    )
+
+
+#: name -> (devices, subids W, caps of the producer waves, how a wave's
+#: valid rows draw their subid, vector payload)
+SPLIT_CASES = {
+    # Ragged waves: every wave leaves invalid rows INSIDE the
+    # concatenation, and those rows carry in-range subids.
+    "ragged": (8, 4, (64, 64, 64, 64), "uniform", False),
+    "empty_subid": (8, 4, (64, 64, 64), "skip2", False),
+    # One subid holds nearly everything: capr is a bucket above cap.
+    "one_subid_nearly_all": (8, 4, (48, 48, 48), "heavy0", False),
+    "unequal_caps": (8, 4, (64, 80, 64, 96), "uniform", False),
+    "vector_payload": (8, 4, (64, 64, 64), "uniform", True),
+    "w_not_power_of_two": (8, 5, (64, 64, 64, 64, 64), "uniform", False),
+    "four_devices": (4, 6, (32, 32, 40, 32, 32, 32), "uniform", False),
+}
+
+
+def _draw_subid(rng, how, n, W):
+    if how == "skip2":
+        return rng.choice([w for w in range(W) if w != 2], n)
+    if how == "heavy0":
+        return np.where(rng.rand(n) < 0.97, 0, rng.randint(0, W, n))
+    return rng.randint(0, W, n)
+
+
+def _hand_built_waves(case, seed=0, full=False):
+    """(executor, wave outputs, task0, W, per-device host rows): the
+    host rows are each device's valid rows in wave order — what a
+    stable compaction of the concatenated waves keeps."""
+    from jax.sharding import Mesh
+
+    ndev, W, caps, how, vector = SPLIT_CASES[case]
+    mesh = Mesh(np.array(jax.devices()[:ndev]), ("shards",))
+    ex = MeshExecutor(mesh)
+    rng = np.random.RandomState(seed)
+    schema = [_ct("int32"), _ct("int32")]
+    if vector:
+        schema.append(_ct("float32", (3,)))
+    outs, host = [], [[] for _ in range(ndev)]
+    for cap in caps:
+        counts = (np.full(ndev, cap) if full
+                  else rng.randint(0, cap + 1, ndev)).astype(np.int32)
+        # Rows past a wave's count are garbage with in-range subids.
+        cols = [rng.randint(0, W, ndev * cap).astype(np.int32),
+                rng.randint(0, 1 << 20, ndev * cap).astype(np.int32),
+                rng.randint(1, 50, ndev * cap).astype(np.int32)]
+        if vector:
+            cols.append(rng.rand(ndev * cap, 3).astype(np.float32))
+        for d in range(ndev):
+            n = int(counts[d])
+            cols[0][d * cap : d * cap + n] = _draw_subid(rng, how, n, W)
+            host[d].append([c[d * cap : d * cap + n] for c in cols])
+        gcols, gcounts = shuffle_mod.place_global_columns(
+            mesh, cols, counts
+        )
+        outs.append(DeviceGroupOutput(
+            list(gcols), gcounts, cap, schema, partitioned=True,
+            subid=True, nmesh=ndev,
+        ))
+    rows = [
+        [np.concatenate([w[j] for w in waves])
+         for j in range(len(schema) + 1)]
+        for waves in host
+    ]
+    return ex, outs, _task0(schema), W, rows
+
+
+def _per_device(arr, ndev):
+    arr = np.asarray(arr)
+    return arr.reshape((ndev, arr.shape[0] // ndev) + arr.shape[1:])
+
+
+def _assert_views_match(views, rows, W):
+    """Region w of device d == the rows of subid w in their order in
+    ``rows[d]``, zeros behind them, at the bucket of the fullest
+    (device, subid) cell."""
+    ndev = len(rows)
+    fullest = max(
+        int((r[0] == w).sum()) for r in rows for w in range(W)
+    )
+    assert len(views) == W
+    for w, view in enumerate(views):
+        assert view.capacity == bucket_size(fullest)
+        assert not view.subid and view.partitioned
+        counts = np.asarray(view.counts)
+        for d in range(ndev):
+            sel = rows[d][0] == w
+            assert counts[d] == sel.sum()
+            for got, want in zip(view.cols, rows[d][1:]):
+                got = _per_device(got, ndev)[d]
+                np.testing.assert_array_equal(got[: sel.sum()],
+                                              want[sel])
+                assert not got[sel.sum():].any()
+
+
+@pytest.mark.parametrize("case", sorted(SPLIT_CASES))
+def test_wave_views_equal_numpy_split(case):
+    ex, outs, task0, W, rows = _hand_built_waves(case)
+    merged = ex._merge_waves(outs, task0)
+    _assert_views_match(ex._build_wave_views(merged, W), rows, W)
+
+
+@pytest.mark.parametrize("case", sorted(SPLIT_CASES))
+def test_merged_output_is_front_packed_and_subid_ordered(case):
+    """The merged output's contract: capacity sum(caps), counts exact,
+    the valid rows first and the tail zeros (as compact_by_mask leaves
+    them), grouped by subid with the order of arrival kept inside a
+    subid — the stable argsort of what compact_by_mask returns."""
+    ex, outs, task0, W, rows = _hand_built_waves(case)
+    caps = SPLIT_CASES[case][2]
+    ndev = len(rows)
+    merged = ex._merge_waves(outs, task0)
+    assert merged.subid and merged.subid_ordered and merged.partitioned
+    assert merged.capacity == sum(caps)
+    counts = np.asarray(merged.counts)
+    for d in range(ndev):
+        n = len(rows[d][0])
+        assert counts[d] == n
+        # compact_by_mask over the same concatenation keeps rows[d].
+        mask = np.zeros(sum(caps), bool)
+        mask[:n] = True
+        padded = [
+            np.concatenate([c, np.zeros((sum(caps) - n,) + c.shape[1:],
+                                        c.dtype)])
+            for c in rows[d]
+        ]
+        kept_n, kept = segment.compact_by_mask(mask, padded)
+        assert int(kept_n) == n
+        order = np.argsort(np.asarray(kept[0])[:n], kind="stable")
+        for got, want in zip(merged.cols, kept):
+            got = _per_device(got, ndev)[d]
+            np.testing.assert_array_equal(got[:n],
+                                          np.asarray(want)[:n][order])
+            assert not got[n:].any()
+
+
+def _op_shapes(text):
+    """Element counts of every tensor type in a StableHLO module."""
+    import re
+
+    return {
+        int(np.prod([int(x) for x in dims.split("x") if x]))
+        for dims in re.findall(r"tensor<((?:\d+x)+)[a-z]", text)
+    }
+
+
+def _split_on_one_device(W, cap, capr, presorted):
+    """(bs_subid_split for a mesh of one, its argument shapes)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:1]), ("shards",))
+    col = NamedSharding(mesh, P("shards"))
+    prog = MeshExecutor(mesh)._subid_split_program(
+        ("int32",) * 3, W, cap, capr, presorted
+    )
+    return prog, [jax.ShapeDtypeStruct((1,), np.int32, sharding=col)] + [
+        jax.ShapeDtypeStruct((cap,), np.int32, sharding=col)
+    ] * 3
+
+
+@pytest.mark.parametrize("presorted", [True, False])
+def test_split_program_holds_no_scatter_and_nothing_cap_by_w(presorted):
+    W, cap, capr = 8, 1 << 12, 1 << 10
+    prog, args = _split_on_one_device(W, cap, capr, presorted)
+    text = prog.lower(*args).as_text()
+    assert "bs_subid_split" in text
+    assert "scatter" not in text
+    assert text.count("stablehlo.sort") == (0 if presorted else 1)
+    # The widest array is a column with its capr rows of padding.
+    assert max(_op_shapes(text)) == cap + capr < cap * W
+
+
+def test_merge_program_with_a_subid_is_one_sort_and_no_scatter():
+    ex, outs, task0, W, rows = _hand_built_waves("ragged")
+    specs = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
+             for a in [o.counts for o in outs]
+             + [c for o in outs for c in o.cols]]
+    ex._merge_waves(outs, task0)
+    (prog,) = [v[0] for k, v in ex._programs.items() if k[0] == "merge"]
+    text = prog.lower(*specs).as_text()
+    assert "bs_merge" in text
+    assert text.count("stablehlo.sort") == 1
+    assert "scatter" not in text
+
+
+def test_split_temporaries_do_not_grow_with_the_number_of_waves():
+    """At a fixed receive capacity the split's scratch is O(cap): the
+    one-hot it replaced was cap x W."""
+    cap, capr = 1 << 16, 1 << 11
+
+    def temp(W):
+        prog, args = _split_on_one_device(W, cap, capr, True)
+        mem = prog.lower(*args).compile().memory_analysis()
+        return mem.temp_size_in_bytes
+
+    few, many = temp(8), temp(32)
+    # The lane and two padded columns; 24 more subids add no column.
+    assert many <= 4 * 3 * (cap + capr)
+    assert many - few < 4 * cap
+
+
+@pytest.mark.parametrize("case", ["ragged", "vector_payload"])
+def test_unordered_output_takes_the_same_split_program(case):
+    """An output nobody ordered (one wave as a map-side program leaves
+    it: front-packed, subids in no order, no merge ran) goes through
+    bs_subid_split as well, which orders it first: same views."""
+    ex, outs, _, W, _ = _hand_built_waves(case)
+    out, ndev = outs[0], SPLIT_CASES[case][0]
+    assert out.subid and not out.subid_ordered
+    counts = np.asarray(out.counts)
+    rows = [[_per_device(c, ndev)[d][: counts[d]] for c in out.cols]
+            for d in range(ndev)]
+    _assert_views_match(ex._build_wave_views(out, W), rows, W)
+    kinds = {k[0]: k[-1] for k in ex._programs if k[0] == "subidsplit"}
+    assert kinds == {"subidsplit": False}
+
+
+def test_machine_combined_merge_is_ordered_and_sliced():
+    """mc=True: the cross-wave re-combine sorts by (validity, subid,
+    key), so its output is subid-ordered too and the split slices it
+    without a sort of its own."""
+    ex, outs, task0, W, rows = _hand_built_waves("ragged", seed=3)
+    fc = SimpleNamespace(fn=lambda a, b: a + b, nkeys=1, nvals=1,
+                         device=True)
+    merged = ex._merge_waves(outs, _task0(task0.schema, combiner=fc))
+    assert merged.subid_ordered
+    want = []
+    for sub, key, val in rows:
+        acc = {}
+        for s, k, v in zip(sub.tolist(), key.tolist(), val.tolist()):
+            acc[(s, k)] = acc.get((s, k), 0) + v
+        keys = sorted(acc)
+        want.append([np.array([s for s, _ in keys], np.int32),
+                     np.array([k for _, k in keys], np.int32),
+                     np.array([acc[sk] for sk in keys], np.int32)])
+    _assert_views_match(ex._build_wave_views(merged, W), want, W)
+    kinds = {k[0]: k[-1] for k in ex._programs if k[0] == "subidsplit"}
+    assert kinds == {"subidsplit": True}
