@@ -12,7 +12,7 @@ measured-signal policies behind one chicken bit:
 ``BIGSLICE_ADAPTIVE`` — unset (or ``off``) = fully disengaged: no
 planner object exists, no adaptive code path executes, results and
 telemetry are bit-identical to the pre-adaptive executor (the same
-contract as BIGSLICE_SHUFFLE / BIGSLICE_SUBID_SPLIT). ``skew`` /
+contract as BIGSLICE_SHUFFLE). ``skew`` /
 ``spec`` / ``cost`` engage one policy; comma/plus-separated combos and
 ``all`` compose them. Unknown tokens fail loudly.
 

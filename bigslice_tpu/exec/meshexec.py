@@ -247,16 +247,6 @@ def _slack_rung(slack: float, need: float) -> float:
     return rung if rung > slack else slack + step
 
 
-def _fleet_aot_enabled() -> bool:
-    """Chicken bit for the multiprocess compile-telemetry lift: the
-    AOT seam now instruments multi-process SPMD meshes too (per-rank
-    attribution, merged post-hoc by the fleet plane);
-    ``BIGSLICE_FLEET_AOT=0`` restores the pre-fleet skip. Read lazily
-    per program build so tests and operators can flip it live."""
-    return os.environ.get("BIGSLICE_FLEET_AOT", "1").lower() \
-        not in ("0", "false", "off")
-
-
 class _AttendHostFallback(Exception):
     """A SelfAttend group's dep is not device-resident in the aligned
     row-sharded layout ring attention needs (producer ran host-tier,
@@ -626,9 +616,9 @@ class MeshExecutor:
                  device_budget_bytes: Optional[int] = None,
                  hash_aggregate: Optional[bool] = None,
                  prefetch_depth: Optional[int] = None,
-                 donate_buffers: Optional[bool] = None,
-                 subid_split: Optional[bool] = None,
-                 staging_arena: Optional[bool] = None):
+                 donate_buffers: bool = True,
+                 subid_split: bool = True,
+                 staging_arena: bool = True):
         self.mesh = mesh
         self.nmesh = int(mesh.devices.size)
         # Mesh topology (parallel/meshutil.MeshTopology): 1-D flat or
@@ -661,13 +651,9 @@ class MeshExecutor:
         # donated to the wave program, and per-wave partitioned outputs
         # are donated to the cross-wave merge program, so steady-state
         # waves reuse HBM instead of reallocating it. Gated on the
-        # backend actually implementing donation (jitutil probe).
-        if donate_buffers is None:
-            env = os.environ.get("BIGSLICE_DONATE_BUFFERS")
-            if env:
-                donate_buffers = env not in ("0", "false", "off")
-            else:
-                donate_buffers = True
+        # backend actually implementing donation (jitutil probe);
+        # donate_buffers=False is the tests' seam to the undonated
+        # programs a backend without donation runs.
         self.donate_buffers = bool(donate_buffers)
         # Subid pre-split (the wave pipeline's consumer-side half): a
         # wave-partitioned output read by a waved device consumer is
@@ -675,20 +661,17 @@ class MeshExecutor:
         # compacted views, so consumer wave w processes only its own
         # partition's rows instead of masking the FULL receive buffer —
         # O(data) total consumer input instead of O(data × waves).
-        # Chicken bit (BIGSLICE_SUBID_SPLIT=0) = the pre-pipeline
-        # behavior, for A/B and triage.
-        if subid_split is None:
-            env = os.environ.get("BIGSLICE_SUBID_SPLIT")
-            subid_split = env not in ("0", "false", "off") if env \
-                else True
+        # subid_split=False is the tests' seam to the unsplit
+        # consumption that multi-process meshes and a split declined
+        # under the device budget still take.
         self.subid_split = bool(subid_split)
         # Staging fast path (exec/staging.py): per-(schema, capacity)
         # reusable host arena + two-pass assembly replaces the
         # decode-copy → Frame.concat → pad-concat chain with one copy
         # per column into a recycled buffer, uploaded as one batched
-        # device_put per dep. Chicken bit (BIGSLICE_STAGING_ARENA=0 or
-        # staging_arena=False) = the pre-arena path, for A/B and the
-        # bit-identical parity test.
+        # device_put per dep. staging_arena=False is the tests' seam
+        # to the concat+pad assembly that object columns and dtype
+        # drift still take (staging.StagingFallback).
         self.staging_arena = staging_mod.StagingArena(
             enabled=staging_arena
         )
@@ -735,16 +718,17 @@ class MeshExecutor:
         # with classified combine ops (parallel/hashagg.py — the
         # combiningFrame analog, exec/combiner.go:56-99): replaces every
         # sort in the Reduce/JoinAggregate pipeline with scatter/gather
-        # probing. Default: on everywhere except real TPU hardware,
-        # where large irregular scatters are the unproven primitive and
-        # the bitonic sort pipeline is the measured-safe default until a
-        # Mosaic hash-table kernel lands (a CPU-mesh A/B showed sorts
-        # at ~40x a scatter pass there; not measured on a chip).
+        # probing. Off unless asked for (hash_aggregate=True,
+        # BIGSLICE_HASH_AGGREGATE=1, or a kernel selector's verdict):
+        # the default on every backend is the sort pipeline, which is
+        # what both cells of the benchmark measure on the chip; the
+        # chip's only reading of this lowering (PERF.md §6, PR 22) was
+        # 14 x the sort pipeline's warm run.
         if hash_aggregate is None:
             env = os.environ.get("BIGSLICE_HASH_AGGREGATE")
-            if env:
-                hash_aggregate = env not in ("0", "false", "off")
-        self._use_hashagg = hash_aggregate
+            hash_aggregate = bool(env) and env not in (
+                "0", "false", "off")
+        self._use_hashagg = bool(hash_aggregate)
         # Ops whose claim cascade overflowed (load factor ~1 /
         # adversarial keys): permanently back on the sort path, which
         # handles them without retries.
@@ -1966,8 +1950,7 @@ class MeshExecutor:
         so every rank takes the same path and dispatch never diverges
         across the gang. Each rank records its own compile/cache-hit
         attribution; the fleet merge (utils/fleettelemetry.py) adds
-        them post-hoc. ``BIGSLICE_FLEET_AOT=0`` restores the old
-        multiprocess skip as a chicken bit.
+        them post-hoc.
 
         ``fns``/``extra`` feed the cross-Session program cache
         (serve/programcache.py): ``fns`` is the complete list of user
@@ -1978,8 +1961,7 @@ class MeshExecutor:
         bits). A long-lived server's fresh Sessions get their
         executables back from that cache without touching XLA."""
         dev = self._device_telemetry()
-        if dev is None or (self.multiprocess
-                           and not _fleet_aot_enabled()):
+        if dev is None:
             return prog
         try:
             # Mesh shape + axis names key the digest: a 1-D and a 2-D
@@ -2808,10 +2790,10 @@ class MeshExecutor:
         # combining bounds each destination's load by the shard's
         # distinct-key count, typically well under capacity — and the
         # receive buffer (slack × capacity rows) is what the reduce-side
-        # combine must sort, the pipeline's single largest pass
-        # (BASELINE.md roofline). Low-reduction data overflows once,
-        # retries bigger, and the adapted slack is remembered per op so
-        # the probe cost is paid once per session, not per wave/run.
+        # combine must sort, the pipeline's single largest pass.
+        # Low-reduction data overflows once, retries bigger, and the
+        # adapted slack is remembered per op so the probe cost is paid
+        # once per session, not per wave/run.
         has_combiner = (task0.num_partition > 1
                         and task0.partitioner.combiner is not None)
         return self._slack_memo.get(
@@ -3516,8 +3498,8 @@ class MeshExecutor:
         staging paths — assembled without a ``Frame.concat``
         intermediate). The fast path assembles into reusable arena
         buffers and issues one batched device_put; the legacy
-        concat+pad path remains for object columns, dtype drift, and
-        the BIGSLICE_STAGING_ARENA=0 chicken bit."""
+        concat+pad path remains for object columns and dtype
+        drift."""
         tls = self._stage_tls
         schema = getattr(tls, "schema", None)
         stats = getattr(tls, "stats", None)
@@ -3659,13 +3641,6 @@ class MeshExecutor:
     # -- hash-aggregate gating --------------------------------------------
 
     def _hashagg_enabled(self) -> bool:
-        if self._use_hashagg is None:
-            import jax
-
-            # Unproven primitive on real TPU hardware (see __init__
-            # rationale); everywhere else the scatter path wins by a
-            # CPU-mesh A/B.
-            self._use_hashagg = jax.default_backend() != "tpu"
         return self._use_hashagg
 
     def _hash_combine_ops(self, opbase: str, fc, schema):

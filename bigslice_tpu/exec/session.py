@@ -814,9 +814,8 @@ class Session:
         wave-pipeline overlap accounting (staging vs exposed time,
         overlap-efficiency), and the ``device`` plane (compile/cost/
         memory attribution, HBM watermarks, donation effectiveness —
-        utils/devicetelemetry.py). bench.py records this next to
-        throughput so the perf trajectory carries overlap efficiency
-        and compile cost alongside rows/sec; tests assert skew flagging
+        utils/devicetelemetry.py). The benchmark's per-layer metrics
+        read it (benchmarks/README.md); tests assert skew flagging
         through it. Empty when the hub is disabled
         (BIGSLICE_TELEMETRY=0).
 

@@ -51,8 +51,7 @@ def spmd_session(mesh=None, parallelism: Optional[int] = None,
     Telemetry is fleet-wide: every signal family — compile
     attribution (the AOT seam now instruments multi-process meshes
     too; the SPMD same-driver contract keeps its signature bake and
-    fallback decisions identical on every rank,
-    ``BIGSLICE_FLEET_AOT=0`` restores the old skip), shuffle-boundary
+    fallback decisions identical on every rank), shuffle-boundary
     partition counts (each rank records its addressable shards at
     their global offsets — no hot-path collective), HBM watermarks,
     stragglers, exchange and recovery — records process-locally per

@@ -34,10 +34,9 @@ Store reads for different shards fan out on a small shared thread pool
 (``map_shards``) inside the wave prefetcher, so per-shard disk/GCS
 latency overlaps instead of accumulating.
 
-Knobs: ``BIGSLICE_STAGING_ARENA`` (default on; 0 = the pre-arena
-concat+pad path, for A/B and triage), ``BIGSLICE_STAGE_THREADS``
-(per-shard read fan-out, default 4, 0/1 = serial reads),
-``BIGSLICE_STAGING_ARENA_BYTES`` (retained free-buffer bound).
+Knobs: ``BIGSLICE_STAGE_THREADS`` (per-shard read fan-out, default 4,
+0/1 = serial reads), ``BIGSLICE_STAGING_ARENA_BYTES`` (retained
+free-buffer bound).
 """
 
 from __future__ import annotations
@@ -57,13 +56,6 @@ class StagingFallback(Exception):
     """Raised by ``assemble`` when the input shape is outside the fast
     path's contract (object columns, cross-shard dtype drift); the
     caller falls back to the legacy concat+pad upload."""
-
-
-def arena_default_enabled() -> bool:
-    env = os.environ.get("BIGSLICE_STAGING_ARENA")
-    if env:
-        return env not in ("0", "false", "off")
-    return True
 
 
 def stage_threads_default() -> int:
@@ -196,11 +188,9 @@ class StagingArena:
     misaligned buffers, always correct). ``mode`` is set lazily by the
     executor from ``staging_mode(mesh)``; unset behaves as norecycle."""
 
-    def __init__(self, enabled: Optional[bool] = None,
+    def __init__(self, enabled: bool = True,
                  max_bytes: Optional[int] = None,
                  mode: Optional[str] = None):
-        if enabled is None:
-            enabled = arena_default_enabled()
         self.enabled = bool(enabled)
         if max_bytes is None:
             env = os.environ.get("BIGSLICE_STAGING_ARENA_BYTES")

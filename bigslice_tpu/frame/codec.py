@@ -14,8 +14,8 @@ the frame buffer: no per-column ``np.load`` round-trip, no copy. Views are
 read-only and hold a reference to the buffer, so they survive the caller
 releasing its own reference. A header-only scan (``scan_frame``) walks row
 counts and column extents without touching payload bytes — for consumers
-staging from raw stream bytes (the ``bench.py staging`` microbench's
-counting pass; executor staging counts from decoded frame lengths).
+staging from raw stream bytes (executor staging counts from decoded
+frame lengths).
 
 ``BSF3`` (legacy) — numeric payloads are ``np.save`` containers. The
 reader stays: old spill files and caches keep decoding; only the writer

@@ -1,8 +1,8 @@
 """Vectorized host-string parsing: the wordcount/urls host sweep.
 
 The config-#2 Amdahl term is the host parse: per-line Python string ops
-cost ~µs/row while everything downstream runs on the device tier
-(BASELINE.md). This module drops per-row Python to zero for ASCII rows.
+cost ~µs/row while everything downstream runs on the device tier.
+This module drops per-row Python to zero for ASCII rows.
 
 The pipeline, one pass each:
 

@@ -20,7 +20,7 @@ device. ``JoinAggregate(a, b, a_fn, b_fn)``:
 On the mesh executor the whole join group is one SPMD program per
 device — two segmented reduces and one alignment sort, no host
 materialization; the shuffles ride the producer edges as all_to_all.
-This is the TPU lowering of the BASELINE.md "Reduce+Cogroup join"
+This is the TPU lowering of BASELINE.json's "Reduce+Cogroup join"
 headline shape. The host tier runs the same contract on numpy for
 ineligible inputs (host keys, non-traceable combine fns).
 """

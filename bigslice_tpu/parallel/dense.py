@@ -3,7 +3,7 @@
 When a Reduce's keys are dense int32 codes in ``[0, K)`` — dictionary
 encodings (frame/dictenc.py), categorical ids, bucketed features — the
 sort-dominated combine+shuffle pipeline (parallel/shuffle.py
-make_combine_shuffle_fn; BASELINE.md roofline) collapses to:
+make_combine_shuffle_fn) collapses to:
 
   1. per-shard dense value tables, one scatter-accumulate pass over the
      rows (no sorts, no overflow slack, no retries);

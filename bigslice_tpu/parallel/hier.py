@@ -57,12 +57,7 @@ from bigslice_tpu.parallel.shuffle import (
     partition_ids,
     route_to_buckets,
     send_capacity,
-    sortless_routing_default,
 )
-
-# Same lane-count bound as the 1-D shuffle's sortless default: above
-# it the [size, ndest] one-hot's O(n·ndest) work loses to the sort.
-SORTLESS_MAX_LANES = 32
 
 
 def exchange_plan(ndcn: int, nici: int, nparts: int, capacity: int,
@@ -103,7 +98,7 @@ def exchange_plan(ndcn: int, nici: int, nparts: int, capacity: int,
 
 
 def dcn_stage(mask1, dest_g, payload_cols, ndcn: int, cap2: int,
-              dcn_axis: str, sortless: bool, waved: bool = False):
+              dcn_axis: str, waved: bool = False):
     """Stage 2 of the hierarchical exchange — ONE implementation shared
     by the plain two-stage shuffle and the fused combine+shuffle reduce:
     received rows carry their destination group-index in ``dest_g``;
@@ -125,7 +120,7 @@ def dcn_stage(mask1, dest_g, payload_cols, ndcn: int, cap2: int,
     else:
         g2 = jnp.where(mask1, dest_g, np.int32(ndcn))
     d2, cols2, off2, counts2 = route_to_buckets(
-        g2, tuple(payload_cols), ndcn, sortless,
+        g2, tuple(payload_cols), ndcn,
     )
     in2 = (d2 < ndcn) & (off2 < cap2)
     row2 = jnp.where(in2, d2, ndcn)
@@ -174,11 +169,6 @@ def make_hier_shuffle_fn(ndcn: int, nici: int, nkeys: int,
     waved = plan["waved"]
     cap1 = plan["cap1"]
     cap2 = plan["cap2"]
-    # Per-stage routing lowering: the shared backend default (sort on
-    # real TPU, sortless on CPU meshes) with the lane-count bound.
-    base_sortless = sortless_routing_default()
-    sortless1 = base_sortless and nici <= SORTLESS_MAX_LANES
-    sortless2 = base_sortless and ndcn <= SORTLESS_MAX_LANES
 
     def body_masked(valid, *cols):
         size = cols[0].shape[0]
@@ -206,7 +196,7 @@ def make_hier_shuffle_fn(ndcn: int, nici: int, nkeys: int,
         # the fast axis. dest_g rides along as a payload column.
         stage1_cols = (dest_g.astype(np.int32),) + tuple(cols)
         d1, cols1, off1, counts1 = route_to_buckets(
-            dest_i, stage1_cols, nici, sortless1,
+            dest_i, stage1_cols, nici,
         )
         in1 = (off1 < cap1) & (d1 < nici)
         row1 = jnp.where(in1, d1, nici)
@@ -224,7 +214,7 @@ def make_hier_shuffle_fn(ndcn: int, nici: int, nkeys: int,
         # exchange's I².
         mask2, ov2, out_cols = dcn_stage(
             mask1, recv_cols[0], recv_cols[1:], ndcn, cap2, dcn_axis,
-            sortless2, waved=waved,
+            waved=waved,
         )
 
         # Global signals: any stage's bucket overflow anywhere, plus
@@ -293,8 +283,6 @@ def make_hier_combine_shuffle_fn(ndcn: int, nici: int, nkeys: int,
     # Stage 1 (the flat fused kernel over ICI) emits the quotient
     # column only when it routes more partitions than ICI lanes.
     stage1_waved = nparts > nici
-    sortless2 = (sortless_routing_default()
-                 and ndcn <= SORTLESS_MAX_LANES)
     fused1 = make_combine_shuffle_fn(
         nici, nkeys, nvals, cfn, ici_axis, seed,
         partition_fn=partition_fn, slack=slack, nparts=nparts,
@@ -322,7 +310,7 @@ def make_hier_combine_shuffle_fn(ndcn: int, nici: int, nkeys: int,
         mask_c, kc, vc = recombine(mask1, (gq,) + keys1, vals1)
         mask2, ov2, out_cols = dcn_stage(
             mask_c, kc[0], tuple(kc[1:]) + tuple(vc), ndcn, cap2,
-            dcn_axis, sortless2, waved=waved_out,
+            dcn_axis, waved=waved_out,
         )
         # fused1's signals are already psummed over ICI; lift both to
         # global totals.
@@ -346,20 +334,18 @@ class HierMeshReduceByKey:
     shuffle.MeshReduceByKey, so its results are the per-shard row sets
     the flat reduce produces.
 
-    ``fused`` (default: on for sort-routing backends, i.e. real TPU)
-    folds the map-side segmented combine into stage 1's routing sort by
-    reusing THE flat fused kernel (shuffle.make_combine_shuffle_fn) in
-    waved mode over the ICI axis: global shard ``s = g*I + i`` is
-    device ``s % I`` of the ICI group with subid ``s // I`` — which IS
-    the destination group — so the kernel's one (validity, lane, subid,
-    keys) sort segments the combine AND orders the ICI routing, and its
-    leading subid output column is exactly the dest-group payload stage
-    2 buckets on (dcn_stage). This drops the separate (validity, keys)
-    combine sort the unfused path pays before the routing sort — the
-    follow-up flagged when hier reduces landed. On sortless-routing
-    backends (CPU meshes) the unfused path's routing is already a
-    linear pass, so the default keeps it; parity between both paths is
-    pinned by test_hier.
+    ``fused`` (the default) folds the map-side segmented combine into
+    stage 1's routing sort by reusing THE flat fused kernel
+    (shuffle.make_combine_shuffle_fn) in waved mode over the ICI axis:
+    global shard ``s = g*I + i`` is device ``s % I`` of the ICI group
+    with subid ``s // I`` — which IS the destination group — so the
+    kernel's one (validity, lane, subid, keys) sort segments the
+    combine AND orders the ICI routing, and its leading subid output
+    column is exactly the dest-group payload stage 2 buckets on
+    (dcn_stage). This drops the separate (validity, keys) combine sort
+    the unfused path pays before the routing sort.
+    ``fused=False`` keeps that unfused path as the reference test_hier
+    pins the fused one against.
 
     ``donate=True`` donates the staged input buffers to the program
     (jitutil.jit_maybe_donate): wave-streamed callers that re-stage
@@ -380,9 +366,7 @@ class HierMeshReduceByKey:
         self.nshards = ndcn * nici
         self.capacity = capacity
         self.out_capacity = ndcn * send_capacity(capacity, ndcn, slack)
-        if fused is None:
-            fused = not sortless_routing_default()
-        self.fused = bool(fused)
+        self.fused = fused is None or bool(fused)
         ncols = nkeys + nvals
         cfn = segment.canonical_combine(combine_fn, nvals)
         combine_final = segment.make_segmented_reduce_masked(
@@ -394,8 +378,6 @@ class HierMeshReduceByKey:
             # serves segmentation and lane routing; out_cols[0] is the
             # subid = destination group.
             cap2 = send_capacity(capacity, ndcn, slack)
-            sortless2 = (sortless_routing_default()
-                         and ndcn <= SORTLESS_MAX_LANES)
             fused1 = make_combine_shuffle_fn(
                 nici, nkeys, nvals, cfn, ici_axis, seed, slack=slack,
                 nparts=self.nshards,
@@ -411,7 +393,7 @@ class HierMeshReduceByKey:
                 mask1, ov1, _bad, s1_cols = fused1.masked(mask0, *cols)
                 mask2, ov2, out_cols = dcn_stage(
                     mask1, s1_cols[0], s1_cols[1:], ndcn, cap2,
-                    dcn_axis, sortless2,
+                    dcn_axis,
                 )
                 overflow = (
                     lax.psum(ov1, dcn_axis)  # ov1 already psummed (ici)

@@ -4,8 +4,8 @@ ROADMAP item 4's second half. The executor has three lowerings for a
 keyed combine/shuffle boundary — the sort+segmented-scan pipeline, the
 open-addressed hash table (parallel/hashagg.py; Mosaic kernel on TPU,
 XLA scatter elsewhere), and the dense rank table (parallel/dense.py) —
-and until now the choice was hardcoded per platform (hash default-on
-for CPU meshes, default-off on real TPU, dense on declaration). Dato's
+and without a selector the choice is fixed (sort unless
+``hash_aggregate`` asks for the table, dense on declaration). Dato's
 argument (PAPERS.md) is that lowering decisions on dataflow
 accelerators should be kernel-granular and *measured*; this module is
 that decision maker.
@@ -258,8 +258,9 @@ class KernelSelector:
         gate verdict (keyutil + op classification + blacklist);
         ``dense_bound`` means a dense key space is declared/discovered
         (the rank-table lowering takes precedence, as it always has);
-        ``legacy_hash`` is what the platform default would have done —
-        the static baseline the measured probe must beat."""
+        ``legacy_hash`` is what the executor does with no selector
+        (its ``hash_aggregate`` setting) — the static baseline the
+        measured probe must beat."""
         dkey = (opbase, site)
         with self._lock:
             cached = self._decisions.get(dkey)
@@ -291,11 +292,11 @@ class KernelSelector:
 
     def _static_choice(self, opbase: str,
                        legacy_hash: bool) -> Tuple[str, str, dict]:
-        """The no-probe verdict. Off-TPU the scatter lowering wins by
-        the BASELINE round-5 A/B (same default the legacy gate
-        applies); on real TPU the legacy default was sort — the Mosaic
-        hash-aggregate kernel is what flips it, when it can serve the
-        shapes."""
+        """The no-probe verdict: off-TPU the scatter lowering (a
+        CPU-mesh A/B, no chip measurement), on real TPU the Mosaic
+        hash-aggregate kernel when it can serve the shapes, else the
+        executor's own setting. A backend gate that waits for a cell on
+        each side of the choice (ROADMAP C2)."""
         import jax
 
         evidence = {}

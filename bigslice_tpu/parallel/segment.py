@@ -95,8 +95,8 @@ def compact_by_mask(mask, cols):
 
     A survivor's packed position is its survivor rank (exclusive cumsum
     of the mask), so compaction is one cumsum + one scatter per column
-    — NOT a sort: on the sort-dominated roofline (BASELINE.md) this
-    pass was costing as much as the keyed combine it followed. Dropped
+    — NOT a sort (on the TPU the scatter itself lowers to one:
+    PERF.md §6, PR 29). Dropped
     rows scatter to the out-of-range drop lane; the vacated tail reads
     as zeros (callers slice to ``count``)."""
     import jax.numpy as jnp

@@ -93,42 +93,22 @@ def partition_ids(keys, nparts: int, seed: int, valid=None,
     return part, None, None
 
 
-def route_to_buckets(dest, cols, ndest: int, sortless: bool,
-                     kernel_counts=None):
-    """THE shared bucket-slot computation, both lowerings (used by the
-    1-D shuffle and each stage of the hierarchical 2-D shuffle, so the
-    routings cannot drift):
-
-    - SORTLESS (one-hot cumsum): a row's slot is its running count
-      among same-destination rows — order-preserving, no sort; the
-      CPU-mesh default (a 3-operand sort costs ~40× a linear pass
-      there, a CPU-mesh A/B; see sortless_routing_default for the
-      TPU gate).
-    - SORT: rows reorder by destination (payload follows via the
-      carried permutation); slots are arange minus bucket starts.
+def route_to_buckets(dest, cols, ndest: int, kernel_counts=None):
+    """THE shared bucket-slot computation (used by the 1-D shuffle and
+    each stage of the hierarchical 2-D shuffle, so the routings cannot
+    drift): rows reorder by destination with one routing sort (payload
+    follows via the carried permutation); a row's slot is its position
+    minus its bucket's start.
 
     ``dest`` int32[size] with values ≥ ndest parking at the drop
     sentinel. Returns (dest', cols', offsets, counts) where dest'/
-    cols' are the (possibly permuted) rows the offsets refer to and
-    counts int32[ndest] excludes sentinel rows."""
+    cols' are the permuted rows the offsets refer to and counts
+    int32[ndest] excludes sentinel rows."""
     import jax.numpy as jnp
 
-    size = dest.shape[0]
-    if sortless:
-        onehot = (dest[:, None]
-                  == jnp.arange(ndest, dtype=np.int32)[None])
-        csum = jnp.cumsum(onehot.astype(np.int32), axis=0)
-        counts = csum[-1]
-        offset = (
-            jnp.take_along_axis(
-                csum,
-                jnp.minimum(dest, np.int32(ndest - 1))[:, None],
-                axis=1,
-            )[:, 0] - 1
-        )
-        return dest, cols, offset, counts
     from bigslice_tpu.parallel.segment import sort_with_payload
 
+    size = dest.shape[0]
     (s_dest,), s_cols = sort_with_payload((dest,), 1, cols)
     counts = (
         kernel_counts if kernel_counts is not None
@@ -177,32 +157,12 @@ def bucket_exchange(axis: str, nshards: int, send_cap: int, dest_row,
     return valid_mask, out_cols
 
 
-def sortless_routing_default() -> bool:
-    """Whether combinerless shuffles use one-hot-cumsum routing instead
-    of the routing sort. Default: on everywhere except real TPU
-    hardware — same rationale and knob convention as the hash-aggregate
-    lowering (exec/meshexec.py BIGSLICE_HASH_AGGREGATE): the ~40x
-    sort-vs-linear-pass gap is a CPU-mesh measurement, while on TPU
-    the [size, ndest] one-hot cumsum multiplies HBM traffic and the
-    bitonic sort is the assumed-safe default (not measured).
-    Override with BIGSLICE_SORTLESS_SHUFFLE=1/0."""
-    import os
-
-    import jax
-
-    env = os.environ.get("BIGSLICE_SORTLESS_SHUFFLE")
-    if env:
-        return env not in ("0", "false", "off")
-    return jax.default_backend() != "tpu"
-
-
 def make_shuffle_fn(nshards: int, nkeys: int, capacity: int,
                     axis: str = "shards", seed: int = 0,
                     partition_fn: Optional[Callable] = None,
                     slack: float = 2.0,
                     use_pallas: Optional[bool] = None,
-                    nparts: Optional[int] = None,
-                    sortless: Optional[bool] = None):
+                    nparts: Optional[int] = None):
     """Build the per-device shuffle body (to be wrapped in shard_map).
 
     Operates on ``cols`` (each shape [capacity]) plus a valid-row count
@@ -242,20 +202,6 @@ def make_shuffle_fn(nshards: int, nkeys: int, capacity: int,
         capacity, nshards if waved else nparts, slack
     )
 
-    # Above this lane count the [size, ndest] one-hot rank cumsum's
-    # O(n·ndest) work overtakes the O(n log n) routing sort it
-    # replaces; combinerless shuffles on meshes that big keep the sort.
-    SORTLESS_MAX_LANES = 32
-    # Destination lane count is static: device lanes when waved,
-    # partition lanes otherwise (nparts <= nshards in that case).
-    ndest_static = nshards if waved else nparts
-    if sortless is None:
-        # The lane cap bounds only the *default* resolution; an
-        # explicit request (tests, aotcheck's lowering proofs) always
-        # gets the routing it named.
-        sortless = (sortless_routing_default()
-                    and ndest_static <= SORTLESS_MAX_LANES)
-
     def body_masked(valid, *cols):
         """Mask-based core: rows where ``valid`` route; returns
         (recv_valid_mask, overflow, out_cols) with received rows left in
@@ -267,15 +213,13 @@ def make_shuffle_fn(nshards: int, nkeys: int, capacity: int,
         # counted separately; invalid rows route to a virtual shard
         # that sorts last. The fused Pallas kernel (when engaged) also
         # returns the destination histogram, replacing the
-        # scatter-lowered bincount below.
-        # The sortless path derives counts from its own cumsum and the
-        # waved sort path re-derives per-DEVICE counts from the sorted
-        # lanes, so the fused kernel's histogram is only requested when
-        # the non-waved sort path will actually consume it.
+        # scatter-lowered bincount below. The waved path re-derives
+        # per-DEVICE counts from the sorted lanes, so the histogram is
+        # only requested when the non-waved path will consume it.
         part, bad, kernel_counts = partition_ids(
             keys, nparts, seed, valid=valid, partition_fn=partition_fn,
             use_pallas=use_pallas,
-            with_counts=not sortless and not waved,
+            with_counts=not waved,
         )
         n_bad = (
             jnp.int32(0) if bad is None
@@ -296,7 +240,7 @@ def make_shuffle_fn(nshards: int, nkeys: int, capacity: int,
             ndest = nparts
 
         s_part, s_cols, offset, counts = route_to_buckets(
-            part, cols, ndest, sortless,
+            part, cols, ndest,
             kernel_counts=kernel_counts if not waved else None,
         )
 
@@ -357,9 +301,9 @@ def make_combine_shuffle_fn(nshards: int, nkeys: int, nvals: int,
     ``(validity, destination[, subid], keys)`` yields intact equal-key
     segments (equal keys share a destination) whose combined survivors
     come out already destination-ordered — bucket slots then follow
-    from cumsum/scatter passes, no second sort. In the sort-dominated
-    roofline (BASELINE.md) this removes the single most expensive pass
-    group of the reduce pipeline.
+    from cumsum/scatter passes, no second sort: this removes the
+    single most expensive pass group of the sort-dominated reduce
+    pipeline.
 
     Guaranteed equivalences with combine-then-shuffle: the same set of
     combined rows reaches the same (device, subid) destinations, and

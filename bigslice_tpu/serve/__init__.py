@@ -17,9 +17,7 @@ long-lived server process owning the mesh. Two pieces make that real:
   per-tenant quotas and metrics, an optional ``ops/cache.py``-backed
   cross-request result cache, and a graceful drain on SIGTERM.
 
-``tools/sliceserve.py`` is the CLI entry; ``bench.py serve-qps``
-measures sustained QPS / p50 / p99 / warm-vs-cold first-request
-latency against it.
+``tools/sliceserve.py`` is the CLI entry.
 """
 
 from bigslice_tpu.serve.programcache import (  # noqa: F401

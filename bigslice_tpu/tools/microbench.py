@@ -2,8 +2,8 @@
 evaluator scheduling overhead, frame kernel throughputs, codec rates.
 
 Usage: python -m bigslice_tpu.tools.microbench [--quick]
-Prints one line per metric; no JSON contract (bench.py is the driver's
-headline benchmark).
+Prints one line per metric; no JSON contract (the benchmark of record
+is BENCHMARK.json + benchmarks/).
 """
 
 from __future__ import annotations

@@ -36,8 +36,8 @@ computes three actionable signal families:
 Surfaced three ways: ``prometheus_text()`` (the ``/debug/metrics``
 endpoint of utils/debughttp.py), ``status_lines()`` (live skew /
 straggler annotations in the utils/status.py display), and
-``summary()`` (the ``Session.telemetry_summary()`` dict that bench.py
-records next to throughput numbers). Each record additionally emits a
+``summary()`` (the ``Session.telemetry_summary()`` dict the
+benchmark's harness reads). Each record additionally emits a
 compact instant event through the session's eventer/tracer so
 ``tools/slicetrace.py`` can render skew/overlap sections offline.
 

@@ -65,8 +65,7 @@ class Sizes:
     pipeline, the prefetcher, donation and the dispatch window all
     run); the dense-table lowerings engage only when a shuffle's
     partition count equals the mesh size (meshexec ``_program``), so
-    the phases that are about them run one shard per device, as
-    bench.py's ``reduce`` and ``kmeans`` modes do."""
+    the phases that are about them run one shard per device."""
 
     shards: int
     kernel_rows: int       # kernels phase, rows per kernel call
@@ -93,10 +92,10 @@ REAL = Sizes(
     url_lines=1 << 20, url_domains=5000,
     kmeans_points=1 << 23,
     cuts=(
-        "reduce-generic: 2^18 rows (bench.py runs 2^24) and join: 2^18 "
-        "rows a side (bench.py runs 2^22), keys cut with them at 16 "
-        "rows a key: the TPU compiler takes 20-70 s for EACH multi-"
-        "operand sort of 2^21 rows (390 s for one map-side group "
+        "reduce-generic: 2^18 rows and join: 2^18 rows a side (the "
+        "cells owed at a real size: PERF.md section 7), keys cut with "
+        "them at 16 rows a key: the TPU compiler takes 20-70 s for "
+        "EACH multi-operand sort of 2^21 rows (390 s for one map-side group "
         "program, compiled for a described v5e), and a cold run must "
         "compile every program inside the smoke's time limit",
         "kmeans: 2^23 points (config 5 has 10M): one shard a device "

@@ -8,7 +8,7 @@ chip time: interpret-mode tests cannot see what Mosaic refuses. The
 code under test picks its TPU branches from ``jax.default_backend()``,
 which still says "cpu" here — the ``tpu_branches`` fixture steers it
 in the test, not through an option of the program. The full sweep with
-cost stats is ``python bench.py --aot-check`` (tools/aotcheck.py).
+cost stats is ``python -m bigslice_tpu.tools.aotcheck``.
 
 Everything that touches the topology lives in fixtures of THIS file:
 only one process may load the TPU's library, so nothing here runs at

@@ -220,7 +220,10 @@ def test_stragglers_cancelled_not_computed(monkeypatch):
     st = sess.telemetry.coded
     members = [t for t in iter_tasks(res.tasks)
                if getattr(t, "coded_group", None) is not None]
-    states = {t.state for t in members}
+    # A RUNNING straggler asked to stop settles at its next seam, which
+    # may come after run() has returned: wait for that transition (the
+    # timeout only bounds a hang), then read the states.
+    states = {t.wait_state(TaskState.OK, timeout=60) for t in members}
     assert states <= {TaskState.OK, TaskState.CANCELLED}
     cancelled = sum(1 for t in members
                     if t.state == TaskState.CANCELLED)

@@ -38,12 +38,6 @@ def pipeline():
     return mod
 
 
-def set_tpu_lowerings(mp):
-    """The sort pipeline the TPU takes, on the CPU mesh."""
-    mp.setenv("BIGSLICE_HASH_AGGREGATE", "0")
-    mp.setenv("BIGSLICE_SORTLESS_SHUFFLE", "0")
-
-
 def mesh_session(ndev, **kw):
     mesh = Mesh(np.array(jax.devices()[:ndev]), ("shards",))
     return Session(executor=MeshExecutor(mesh), **kw)
@@ -51,12 +45,12 @@ def mesh_session(ndev, **kw):
 
 # ------------------------------------------- (a) answers, mesh by mesh
 
-@pytest.mark.parametrize("lowering", ["tpu", "cpu"])
+@pytest.mark.parametrize("lowering", ["sort", "hash"])
 @pytest.mark.parametrize("ndev", [1, 2, 4])
 def test_q18_shaped_job_matches_the_reference(pipeline, monkeypatch,
                                               ndev, lowering):
-    if lowering == "tpu":
-        set_tpu_lowerings(monkeypatch)
+    if lowering == "hash":  # asked for; "sort" is every backend's default
+        monkeypatch.setenv("BIGSLICE_HASH_AGGREGATE", "1")
     data = pipeline.make_data(CFG, seed=28 + ndev)
     assert data.shards == 20 > ndev
     want = pipeline.reference(CFG, data)
@@ -112,11 +106,9 @@ def exchange_blocks(summary):
 
 @pytest.fixture(scope="module")
 def overflowing(tmp_path_factory):
-    """Two jobs of 3 waves on a mesh of 4 with the TPU's lowerings, in
-    one traced session: (blocks after job 1, blocks after job 2, the
-    executor's gauges, groups a job, trace events)."""
-    mp = pytest.MonkeyPatch()
-    set_tpu_lowerings(mp)
+    """Two jobs of 3 waves on a mesh of 4, in one traced session:
+    (blocks after job 1, blocks after job 2, the executor's gauges,
+    groups a job, trace events)."""
     path = str(tmp_path_factory.mktemp("exchange") / "trace.json")
     sess = mesh_session(4, trace_path=path)
     try:
@@ -129,7 +121,6 @@ def overflowing(tmp_path_factory):
         gauges = sess.executor.resource_stats()["gauges"]
     finally:
         sess.shutdown()
-        mp.undo()
     with open(path) as fp:
         events = [e for e in json.load(fp)["traceEvents"]
                   if e.get("pid") == trace_mod.SPAN_PID]
@@ -173,8 +164,7 @@ def test_retried_waves_dispatch_span_carries_attempt(overflowing):
     assert len(dispatches) - len(again) == 2 * (3 + 3)
 
 
-def test_mesh_of_one_has_no_exchange_block_and_no_ladder(monkeypatch):
-    set_tpu_lowerings(monkeypatch)
+def test_mesh_of_one_has_no_exchange_block_and_no_ladder():
     sess = mesh_session(1)
     try:
         res, groups = distinct_keys_job(sess, 3, 1, seed=3)

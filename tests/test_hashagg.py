@@ -383,6 +383,28 @@ def test_e2e_join_hash_path_matches_local():
     assert got == expect
 
 
+def test_default_keyed_combine_is_the_sort_pipeline(monkeypatch):
+    """With nothing asked for, an eligible combiner (``add`` over int32
+    keys) takes the sort pipeline on every backend — the CPU mesh builds
+    the family of programs the chip runs; ``hash_aggregate=True`` is
+    what routes it to the hash lowering."""
+    from bigslice_tpu.slicetype import ColType, Schema
+
+    monkeypatch.delenv("BIGSLICE_HASH_AGGREGATE", raising=False)
+    schema = Schema([ColType(np.dtype(np.int32), "", ()),
+                     ColType(np.dtype(np.int32), "", ())], 1)
+
+    class FC:  # minimal combiner stand-in for the gate call
+        fn = staticmethod(lambda a, b: a + b)
+        nvals = 1
+        dense_keys = None
+
+    default = MeshExecutor(_mesh(), auto_dense=False)
+    assert default._hash_combine_ops("op", FC(), schema) is None
+    asked = MeshExecutor(_mesh(), auto_dense=False, hash_aggregate=True)
+    assert asked._hash_combine_ops("op", FC(), schema) == ("add",)
+
+
 def test_float_keys_route_to_sort_lowering():
     """Float keys never take the hash lowering (ADVICE r5): the claim
     cascade slot-hashes bit patterns but compares with ==, so -0.0/0.0

@@ -140,8 +140,8 @@ def test_hier_reduce_fused_matches_unfused_and_oracle(meshes):
     """The fused hier reduce (map-side combine folded into stage 1's
     routing sort by reusing the flat make_combine_shuffle_fn in waved
     mode) produces the same per-shard row sets as the unfused path,
-    the flat reduce, and the Python oracle — pinned explicitly since
-    the CPU-mesh default is unfused (sortless routing)."""
+    the flat reduce, and the Python oracle; ``fused=False`` is kept as
+    that reference."""
     flat, grid = meshes
     rng = np.random.RandomState(21)
     cap = 512

@@ -893,8 +893,7 @@ def test_wave_stress_64_shards(mesh):
     """The north-star dispatcher shape: S=64 shards stream 8 waves
     through the 8-device mesh (wave-partitioned subid shuffle +
     waved re-combine). Regression guard for the control plane at
-    pod-scale task counts (the BenchmarkEval analog, recorded in
-    BASELINE.md)."""
+    pod-scale task counts (the BenchmarkEval analog)."""
     import time
 
     sess = Session(executor=MeshExecutor(mesh))

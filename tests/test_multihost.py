@@ -108,7 +108,9 @@ def test_mid_collective_kill_classified_fast():
     """Round-5 verdict #8: a peer SIGKILLed while an SPMD collective is
     EXECUTING (not between runs, not before launch) must surface on the
     survivor as a classified HostLostError fast — the in-flight
-    collective errors instead of hanging. Also pins the hyphenated
+    collective errors instead of hanging (the peer dies at its dispatch
+    seam once the survivor has announced its own dispatch: no timer
+    races the run, and only a hang fails). Also pins the hyphenated
     Gloo error spellings in the host-loss classifier, which this smoke
     discovered live."""
     env = dict(os.environ)

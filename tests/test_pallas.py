@@ -125,11 +125,10 @@ def test_shuffle_pallas_path_matches_xla_path():
 
     outs = []
     for use_pallas in (False, True):
-        # sortless pinned off: this test is the kernel-histogram
-        # (with_counts → kernel_counts) plumbing's value-parity
-        # coverage, which only the sort branch consumes.
+        # The kernel-histogram (with_counts → kernel_counts)
+        # plumbing's value-parity coverage.
         body = make_shuffle_fn(nshards, 1, cap, axis="s",
-                               use_pallas=use_pallas, sortless=False)
+                               use_pallas=use_pallas)
 
         def run(n_, keys_, vals_):
             c, o, out_cols = body(n_[0], keys_, vals_)

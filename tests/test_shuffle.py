@@ -205,10 +205,8 @@ def test_mesh_shuffle_pallas_hash_path(mesh):
 
     outs = {}
     for use_pallas in (False, True):
-        # sortless=False: keep the kernel_counts-consuming sort branch
-        # (the TPU-default routing) under test on the CPU mesh.
         body = shuffle_mod.make_shuffle_fn(
-            n, 1, cap, "shards", use_pallas=use_pallas, sortless=False
+            n, 1, cap, "shards", use_pallas=use_pallas
         )
 
         def stepped(cnt, k, v):
@@ -230,12 +228,10 @@ def test_mesh_shuffle_pallas_hash_path(mesh):
 
 
 @pytest.mark.parametrize("nparts_mult", [1, 3])
-def test_mesh_shuffle_sortless_parity(mesh, nparts_mult):
-    """One-hot-cumsum routing and the routing sort produce bit-identical
-    shuffles (both preserve within-bucket arrival order), flat and waved
-    — this is also the sort branch's only coverage on meshes small
-    enough that the lane-count bound would always pick sortless."""
-    import jax
+def test_mesh_shuffle_routing_matches_numpy(mesh, nparts_mult):
+    """The routing sort delivers to every partition exactly the rows a
+    numpy ``hash % nparts`` sends there, flat and waved (where partition
+    p arrives on device ``p % n`` under subid ``p // n``)."""
     from jax.sharding import PartitionSpec as P
 
     from bigslice_tpu.parallel.meshutil import get_shard_map
@@ -245,31 +241,40 @@ def test_mesh_shuffle_sortless_parity(mesh, nparts_mult):
     cap = 256
     per = 96
     nparts = n * nparts_mult
+    waved = nparts > n
     kc = [rng.randint(0, 500, per).astype(np.int32) for _ in range(n)]
     vc = [rng.randint(0, 100, per).astype(np.int32) for _ in range(n)]
     cols, counts = shuffle_mod.shard_columns(mesh, [kc, vc], [per] * n, cap)
 
-    outs = {}
-    for sortless in (False, True):
-        body = shuffle_mod.make_shuffle_fn(
-            n, 1, cap, "shards", nparts=nparts, sortless=sortless
-        )
+    body = shuffle_mod.make_shuffle_fn(n, 1, cap, "shards", nparts=nparts)
 
-        def stepped(cnt, k, v):
-            c, ov, out = body(cnt[0], k, v)
-            return c.reshape(1), ov, tuple(out)
+    def stepped(cnt, k, v):
+        c, ov, out = body(cnt[0], k, v)
+        return c.reshape(1), ov, tuple(out)
 
-        f = jax.jit(get_shard_map()(
-            stepped, mesh=mesh,
-            in_specs=(P("shards"), P("shards"), P("shards")),
-            out_specs=(P("shards"), P(),
-                       tuple(P("shards") for _ in range(2 + (nparts > n)))),
-            check_rep=False,
-        ))
-        oc, ov, out = f(counts, cols[0], cols[1])
-        outs[sortless] = (np.asarray(oc), int(ov),
-                          [np.asarray(c) for c in out])
-    np.testing.assert_array_equal(outs[False][0], outs[True][0])
-    assert outs[False][1] == outs[True][1] == 0
-    for a, b in zip(outs[False][2], outs[True][2]):
-        np.testing.assert_array_equal(a, b)
+    f = jax.jit(get_shard_map()(
+        stepped, mesh=mesh,
+        in_specs=(P("shards"), P("shards"), P("shards")),
+        out_specs=(P("shards"), P(),
+                   tuple(P("shards") for _ in range(2 + waved))),
+        check_rep=False,
+    ))
+    oc, ov, out = f(counts, cols[0], cols[1])
+    assert int(ov) == 0
+    out_cap = np.asarray(out[0]).shape[0] // n
+    chunks = shuffle_mod.unshard_columns(list(out), oc, out_cap)
+    got = {}
+    for dev in range(n):
+        subid = chunks[0][dev] if waved else np.zeros(
+            len(chunks[0][dev]), np.int32)
+        keys, vals = chunks[waved][dev], chunks[waved + 1][dev]
+        for p in np.unique(subid * n + dev):
+            sel = subid * n + dev == p
+            got[int(p)] = sorted(zip(keys[sel].tolist(),
+                                     vals[sel].tolist()))
+    keys, vals = np.concatenate(kc), np.concatenate(vc)
+    part = frame_ops.hash_host_column(keys, 0) % np.uint32(nparts)
+    want = {int(p): sorted(zip(keys[part == p].tolist(),
+                               vals[part == p].tolist()))
+            for p in np.unique(part)}
+    assert got == want

@@ -398,18 +398,9 @@ def test_telemetry_summary_is_json_serializable():
                  np.ones(4096, np.int32)),
         lambda a, b: a + b))
     s = sess.telemetry_summary()
-    json.dumps(s)  # must not raise (bench records it into BENCH json)
+    json.dumps(s)  # must not raise (the harness and CI record it)
     assert "ops" in s and "task_states" in s
     res.discard()
-
-
-def test_bench_emit_accepts_extra_fields(capsys):
-    import bench
-
-    bench.emit("m", 10.0, "rows/sec", 5.0, overlap_efficiency=0.42)
-    line = json.loads(capsys.readouterr().out)
-    assert line["overlap_efficiency"] == 0.42
-    assert line["vs_baseline"] == 2.0
 
 
 # ---------------------------------------------------- device plane
