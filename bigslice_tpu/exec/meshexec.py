@@ -3020,10 +3020,10 @@ class MeshExecutor:
         Consumers treat the merged rows as multiple producer
         contributions — combiner-bearing consumers re-combine, concat
         consumers concat. Wave-partitioned outputs (a leading subid
-        column) come out grouped by subid — one stable single-key sort
-        (segment.group_by_lane) in place of the cumsum + scatter
-        compaction — so the reduce side's per-wave views are slices of
-        the merged output (_subid_split_program).
+        column) come out grouped by subid — the compaction's one stable
+        single-key sort, keyed by subid (segment.group_by_lane) — so
+        the reduce side's per-wave views are slices of the merged
+        output (_subid_split_program).
 
         Machine-combined producers (combine_key with a device combiner)
         additionally RE-COMBINE across waves here — the mesh analog of

@@ -119,15 +119,11 @@ def dcn_stage(mask1, dest_g, payload_cols, ndcn: int, cap2: int,
         ) + tuple(payload_cols)
     else:
         g2 = jnp.where(mask1, dest_g, np.int32(ndcn))
-    d2, cols2, off2, counts2 = route_to_buckets(
+    cols2, counts2 = route_to_buckets(
         g2, tuple(payload_cols), ndcn,
     )
-    in2 = (d2 < ndcn) & (off2 < cap2)
-    row2 = jnp.where(in2, d2, ndcn)
-    o2 = jnp.where(in2, off2, 0)
-    send2 = jnp.minimum(counts2, cap2).astype(np.int32)
     mask2, out_cols = bucket_exchange(
-        dcn_axis, ndcn, cap2, row2, o2, send2, cols2,
+        dcn_axis, ndcn, cap2, counts2, cols2,
     )
     ov2 = jnp.maximum(counts2.max() - cap2, 0)
     return mask2, ov2, out_cols
@@ -195,15 +191,11 @@ def make_hier_shuffle_fn(ndcn: int, nici: int, nkeys: int,
         # ---- Stage 1: bucket by destination ICI lane, exchange on
         # the fast axis. dest_g rides along as a payload column.
         stage1_cols = (dest_g.astype(np.int32),) + tuple(cols)
-        d1, cols1, off1, counts1 = route_to_buckets(
+        cols1, counts1 = route_to_buckets(
             dest_i, stage1_cols, nici,
         )
-        in1 = (off1 < cap1) & (d1 < nici)
-        row1 = jnp.where(in1, d1, nici)
-        o1 = jnp.where(in1, off1, 0)
-        send1 = jnp.minimum(counts1, cap1).astype(np.int32)
         mask1, recv_cols = bucket_exchange(
-            ici_axis, nici, cap1, row1, o1, send1, cols1,
+            ici_axis, nici, cap1, counts1, cols1,
         )
         ov1 = jnp.maximum(counts1.max() - cap1, 0)
 

@@ -88,44 +88,19 @@ def sort_and_segment(nkeys: int, valid_mask, key_cols, payload):
     return s_invalid, s_keys, s_payload, diff
 
 
-def compact_by_mask(mask, cols):
-    """Front-compact rows selected by ``mask`` (stable; preserves the
-    relative order of survivors). Returns (count, cols). The one shared
-    implementation of the capacity+validity → front-packed conversion.
-
-    A survivor's packed position is its survivor rank (exclusive cumsum
-    of the mask), so compaction is one cumsum + one scatter per column
-    — NOT a sort (on the TPU the scatter itself lowers to one:
-    PERF.md §6, PR 29). Dropped
-    rows scatter to the out-of-range drop lane; the vacated tail reads
-    as zeros (callers slice to ``count``)."""
-    import jax.numpy as jnp
-
-    cols = tuple(cols)
-    size = cols[0].shape[0]
-    keep = mask.astype(np.int32)
-    rank = jnp.cumsum(keep).astype(np.int32) - keep
-    dest = jnp.where(mask, rank, np.int32(size))  # size = drop lane
-    out = []
-    for c in cols:
-        buf = jnp.zeros(c.shape, c.dtype)
-        buf = buf.at[dest].set(c, mode="drop")
-        out.append(buf)
-    return mask.sum().astype(np.int32), tuple(out)
-
-
 #: Sort key of a row outside the mask in ``group_by_lane``: above every
 #: lane a valid row can carry, so those rows go last.
 _NO_LANE = np.iinfo(np.int32).max
 
 
 def zero_rows_unless(live, col):
-    """``col`` with the rows outside the bool[n] mask ``live`` read as
-    zeros (trailing dims follow their row)."""
+    """``col`` with the rows outside the bool mask ``live`` (the
+    leading dims of ``col``) read as zeros; trailing dims follow their
+    row."""
     import jax.numpy as jnp
 
     return jnp.where(
-        live.reshape(live.shape + (1,) * (col.ndim - 1)), col,
+        live.reshape(live.shape + (1,) * (col.ndim - live.ndim)), col,
         jnp.zeros_like(col),
     )
 
@@ -135,9 +110,9 @@ def group_by_lane(mask, lane, payload):
     column ``lane`` (ascending), order kept inside a lane: ONE stable
     single-key sort with the payload riding along (vector columns by
     permutation, ``sort_with_payload``). Returns (count, lane,
-    payload) — what ``compact_by_mask`` leaves (count = selected rows,
-    the tail reads as zeros) with every lane's rows contiguous, so a
-    consumer cuts a lane out as a slice instead of scattering to it."""
+    payload): count = selected rows, the tail reads as zeros, and every
+    lane's rows are contiguous, so a consumer cuts a lane out as a
+    slice instead of scattering to it."""
     import jax.numpy as jnp
 
     key = jnp.where(mask, lane, _NO_LANE)
@@ -147,6 +122,24 @@ def group_by_lane(mask, lane, payload):
     return count, zero_rows_unless(live, s_key), tuple(
         zero_rows_unless(live, c) for c in s_payload
     )
+
+
+def compact_by_mask(mask, cols):
+    """Front-compact rows selected by ``mask`` (stable; preserves the
+    relative order of survivors). Returns (count, cols); the vacated
+    tail reads as zeros (callers slice to ``count``). The one shared
+    implementation of the capacity+validity → front-packed conversion.
+
+    It is ``group_by_lane`` with every survivor in one lane: ONE stable
+    single-key sort carrying every column. A scatter to the survivors'
+    ranks runs row by row on the TPU — one 2^17-row column costs what
+    three sorts of all columns do (PERF.md §5, PR 31)."""
+    import jax.numpy as jnp
+
+    count, _, packed = group_by_lane(
+        mask, jnp.zeros(mask.shape, np.int32), cols
+    )
+    return count, packed
 
 
 def segmented_combine(diff, s_vals, cfn):

@@ -6,9 +6,9 @@ streams pulled worker→worker over TCP with randomized read order
 collectives over ICI:
 
 1. each device hashes its rows' key prefixes (murmur-style mix, fused),
-2. rows are sorted by destination shard and scattered into fixed-capacity
-   per-destination buckets (static shapes — XLA requirement, SURVEY.md
-   §7.3(1)),
+2. rows are sorted by destination shard and each destination's run is
+   cut out as one fixed-capacity bucket — a slice, not a scatter
+   (static shapes — XLA requirement, SURVEY.md §7.3(1)),
 3. one ``all_to_all`` moves the buckets; a second tiny ``all_to_all``
    carries the per-destination row counts,
 4. receivers compact their buckets into a (rows, count) pair.
@@ -93,55 +93,89 @@ def partition_ids(keys, nparts: int, seed: int, valid=None,
     return part, None, None
 
 
-def route_to_buckets(dest, cols, ndest: int, kernel_counts=None):
-    """THE shared bucket-slot computation (used by the 1-D shuffle and
-    each stage of the hierarchical 2-D shuffle, so the routings cannot
-    drift): rows reorder by destination with one routing sort (payload
-    follows via the carried permutation); a row's slot is its position
-    minus its bucket's start.
-
-    ``dest`` int32[size] with values ≥ ndest parking at the drop
-    sentinel. Returns (dest', cols', offsets, counts) where dest'/
-    cols' are the permuted rows the offsets refer to and counts
-    int32[ndest] excludes sentinel rows."""
+def lane_counts(lane, nlanes: int, mask=None):
+    """Rows a lane, int32[nlanes], of the rows selected by ``mask``
+    (all rows without one): a compare-and-sum over the lanes, which
+    are few (a mesh axis). A scatter-add of the rows into so few bins
+    serialises on the TPU (PERF.md §5, PR 31); lanes outside
+    [0, nlanes) count nowhere."""
     import jax.numpy as jnp
 
+    hit = lane[None, :] == jnp.arange(nlanes, dtype=np.int32)[:, None]
+    if mask is not None:
+        hit = hit & mask[None, :]
+    return hit.sum(axis=1).astype(np.int32)
+
+
+def route_to_buckets(dest, cols, ndest: int, kernel_counts=None):
+    """THE shared routing sort (used by the 1-D shuffle and each stage
+    of the hierarchical 2-D shuffle, so the routings cannot drift):
+    rows reorder by destination with one stable sort (payload follows
+    via the carried permutation), which leaves every destination's
+    rows one contiguous run for ``bucket_exchange`` to slice.
+
+    ``dest`` int32[size] with values ≥ ndest parking at the drop
+    sentinel (they sort last). Returns (cols', counts): the permuted
+    rows and each destination's row count, int32[ndest], sentinel rows
+    excluded."""
     from bigslice_tpu.parallel.segment import sort_with_payload
 
-    size = dest.shape[0]
-    (s_dest,), s_cols = sort_with_payload((dest,), 1, cols)
+    _, s_cols = sort_with_payload((dest,), 1, cols)
     counts = (
         kernel_counts if kernel_counts is not None
-        else jnp.bincount(s_dest, length=ndest + 1)[:ndest]
+        else lane_counts(dest, ndest)
     )
-    starts = jnp.concatenate(
-        [jnp.zeros(1, np.int32),
-         jnp.cumsum(counts).astype(np.int32)[:-1]]
-    )
-    offset = jnp.arange(size, dtype=np.int32) - jnp.take(
-        starts, jnp.minimum(s_dest, ndest - 1)
-    )
-    return s_dest, s_cols, offset, counts
+    return s_cols, counts
 
 
-def bucket_exchange(axis: str, nshards: int, send_cap: int, dest_row,
-                    dest_off, send_counts, cols):
-    """Scatter rows into per-destination send buckets and run the two
-    all_to_alls (counts then data). ``dest_row`` is each row's
-    destination device lane (``nshards`` = drop), ``dest_off`` its slot
-    within that bucket, ``send_counts`` int32[nshards] the (clipped)
-    rows per destination. Returns (recv_valid_mask, out_cols) with
-    out_cols holding ``nshards * send_cap`` rows — bucket from each
-    source shard, row j of source bucket s valid iff j < recv_counts[s].
-    Shared by the routing-sort shuffle and the fused combine+shuffle."""
+def bucket_exchange(axis: str, nshards: int, send_cap: int, counts,
+                    cols):
+    """Cut rows into per-destination send buckets and run the two
+    all_to_alls (counts then data). ``cols`` hold the rows GROUPED by
+    destination device lane and front-packed (``route_to_buckets``,
+    ``segment.group_by_lane``) and ``counts`` the rows a lane, int32 of
+    at most ``nshards`` lanes (a mesh padded beyond the partitions
+    sends its trailing devices nothing). Lane ``d`` starts at the
+    exclusive cumsum of ``counts`` and its bucket is the ONE slice of
+    ``send_cap`` rows from there, with the rows past the lane's count
+    — the next lane's — masked to zero; a lane over ``send_cap`` sends
+    its first ``send_cap`` rows (the caller reports the excess).
+    Returns (recv_valid_mask, out_cols) with out_cols holding
+    ``nshards * send_cap`` rows — bucket from each source shard, row j
+    of source bucket s valid iff j < recv_counts[s]. Shared by the
+    routing-sort shuffle, the fused combine+shuffle and both stages of
+    the 2-D shuffle (``hier.py``).
+
+    The cut unrolls ``nshards`` slices a column and ``lane_counts`` a
+    ``[nlanes, n]`` compare. Known bound: 8 lanes is the most that has
+    been compiled (the tests' meshes; ``tools/aotcheck`` for a described
+    v5e:2x4) — a larger mesh rechecks the compile before it relies on
+    this form (one gather of ``starts[d] + arange(send_cap)`` is the
+    other way)."""
     import jax.numpy as jnp
     from jax import lax
 
+    from bigslice_tpu.parallel.segment import zero_rows_unless
+
+    counts = jnp.concatenate(
+        [counts.astype(np.int32),
+         jnp.zeros(nshards - counts.shape[0], np.int32)]
+    )
+    starts = jnp.cumsum(counts).astype(np.int32) - counts
+    send_counts = jnp.minimum(counts, send_cap)
+    row_in_bucket = jnp.arange(send_cap, dtype=np.int32)
+    live = row_in_bucket[None, :] < send_counts[:, None]
     out_buckets = []
     for c in cols:
-        buf = jnp.zeros((nshards + 1, send_cap) + c.shape[1:], c.dtype)
-        buf = buf.at[dest_row, dest_off].set(c, mode="drop")
-        out_buckets.append(buf[:nshards])
+        # send_cap rows of zeros behind the column: a slice never
+        # clamps, wherever a lane starts.
+        padded = jnp.concatenate(
+            [c, jnp.zeros((send_cap,) + c.shape[1:], c.dtype)]
+        )
+        out_buckets.append(zero_rows_unless(live, jnp.stack([
+            lax.dynamic_slice_in_dim(padded, starts[d], send_cap)
+            for d in range(nshards)
+        ])))
     recv_counts = lax.all_to_all(
         send_counts.reshape(nshards, 1), axis, 0, 0, tiled=False
     ).reshape(nshards)
@@ -151,7 +185,6 @@ def bucket_exchange(axis: str, nshards: int, send_cap: int, dest_row,
     ]
     out_cols = [r.reshape((nshards * send_cap,) + r.shape[2:])
                 for r in recv]
-    row_in_bucket = jnp.arange(send_cap, dtype=np.int32)
     valid_mask = (row_in_bucket[None, :]
                   < recv_counts[:, None]).reshape(-1)
     return valid_mask, out_cols
@@ -212,9 +245,8 @@ def make_shuffle_fn(nshards: int, nkeys: int, capacity: int,
         # Out-of-range partitioner ids route to the drop lane and are
         # counted separately; invalid rows route to a virtual shard
         # that sorts last. The fused Pallas kernel (when engaged) also
-        # returns the destination histogram, replacing the
-        # scatter-lowered bincount below. The waved path re-derives
-        # per-DEVICE counts from the sorted lanes, so the histogram is
+        # returns the destination histogram in route_to_buckets' place.
+        # The waved path counts per DEVICE lane, so the histogram is
         # only requested when the non-waved path will consume it.
         part, bad, kernel_counts = partition_ids(
             keys, nparts, seed, valid=valid, partition_fn=partition_fn,
@@ -239,25 +271,14 @@ def make_shuffle_fn(nshards: int, nkeys: int, capacity: int,
         else:
             ndest = nparts
 
-        s_part, s_cols, offset, counts = route_to_buckets(
+        s_cols, counts = route_to_buckets(
             part, cols, ndest,
             kernel_counts=kernel_counts if not waved else None,
         )
-
-        # Scatter into (nshards, send_cap) send buckets; rows beyond
-        # capacity (or invalid) drop — reported via `overflow`.
-        in_bounds = (offset < send_cap) & (s_part < ndest)
-        dest_row = jnp.where(in_bounds, s_part, nshards)  # drop lane
-        dest_off = jnp.where(in_bounds, offset, 0)
-        send_counts = jnp.concatenate([
-            jnp.minimum(counts, send_cap).astype(np.int32),
-            jnp.zeros(nshards - ndest, np.int32),
-        ]) if ndest < nshards else jnp.minimum(
-            counts, send_cap
-        ).astype(np.int32)
+        # Rows beyond a bucket's capacity (or invalid) stay behind —
+        # reported via `overflow`.
         valid_mask, out_cols = bucket_exchange(
-            axis, nshards, send_cap, dest_row, dest_off, send_counts,
-            s_cols,
+            axis, nshards, send_cap, counts, s_cols,
         )
         # Bucket overflow (capacity skew — caller retries with slack)
         # and out-of-range partitioner ids (a user error — caller should
@@ -300,10 +321,10 @@ def make_combine_shuffle_fn(nshards: int, nkeys: int, nvals: int,
     destination is a pure function of its key prefix, so sorting once by
     ``(validity, destination[, subid], keys)`` yields intact equal-key
     segments (equal keys share a destination) whose combined survivors
-    come out already destination-ordered — bucket slots then follow
-    from cumsum/scatter passes, no second sort: this removes the
-    single most expensive pass group of the sort-dominated reduce
-    pipeline.
+    come out already destination-ordered — one single-key sort then
+    front-packs them by device lane (segment.group_by_lane) and every
+    send bucket is a slice (bucket_exchange); no second full-key sort,
+    no scatter.
 
     Guaranteed equivalences with combine-then-shuffle: the same set of
     combined rows reaches the same (device, subid) destinations, and
@@ -392,39 +413,25 @@ def make_combine_shuffle_fn(nshards: int, nkeys: int, nvals: int,
 
         is_last, red = segment.segmented_combine(diff, s_vals, cfn)
         keep = is_last & (s_invalid == 0)
-        keep_i32 = keep.astype(np.int32)
-
-        # Bucket slots without a sort: rows are dev-ordered, so a
-        # survivor's slot is its global survivor rank minus the rank at
-        # its device run's start (exclusive cumsum of per-lane counts;
-        # the sentinel lane sits last and is sliced off).
-        counts_all = jnp.zeros(nshards + 1, np.int32).at[s_dev].add(
-            keep_i32, mode="drop"
-        )
-        counts = counts_all[:nshards]
-        base = jnp.concatenate(
-            [jnp.zeros(1, np.int32),
-             jnp.cumsum(counts_all).astype(np.int32)[:-1]]
-        )
-        ex_keep = jnp.cumsum(keep_i32).astype(np.int32) - keep_i32
-        offset = ex_keep - jnp.take(base, s_dev)
 
         n_bad = (
             jnp.int32(0) if bad is None
             else (keep & (s_dev == nshards)).sum().astype(np.int32)
         )
 
-        in_bounds = keep & (offset < cap_send) & (s_dev < nshards)
-        dest_row = jnp.where(in_bounds, s_dev, nshards)
-        dest_off = jnp.where(in_bounds, offset, 0)
-        # Survivor rows hold their segment's full reduction.
-        payload = (
-            ((s_subid,) if waved else ()) + tuple(s_keys) + tuple(red)
+        # Survivors (each holds its segment's full reduction) are
+        # dev-ordered but sparse: front-pack them grouped by device
+        # lane, order inside a lane — (subid, key) — kept, so on
+        # overflow a lane sends its first cap_send survivors. The
+        # sentinel lane's rows stay behind.
+        routed = keep & (s_dev < nshards)
+        counts = lane_counts(s_dev, nshards, routed)
+        _, _, payload = segment.group_by_lane(
+            routed, s_dev,
+            ((s_subid,) if waved else ()) + tuple(s_keys) + tuple(red),
         )
-        send_counts = jnp.minimum(counts, cap_send).astype(np.int32)
         valid_mask, out_cols = bucket_exchange(
-            axis, nshards, cap_send, dest_row, dest_off, send_counts,
-            payload,
+            axis, nshards, cap_send, counts, payload,
         )
         total_overflow = lax.psum(
             jnp.maximum(counts.max() - cap_send, 0), axis

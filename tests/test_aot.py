@@ -70,9 +70,13 @@ def tpu_branches(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
-def _mosaic_calls(compiled, kernel: str) -> int:
+def _mosaic_calls_in(text: str, kernel: str) -> int:
     return sum(kernel in line and "tpu_custom_call" in line
-               for line in compiled.as_text().splitlines())
+               for line in text.splitlines())
+
+
+def _mosaic_calls(compiled, kernel: str) -> int:
+    return _mosaic_calls_in(compiled.as_text(), kernel)
 
 
 # The smoke's real widths: a wave of 2^21 rows through the partitioner,
@@ -214,3 +218,44 @@ def test_aot_hash_reduce_compiles_for_tpu(mesh, tpu_branches):
                         ).compile()
     assert _mosaic_calls(compiled, pk.HASH_AGGREGATE_KERNEL) == 2
     assert "all-to-all" in compiled.as_text()
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_aot_fused_map_side_wave_holds_no_scatter(topo, tpu_branches,
+                                                  chips):
+    """The map-side wave body — Mosaic partitioner, fused combine +
+    shuffle, the packing of what arrived — compiled for v5e on both of
+    the cells' layouts: sorts and slices, no ``scatter`` op (one of a
+    wave's rows runs row by row on the chip: PERF.md §5, PR 31)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from bigslice_tpu.parallel import pallas_kernels as pk
+    from bigslice_tpu.parallel import segment, shuffle
+    from bigslice_tpu.parallel.meshutil import get_shard_map
+
+    mesh = Mesh(np.array(topo.devices[:chips]), ("shards",))
+    size = 4096
+    fused = shuffle.make_combine_shuffle_fn(
+        chips, 1, 1, segment.canonical_combine(lambda a, b: a + b, 1),
+        "shards", slack=1.0, nparts=46 * chips)
+
+    def body(n, k, v):
+        mask = jnp.arange(size, dtype=np.int32) < n[0]
+        rm, ov, bad, oc = fused.masked(mask, k, v)
+        n_out, packed = segment.compact_by_mask(rm, oc)
+        return n_out.reshape(1), ov, bad, packed
+
+    row = P("shards")
+    fn = jax.jit(get_shard_map()(
+        body, mesh=mesh, in_specs=(row,) * 3,
+        out_specs=(row, P(), P(), (row,) * 3), check_rep=False,
+    ))
+    S = lambda rows: jax.ShapeDtypeStruct(  # noqa: E731
+        (chips * rows,), np.int32)
+    text = fn.lower(S(1), S(size), S(size)).compile().as_text()
+    assert " scatter(" not in text
+    assert text.count(" sort(") == 3
+    assert _mosaic_calls_in(text, pk.HASH_PARTITION_KERNEL) == 1
+    assert ("all-to-all" in text) == (chips > 1)

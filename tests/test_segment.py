@@ -170,3 +170,70 @@ class TestDeviceFold:
             oracle[k] = oracle.get(k, 0) + 1
         assert got == oracle
         assert sess.executor.device_group_count() >= 2
+
+
+def _mask_case(name, n, rng):
+    if name == "empty":
+        return np.zeros(n, bool)
+    if name == "full":
+        return np.ones(n, bool)
+    if name == "alternating":
+        return np.arange(n) % 2 == 1
+    if name == "last_row_only":
+        return np.arange(n) == n - 1
+    assert name == "random"
+    return rng.random(n) < 0.4
+
+
+@pytest.mark.parametrize("payload", ["int32", "float32", "int8+vector"])
+@pytest.mark.parametrize("mask_case", ["empty", "full", "alternating",
+                                       "last_row_only", "random"])
+def test_compact_by_mask_matches_numpy(mask_case, payload):
+    """Count, the survivors first in their order, a zero tail — with
+    garbage in the masked rows, scalar and vector columns."""
+    import jax
+
+    n = 96
+    rng = np.random.default_rng(len(mask_case) * 7 + len(payload))
+    mask = _mask_case(mask_case, n, rng)
+    if payload == "int8+vector":
+        cols = [rng.integers(-100, 100, n).astype(np.int8),
+                rng.normal(size=(n, 3)).astype(np.float32),
+                rng.integers(1, 1 << 30, n).astype(np.int32)]
+    else:
+        dt = np.dtype(payload)
+        cols = [(rng.integers(1, 1 << 20, n)).astype(dt),
+                (rng.integers(-50, 50, n) - 0.5).astype(dt)]
+    count, packed = jax.jit(segment.compact_by_mask)(mask, tuple(cols))
+    k = int(mask.sum())
+    assert int(count) == k and count.dtype == np.int32
+    assert len(packed) == len(cols)
+    for c, p in zip(cols, packed):
+        p = np.asarray(p)
+        assert p.shape == c.shape and p.dtype == c.dtype
+        np.testing.assert_array_equal(p[:k], c[mask])
+        assert not p[k:].any()
+
+
+def test_group_by_lane_packs_lanes_in_order_like_numpy():
+    """compact_by_mask's body with lanes: selected rows grouped by lane
+    ascending, order kept inside a lane, zero tail."""
+    import jax
+
+    n = 200
+    rng = np.random.default_rng(31)
+    mask = rng.random(n) < 0.6
+    lane = rng.integers(0, 5, n).astype(np.int32)
+    lane[~mask] = rng.integers(-9, 99, int((~mask).sum()))  # garbage
+    col = rng.integers(1, 1 << 20, n).astype(np.int32)
+    count, s_lane, (s_col,) = jax.jit(segment.group_by_lane)(
+        mask, lane, (col,))
+    order = np.argsort(lane[mask], kind="stable")
+    k = int(mask.sum())
+    assert int(count) == k
+    np.testing.assert_array_equal(np.asarray(s_lane)[:k],
+                                  lane[mask][order])
+    np.testing.assert_array_equal(np.asarray(s_col)[:k],
+                                  col[mask][order])
+    assert not np.asarray(s_lane)[k:].any()
+    assert not np.asarray(s_col)[k:].any()

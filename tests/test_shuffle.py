@@ -278,3 +278,125 @@ def test_mesh_shuffle_routing_matches_numpy(mesh, nparts_mult):
                                vals[part == p].tolist()))
             for p in np.unique(part)}
     assert got == want
+
+
+# ------------------------------------------------------- the bucket cut
+
+CUT_DEVICES = 4
+CUT_ROWS = 64
+CUT_SLACK = 2.0  # send_cap = 64 * 2 / 4 = 32 rows a (source, lane)
+
+
+def _cut_rows(scenario, nparts, rng):
+    """(keys, vals, valid) a source device: routing is ``key % nparts``
+    (lane ``key % 4``), so the keys choose the lanes. Invalid rows hold
+    garbage."""
+    n, rows = CUT_DEVICES, CUT_ROWS
+    keys, vals, valid = [], [], []
+    for dev in range(n):
+        k = rng.integers(0, 40 * nparts, rows)
+        ok = rng.random(rows) < 0.8
+        if scenario == "empty_lane":
+            k = k - k % n + rng.choice([0, 1, 3], rows)  # none to lane 2
+        elif scenario == "over_send_cap" and dev == 0:
+            # 48 distinct keys of lane 1, every one valid: 16 over
+            # at least.
+            k[:48] = 1 + n * rng.permutation(16 * nparts)[:48]
+            ok[:48] = True
+        elif scenario == "all_invalid":
+            ok[:] = False
+        keys.append(k.astype(np.int32))
+        vals.append(rng.integers(1, 100, rows).astype(np.int32))
+        valid.append(ok)
+    return keys, vals, valid
+
+
+def _cut_reference(kind, keys, vals, valid, nparts, send_cap):
+    """What each device receives: recv[d][s] = the (subid, key, val)
+    rows source s sends lane d in bucket order, clipped to the lane's
+    first ``send_cap``; and the summed excess of every source's fullest
+    lane."""
+    n = CUT_DEVICES
+    recv = [[None] * n for _ in range(n)]
+    overflow = 0
+    for s in range(n):
+        k, v = keys[s][valid[s]], vals[s][valid[s]]
+        if kind == "fused":  # combined: one row a key, its sum
+            k, inv = np.unique(k, return_inverse=True)
+            v = np.bincount(inv, weights=v, minlength=len(k)).astype(
+                np.int32)
+        part = k % nparts
+        lane, subid = part % n, part // n
+        order = (np.lexsort((k, subid, lane)) if kind == "fused"
+                 else np.argsort(lane, kind="stable"))
+        worst = 0
+        for d in range(n):
+            sel = order[lane[order] == d]
+            worst = max(worst, len(sel) - send_cap)
+            sel = sel[:send_cap]
+            recv[d][s] = (subid[sel], k[sel], v[sel])
+        overflow += worst
+    return recv, overflow
+
+
+@pytest.mark.parametrize("scenario", ["spread", "empty_lane",
+                                      "over_send_cap", "all_invalid"])
+@pytest.mark.parametrize("waved", [False, True])
+@pytest.mark.parametrize("kind", ["plain", "fused"])
+def test_bucket_cut_matches_numpy(kind, waved, scenario):
+    """The send buckets are slices of the lane-grouped rows: every
+    (source, lane) bucket holds the lane's rows in sorted order, its
+    first ``send_cap`` when the lane holds more, and ``overflow`` reads
+    the excess — for the combinerless and the fused body alike."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from bigslice_tpu.parallel import segment
+    from bigslice_tpu.parallel.meshutil import get_shard_map
+
+    n = CUT_DEVICES
+    nparts = n * 3 if waved else n
+    send_cap = shuffle_mod.send_capacity(CUT_ROWS, n, CUT_SLACK)
+    keys, vals, valid = _cut_rows(
+        scenario, nparts, np.random.default_rng(7 + 2 * waved))
+    pfn = lambda k: k % np.int32(nparts)  # noqa: E731
+    if kind == "fused":
+        body = shuffle_mod.make_combine_shuffle_fn(
+            n, 1, 1, segment.canonical_combine(lambda a, b: a + b, 1),
+            "shards", partition_fn=pfn, slack=CUT_SLACK, nparts=nparts)
+    else:
+        body = shuffle_mod.make_shuffle_fn(
+            n, 1, CUT_ROWS, "shards", partition_fn=pfn,
+            slack=CUT_SLACK, nparts=nparts)
+
+    def stepped(ok, k, v):
+        mask, ov, bad, cols = body.masked(ok, k, v)
+        return mask, ov, bad, tuple(cols)
+
+    mesh = Mesh(np.array(jax.devices()[:n]), ("shards",))
+    row = P("shards")
+    mask, ov, bad, cols = jax.jit(get_shard_map()(
+        stepped, mesh=mesh, in_specs=(row,) * 3,
+        out_specs=(row, P(), P(), (row,) * (2 + waved)),
+        check_rep=False,
+    ))(np.concatenate(valid), np.concatenate(keys), np.concatenate(vals))
+
+    want, want_ov = _cut_reference(kind, keys, vals, valid, nparts,
+                                   send_cap)
+    assert int(bad) == 0
+    assert int(ov) == want_ov
+    assert (want_ov >= 16) == (scenario == "over_send_cap")
+    mask = np.asarray(mask).reshape(n, n, send_cap)
+    cols = [np.asarray(c).reshape(n, n, send_cap) for c in cols]
+    for d in range(n):
+        for s in range(n):
+            subid, k, v = want[d][s]
+            np.testing.assert_array_equal(
+                mask[d, s], np.arange(send_cap) < len(k))
+            got = [c[d, s, :len(k)] for c in cols]
+            for g, w in zip(got, ((subid,) if waved else ()) + (k, v)):
+                np.testing.assert_array_equal(g, w)
+            assert not any(c[d, s, len(k):].any() for c in cols)
+    if scenario == "empty_lane":
+        assert not mask[2].any()
+    if scenario == "all_invalid":
+        assert not mask.any()

@@ -656,3 +656,88 @@ def test_machine_combined_merge_is_ordered_and_sliced():
     _assert_views_match(ex._build_wave_views(merged, W), want, W)
     kinds = {k[0]: k[-1] for k in ex._programs if k[0] == "subidsplit"}
     assert kinds == {"subidsplit": True}
+
+
+# ------------------------------------- the wave programs hold no scatter
+
+def _row_indexed_scatters(text):
+    """Index-tensor types of the scatters of a StableHLO module that
+    carry more than one scatter index. The static ``.at[0]`` /
+    ``.at[1:]`` / ``.at[:-1]`` updates of ``diff`` and ``is_last``
+    lower to scatters of ONE index (a window update, no scatter once
+    compiled) and are not listed."""
+    import re
+
+    found = []
+    for types in re.findall(
+            r'"stablehlo\.scatter"\(.*?\n\s*\}\) : \(([^)]*)\) ->', text,
+            flags=re.S):
+        types = re.findall(r"tensor<([^>]*)>", types)
+        index = types[len(types) // 2]  # operands, indices, updates
+        dims = [int(d) for d in index.split("x")[:-1]]
+        if int(np.prod(dims[:-1])) > 1:
+            found.append(index)
+    return found
+
+
+def test_row_indexed_scatter_predicate_tells_the_two_kinds_apart():
+    import jax.numpy as jnp
+
+    def body(x, i):
+        return (x.at[0].set(7).at[1:].set(x[:-1]),
+                jnp.zeros_like(x).at[i].set(x, mode="drop"))
+
+    text = jax.jit(body).lower(
+        jax.ShapeDtypeStruct((64,), np.int32),
+        jax.ShapeDtypeStruct((64,), np.int32)).as_text()
+    assert text.count('"stablehlo.scatter"') == 3
+    assert _row_indexed_scatters(text) == ["64x1xi32"]
+
+
+@pytest.mark.parametrize("ndev,rows", [(1, 1 << 17), (4, 1 << 13)])
+def test_wave_programs_hold_no_row_indexed_scatter(ndev, rows):
+    """The map-side, reduce-side and filter wave programs of a keyed
+    Reduce + Filter, as the executor builds them (``rows`` a shard, two
+    waves): compaction and the bucket fill are sorts and slices. A
+    scatter of a wave's rows runs row by row on the TPU (PERF.md §5,
+    PR 31)."""
+    import re
+
+    from jax.sharding import Mesh
+
+    rng = np.random.default_rng(ndev)
+    n = 2 * ndev * rows
+    # Sparse as dbgen's order keys (the first 8 of every 32), so the
+    # keyed combine is the generic one and not the dense table.
+    order = rng.integers(0, n // 4, n)
+    keys = (((order >> 3) << 5 | (order & 7)) + 1).astype(np.int32)
+    sess = Session(executor=MeshExecutor(
+        Mesh(np.array(jax.devices()[:ndev]), ("shards",))))
+    try:
+        agg = sess.run(bs.Reduce(
+            bs.Const(2 * ndev, keys, np.ones(n, np.int32)),
+            lambda a, b: a + b))
+        big = sess.run(bs.Filter(agg, lambda k, total: total > 4))
+        assert 0 < len(big.rows()) < n // 4
+        with sess.executor._lock:
+            programs = [p for p, _ in sess.executor._programs.values()
+                        if getattr(p, "_kind", None) == "group"]
+        seen = {}
+        for prog in programs:
+            for sig in prog._compiled:
+                # (shape, dtype[, sharding]) an argument.
+                text = prog.lower(*[
+                    jax.ShapeDtypeStruct(a[0], a[1], sharding=a[2])
+                    if len(a) > 2 else jax.ShapeDtypeStruct(*a)
+                    for a in sig]).as_text()
+                (name,) = set(re.findall(r"bs_group_\w+", text))
+                seen[name] = (_row_indexed_scatters(text),
+                              text.count("stablehlo.sort"))
+    finally:
+        sess.shutdown()
+    # Sorts: the fused (validity, lane, subid, key) sort, the lane
+    # grouping and the packing of what arrived; (validity, key) and
+    # the packing; the packing.
+    assert seen == {"bs_group_shuffle": ([], 3),
+                    "bs_group_combine": ([], 2),
+                    "bs_group_filter": ([], 1)}
