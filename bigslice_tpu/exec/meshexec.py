@@ -66,6 +66,7 @@ from bigslice_tpu.parallel import segment
 from bigslice_tpu.parallel.jitutil import (
     bucket_size,
     donation_supported,
+    jit,
     jit_maybe_donate,
 )
 from bigslice_tpu.parallel.meshutil import (
@@ -2102,6 +2103,72 @@ class MeshExecutor:
         except Exception:
             pass
 
+    def _telemetry_wave_host(self, task0: Task, field: str,
+                             dur_s: float) -> None:
+        """Host seconds of one wave's ``dispatch`` or ``settle`` span,
+        by op: the span table knows them by name only, and which
+        GROUP's waves cost the host what is the question a job of many
+        nearly empty reduce-side waves asks."""
+        hub = self._telemetry_hub()
+        if hub is None:
+            return
+        try:
+            hub.record_wave_host(task0.name.op, task0.name.inv_index,
+                                 field, dur_s)
+        except Exception:
+            pass
+
+    def _shuffle_lowering(self, task: Task) -> Optional[str]:
+        """Which lowering the map-side combine of ``task``'s shuffle
+        stage takes — "dense" (a table, then the static-routed
+        all_to_all of its planes or the routing shuffle of its rows),
+        "hash" (open-addressed aggregation) or "sort" (the fused sort
+        pipeline, or segmented reduce + routing sort) — None without a
+        combiner. ONE source of truth: the program builder branches on
+        it and the ``combine`` telemetry block reports it."""
+        part = task.partitioner
+        fc = part.combiner
+        if task.num_partition <= 1 or fc is None:
+            return None
+        nkeys = task.schema.prefix
+        dk = getattr(fc, "dense_keys", None)
+        if (dk is not None and part.partition_fn is None
+                and fc.nkeys == nkeys and not self.topo.is_hier):
+            from bigslice_tpu.parallel import dense as dense_mod
+
+            # One partition a device: the table's planes take a
+            # static-routed all_to_all. Otherwise (waved, or a key of
+            # several columns) a SMALL table's rows take the routing
+            # shuffle in the wave's place; a larger one would be filled
+            # by scatters, which the TPU runs row by row, and the sort
+            # pipeline keeps it (PERF.md §5).
+            if ((nkeys == 1 and task.num_partition == self.nmesh)
+                    or dense_mod.key_space(dk) <= dense_mod.SMALL_TABLE):
+                return "dense"
+        if (fc.nkeys == nkeys and not self.topo.is_hier
+                and self._hash_combine_ops(
+                    _op_base(task.name.op), fc, task.schema)
+                is not None):
+            return "hash"
+        return "sort"
+
+    def _telemetry_combine(self, task0: Task, rows_out: int) -> None:
+        """One group's map-side combine, for the per-op ``combine``
+        block: rows out (the group's merged output), the lowering and
+        how many 64-bit value columns it carried; rows in are the rows
+        the group's waves staged (``record_wave_staging``)."""
+        hub = self._telemetry_hub()
+        lowering = self._shuffle_lowering(task0)
+        if hub is None or lowering is None:
+            return
+        from bigslice_tpu.slicetype import is_wide
+
+        hub.record_combine_input(
+            task0.name.op, task0.name.inv_index, None, rows_out,
+            lowering=lowering,
+            wide_columns=sum(is_wide(ct.dtype)
+                             for ct in task0.schema.values))
+
     def _telemetry_compute(self, task0: Task, wave: int,
                            dur_s: float) -> None:
         hub = self._telemetry_hub()
@@ -2173,6 +2240,7 @@ class MeshExecutor:
                 [int(c) for c in counts],
                 [int(c) * rowbytes for c in counts],
             )
+            self._telemetry_combine(task0, int(counts.sum()))
         except Exception:
             pass
         finally:
@@ -2741,7 +2809,7 @@ class MeshExecutor:
             subn = jnp.clip(counts[0] - start, 0, B).astype(np.int32)
             return subn.reshape(1), sub
 
-        prog = jax.jit(shard_map(
+        prog = jit(shard_map(
             _named(stepped, "rowslice"), mesh=self.mesh,
             in_specs=(P(), P(axis)) + tuple(P(axis) for _ in range(ncols)),
             out_specs=(P(axis), tuple(P(axis) for _ in range(ncols))),
@@ -2833,6 +2901,7 @@ class MeshExecutor:
                 # Every dispatched attempt (first run and slack retries
                 # alike) put its buckets on the wire.
                 self._telemetry_exchange(task0, wave, inputs, slack)
+        self._telemetry_wave_host(task0, "dispatch_s", sp.seconds)
         return raw, stages, slack
 
     @staticmethod
@@ -2899,7 +2968,7 @@ class MeshExecutor:
              out_cols), stages, slack = first
             first = None
             has_shuffle = any(k == "shuffle" for k, _, _ in stages)
-            with span("settle", wave=wave):
+            with span("settle", wave=wave) as settling:
                 # The first read waits for the wave's program; this is
                 # where the host is blocked on the device.
                 gbover = int(np.asarray(gbover))
@@ -2907,6 +2976,8 @@ class MeshExecutor:
                 hashov = int(np.asarray(hashov))
                 overflow = (int(np.asarray(overflow))
                             if has_shuffle or is_cogroup else 0)
+            self._telemetry_wave_host(tasks[0], "settle_s",
+                                      settling.seconds)
             if gbover > 0:
                 # Checked BEFORE badrange: a strict capacity overflow
                 # must never trigger the auto-dense retraction path.
@@ -3227,7 +3298,7 @@ class MeshExecutor:
             )
             return sel.sum(0).astype(np.int32)  # [W] per device
 
-        prog = jax.jit(shard_map(
+        prog = jit(shard_map(
             _named(body, "subid_count"), mesh=self.mesh,
             in_specs=(P(axis), P(axis)),
             out_specs=P(axis), check_rep=False,
@@ -3300,7 +3371,7 @@ class MeshExecutor:
             ) + tuple(wave_cols)
 
         col = P(axis)
-        prog = jax.jit(shard_map(
+        prog = jit(shard_map(
             _named(body, "subid_split"), mesh=self.mesh,
             in_specs=(col,) + tuple(col for _ in range(npay + 1)),
             out_specs=tuple(col for _ in range(W))
@@ -3530,6 +3601,7 @@ class MeshExecutor:
                 pass
             else:
                 _stat_add(stats, "assemble_s", assembling.seconds)
+                _stat_add(stats, "rows", sum(counts))
                 with span("upload") as uploading:
                     cols, counts_arr = fileio.retry_transient(
                         lambda: shuffle_mod.place_global_columns(
@@ -3559,6 +3631,7 @@ class MeshExecutor:
             per_shard_cols, counts, capacity = (
                 self._assemble_legacy(shard_lists, schema))
         _stat_add(stats, "assemble_s", assembling.seconds)
+        _stat_add(stats, "rows", sum(counts))
         with span("upload") as uploading:
             cols, counts_arr = fileio.retry_transient(
                 lambda: shuffle_mod.shard_columns(
@@ -3665,6 +3738,11 @@ class MeshExecutor:
             # decision — the hash path has already proven too small
             # for this op's key cardinality.
             return None
+        if schema.wide:
+            # The hash-aggregate kernels admit 32-bit keys and values
+            # (pallas_kernels.py): a 64-bit column takes the sort
+            # pipeline or the dense table.
+            return None
         dense_bound = getattr(fc, "dense_keys", None) is not None
         if dense_bound and sel is None:
             # Declared/discovered dense bound: the rank-table lowering
@@ -3754,11 +3832,16 @@ class MeshExecutor:
         if wave != 0 or not self.auto_dense:
             return
         opb = _op_base(task0.name.op)
-        if opb in self._auto_dense_off:
+        if opb in self._auto_dense_off or any(
+                _op_base(t.name.op) in self._auto_dense_off
+                for d in task0.deps for t in d.tasks[:1]):
+            # This site misprobed before — or its producer's did (a
+            # small table is retracted on the map side, where its
+            # range is first checked): no second guess.
             return
         cand = self._dense_candidate(task0)
-        if cand is None:
-            return
+        if cand is None or getattr(cand, "nkeys", 1) != 1:
+            return  # the probe reads one key column
         from bigslice_tpu.parallel import dense as dense_mod
 
         cols, counts, capacity, has_sub, _owned = inputs[0]
@@ -3819,7 +3902,7 @@ class MeshExecutor:
                 return jnp.stack([lax.pmin(kmin, axis),
                                   lax.pmax(kmax, axis)])
 
-            prog = jax.jit(shard_map(
+            prog = jit(shard_map(
                 _named(body, "keyrange"), mesh=self.mesh,
                 in_specs=(P(axis), P(axis)),
                 out_specs=P(), check_rep=False,
@@ -3952,6 +4035,14 @@ class MeshExecutor:
                task.num_partition, len(task.schema),
                self._input_ncols(task), slack, subids, donate,
                self._op_hash_engaged(task, stages))
+        # A 64-bit column anywhere in the chain — an input's, a Map's
+        # out=, the output's — makes this a program of JAX's 64-bit
+        # mode (jitutil.ScopedJit); the arguments alone do not say so
+        # when a Map widens int32 inputs.
+        wide = any(s.schema.wide for s in task.chain) or any(
+            d.slice.schema.wide for d in task.chain[-1].deps())
+        if wide:
+            key = key + ("wide",)
         if self.kernel_select is not None:
             # The selector's live decision set keys the cache too:
             # a wave-boundary re-selection must rebuild the program,
@@ -4031,7 +4122,7 @@ class MeshExecutor:
             # its mesh position (waved groups shift partition indices),
             # and a table in the same league as the inputs (see the
             # combine-stage heuristic).
-            if (dkA is not None and dkA == dkB
+            if (dkA is not None and dkA == dkB and nk == 1
                     and s.num_shards == nmesh
                     # Table cost is maxc ≈ dk/nmesh per device — that,
                     # not the global key count, is what must stay in
@@ -4074,7 +4165,7 @@ class MeshExecutor:
             )
             return mask, cols, jnp.int32(0), jnp.int32(0)
 
-        def dense_gate(dk, key_col, mask, badrange):
+        def dense_gate(dk, key_cols, mask, badrange):
             """Declared-dense bookkeeping shared by the combine and
             fold stages: range violations count into the bad signal
             WHENEVER a bound is declared (the loud-failure contract
@@ -4089,12 +4180,14 @@ class MeshExecutor:
                 return None, badrange
             from jax import lax as _lax
 
+            from bigslice_tpu.parallel import dense as dense_mod
+
+            _, in_range = dense_mod.dense_code(
+                key_cols, dense_mod.key_dims(dk))
             badrange = badrange + _lax.psum(
-                jnp.sum((mask & ((key_col < 0) | (key_col >= dk))
-                         ).astype(np.int32)),
-                axis,
+                jnp.sum((mask & ~in_range).astype(np.int32)), axis,
             )
-            if dk > 2 * key_col.shape[0]:
+            if dense_mod.key_space(dk) > 2 * key_cols[0].shape[0]:
                 return None, badrange
             return dk, badrange
 
@@ -4257,8 +4350,8 @@ class MeshExecutor:
                 elif kind == "combine":
                     fc = s.frame_combiner
                     use_dk, badrange = dense_gate(
-                        getattr(fc, "dense_keys", None), cols[0],
-                        mask, badrange,
+                        getattr(fc, "dense_keys", None),
+                        cols[: fc.nkeys], mask, badrange,
                     )
                     hops = self._hash_combine_ops(opbase, fc, s.schema)
                     if use_dk is not None:
@@ -4304,7 +4397,7 @@ class MeshExecutor:
                 elif kind == "fold":
                     nk = s.prefix
                     use_dk, badrange = dense_gate(
-                        getattr(s, "dense_keys", None), cols[0],
+                        getattr(s, "dense_keys", None), cols[:1],
                         mask, badrange,
                     )
                     if use_dk is not None:
@@ -4368,8 +4461,8 @@ class MeshExecutor:
                     # kernel keeps the map-side combine (plus an
                     # ici-stage re-combine) before anything rides DCN.
                     hier_on = topo.is_hier
-                    if (dense_k is not None and pf is None
-                            and nkeys == 1 and not hier_on
+                    lowering = self._shuffle_lowering(s)
+                    if (lowering == "dense" and nkeys == 1
                             and s.num_partition == nmesh):
                         # Dense-coded keys: sort-free table combine +
                         # static-routed all_to_all (parallel/dense.py).
@@ -4386,10 +4479,34 @@ class MeshExecutor:
                         cols = list(cols)
                         overflow = overflow + ov
                         badrange = badrange + nb
-                    elif (fc is not None and fc.nkeys == nkeys
-                          and not hier_on
-                          and (shops := self._hash_combine_ops(
-                              opbase, fc, s.schema)) is not None):
+                    elif lowering == "dense":
+                        # A small table with more partitions than
+                        # devices (waved), or over a key of several
+                        # dictionary-coded columns: the table combine,
+                        # then its K rows — not the wave's — take the
+                        # routing shuffle.
+                        from bigslice_tpu.parallel import (
+                            dense as dense_mod,
+                        )
+
+                        _, badrange = dense_gate(
+                            dense_k, cols[:nkeys], mask, badrange)
+                        mask, keys, vals = dense_mod.make_dense_combine(
+                            dense_k, fc.dense_ops,
+                            [ct.dtype for ct in s.schema.values],
+                        )(mask, tuple(cols[:nkeys]),
+                          tuple(cols[nkeys:]))
+                        # Buckets that hold the whole (small) table:
+                        # its few keys hash unevenly, and no slack
+                        # ladder should chase that.
+                        body = shuffle_mod.make_shuffle_fn(
+                            nmesh, nkeys, dense_mod.key_space(dense_k),
+                            axis, slack=float(nmesh),
+                            nparts=s.num_partition,
+                        )
+                        mask, ov, nb, cols = body.masked(
+                            mask, *keys, *vals)
+                    elif lowering == "hash":
                         # Generic keys, classified ops: sortless fused
                         # combine+shuffle — the aggregation table is
                         # destination-contiguous, so the exchange is one
@@ -4400,7 +4517,9 @@ class MeshExecutor:
                         )
 
                         body = hashagg_mod.make_hash_combine_shuffle(
-                            nmesh, fc.nkeys, fc.nvals, shops,
+                            nmesh, fc.nkeys, fc.nvals,
+                            self._hash_combine_ops(opbase, fc,
+                                                   s.schema),
                             axis, partition_fn=pfn,
                             nparts=s.num_partition,
                         )
@@ -4520,7 +4639,7 @@ class MeshExecutor:
                        tuple(k for k, _, _ in stages)),
                 mesh=self.mesh, in_specs=in_specs,
                 out_specs=out_specs, check_rep=False),
-            tuple(donate_argnums),
+            tuple(donate_argnums), wide,
         )
         # Compile-telemetry seam: the op's SPMD group program, keyed by
         # the repr-stable half of the cache key (stage kinds, caps,

@@ -23,7 +23,7 @@ from typing import Any, Iterable, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from bigslice_tpu.slicetype import ColType, Schema
+from bigslice_tpu.slicetype import ColType, Schema, is_wide
 from bigslice_tpu.frame import ops as frame_ops
 
 
@@ -57,9 +57,8 @@ def _infer_coltype(col) -> ColType:
                 break
         return ColType(dt, tag)
     # Route through coltype() so the device-dtype whitelist applies to
-    # inferred ndarray columns too (a raw float64/int64 ndarray would
-    # otherwise smuggle a 64-bit column past _coerce's downcasts and
-    # corrupt hashing, which assumes ≤4-byte lanes).
+    # inferred ndarray columns too (a raw float64 ndarray must not get
+    # past _coerce's downcast).
     from bigslice_tpu.slicetype import coltype
 
     ct = coltype(dt)
@@ -69,6 +68,23 @@ def _infer_coltype(col) -> ColType:
     return ct
 
 
+def _narrowed(a: np.ndarray, to, j: int) -> np.ndarray:
+    """``a`` (64-bit integers, not declared so) as ``to``, or an
+    OverflowError naming column ``j`` and the value that does not fit."""
+    if a.size:
+        info = np.iinfo(to)
+        lo, hi = a.min(), a.max()
+        if lo < info.min or hi > info.max:
+            raise OverflowError(
+                f"column {j}: value {lo if lo < info.min else hi} does "
+                f"not fit {np.dtype(to)}, which an undeclared {a.dtype} "
+                f"column narrows to; declare the column 64-bit "
+                f"(schema=Schema([..., np.{a.dtype}, ...]) on a Const, "
+                f"out=[..., np.{a.dtype}, ...] on a Map or reader)"
+            )
+    return a.astype(to)
+
+
 class Frame:
     """An immutable columnar batch of rows."""
 
@@ -76,13 +92,14 @@ class Frame:
 
     def __init__(self, cols: Sequence[Any], schema: Optional[Schema] = None,
                  prefix: int = 1):
-        cols = [self._coerce(c) for c in cols]
-        if schema is None:
-            schema = Schema([_infer_coltype(c) for c in cols], prefix)
-        if len(cols) != len(schema):
+        if schema is not None and len(cols) != len(schema):
             raise ValueError(
                 f"frame has {len(cols)} columns but schema has {len(schema)}"
             )
+        cols = [self._coerce(c, j, schema[j] if schema is not None else None)
+                for j, c in enumerate(cols)]
+        if schema is None:
+            schema = Schema([_infer_coltype(c) for c in cols], prefix)
         n = None
         for c in cols:
             cn = int(c.shape[0])
@@ -94,7 +111,14 @@ class Frame:
         self.schema = schema
 
     @staticmethod
-    def _coerce(c):
+    def _coerce(c, j: int = 0, declared: Optional[ColType] = None):
+        """Column ``j`` as it enters a frame. The device tier is
+        32-bit-first (TPU-native; see slicetype): an int64 / uint64
+        ndarray or a list of Python ints is an int32 / uint32 column —
+        unless the schema DECLARES the column 64-bit, which keeps every
+        bit. The narrowing is checked (one min/max on the host, paid by
+        64-bit inputs only): a value that does not fit raises instead
+        of wrapping. float64 narrows to float32 unchecked, as ever."""
         if _is_jax_array(c):
             return c
         if not isinstance(c, np.ndarray):
@@ -103,13 +127,14 @@ class Frame:
                 return obj_col(list(c))
         else:
             a = c
-        # The device tier is 32-bit-first (TPU-native; see slicetype):
-        # 64-bit numerics are downcast on entry, for ndarray and list
-        # inputs alike.
+        if declared is not None and is_wide(declared.dtype):
+            if a.dtype != declared.dtype and a.dtype.kind in "iub":
+                a = a.astype(declared.dtype)
+            return a
         if a.dtype == np.int64:
-            a = a.astype(np.int32)
+            a = _narrowed(a, np.int32, j)
         elif a.dtype == np.uint64:
-            a = a.astype(np.uint32)
+            a = _narrowed(a, np.uint32, j)
         elif a.dtype == np.float64:
             a = a.astype(np.float32)
         return a

@@ -45,6 +45,10 @@ def _bits32(col):
     dt = np.dtype(col.dtype)
     xp = np if isinstance(col, np.ndarray) else jnp
     if dt.kind in ("i", "u", "b"):
+        if dt.itemsize == 8:
+            # Fold the high word into the low one: keys that differ
+            # only above bit 31 must not all share a partition.
+            col = col ^ (col >> 32)
         return col.astype(np.uint32)
     if dt.kind == "f" or dt.name == "bfloat16":
         # Normalize -0.0 to +0.0 so equal keys hash equally.

@@ -121,10 +121,13 @@ class Fold(Slice):
         try:
             import jax
 
-            acc_spec = jax.ShapeDtypeStruct((), self.acc_dtype)
-            val_specs = [jax.ShapeDtypeStruct((), ct.dtype)
-                         for ct in in_schema.values]
-            out = jax.eval_shape(self.fn, acc_spec, *val_specs)
+            from bigslice_tpu.parallel.jitutil import wide_scope
+
+            with wide_scope(in_schema.wide or self.schema.wide):
+                acc_spec = jax.ShapeDtypeStruct((), self.acc_dtype)
+                val_specs = [jax.ShapeDtypeStruct((), ct.dtype)
+                             for ct in in_schema.values]
+                out = jax.eval_shape(self.fn, acc_spec, *val_specs)
             if isinstance(out, (tuple, list)):
                 return False
             return out.shape == ()

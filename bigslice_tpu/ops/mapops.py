@@ -31,7 +31,7 @@ from bigslice_tpu.ops.base import (
     make_name,
     single_dep,
 )
-from bigslice_tpu.parallel.jitutil import get_padded_vmap
+from bigslice_tpu.parallel.jitutil import get_padded_vmap, wide_scope
 
 
 def _as_schema(out, default_prefix: int = 1) -> Schema:
@@ -46,12 +46,14 @@ _TRY_TRACE_CACHE_MAX = 256
 
 
 def _try_trace(fn: Callable, in_schema: Schema, extra: tuple = (),
-               why: list = None):
+               why: list = None, wide: bool = False):
     """Attempt an abstract trace of fn over scalar avals of the input
     columns (plus unbatched ``extra`` args). Returns the output Schema
     or None if fn must run host-tier; when ``why`` is passed, a reason
     string is appended on None returns that aren't plain
-    untraceability.
+    untraceability. The trace runs in JAX's 64-bit mode when an input
+    column is 64-bit or the caller's declared output is (``wide``):
+    outside it an int64 aval narrows to int32 and the traced dtypes lie.
 
     Memoized on (fn, input signature, extra-arg signature) — iterative
     drivers rebuild the same Map each round with fresh extra VALUES
@@ -61,9 +63,10 @@ def _try_trace(fn: Callable, in_schema: Schema, extra: tuple = (),
     stable-identity contract; recorded `why` reasons replay on hits."""
     if not all(ct.is_device for ct in in_schema):
         return None
+    wide = wide or in_schema.wide
     try:
         key = (
-            fn,
+            fn, wide,
             tuple((ct.dtype, ct.shape, ct.is_device) for ct in in_schema),
             tuple((tuple(np.shape(e)),
                    np.asarray(e).dtype if not hasattr(e, "dtype") else e.dtype)
@@ -78,7 +81,8 @@ def _try_trace(fn: Callable, in_schema: Schema, extra: tuple = (),
             why.extend(msgs)
         return out
     msgs: list = []
-    out = _try_trace_uncached(fn, in_schema, extra, msgs)
+    with wide_scope(wide):
+        out = _try_trace_uncached(fn, in_schema, extra, msgs)
     if key is not None:
         _TRY_TRACE_CACHE[key] = (out, tuple(msgs))
         while len(_TRY_TRACE_CACHE) > _TRY_TRACE_CACHE_MAX:
@@ -196,8 +200,14 @@ class Map(_Pipelined):
         self.args = tuple(args)
         traced = None
         why: list = []
+        # A 64-bit column in or out: classify, cast and run under
+        # JAX's 64-bit mode (a Map from int32 columns to an int64 one
+        # says so with out=; nothing else can).
+        self.wide = slice_.schema.wide or (
+            out is not None and _as_schema(out).wide)
         if mode in ("auto", "jax"):
-            traced = _try_trace(fn, slice_.schema, self.args, why=why)
+            traced = _try_trace(fn, slice_.schema, self.args, why=why,
+                                wide=self.wide)
         if traced is not None:
             self.mode = "jax"
             if out is None:
@@ -266,7 +276,8 @@ class Map(_Pipelined):
                 if not len(f):
                     continue
                 if self.mode == "jax":
-                    cols, n = self._vfn(f.cols, len(f), extra=self.args)
+                    cols, n = self._vfn(f.cols, len(f), extra=self.args,
+                                        wide=self.wide)
                     yield Frame(cols, self.schema)
                 else:
                     rows = [self.fn(*r, *self.args) for r in f.rows()]
@@ -415,9 +426,10 @@ class Flatmap(_Pipelined):
         try:
             import jax
 
-            specs = [jax.ShapeDtypeStruct((), ct.dtype)
-                     for ct in slice_.schema]
-            out = jax.eval_shape(fn, *specs)
+            with wide_scope(slice_.schema.wide or schema.wide):
+                specs = [jax.ShapeDtypeStruct((), ct.dtype)
+                         for ct in slice_.schema]
+                out = jax.eval_shape(fn, *specs)
         except Exception as e:
             raise typecheck.errorf(
                 "flatmap: fixed-fanout function is not jax-traceable "
@@ -470,7 +482,8 @@ class Flatmap(_Pipelined):
             for f in deps[0]():
                 if not len(f):
                     continue
-                outs, n = self._vfn(f.cols, len(f))
+                outs, n = self._vfn(f.cols, len(f),
+                                    wide=self.schema.wide)
                 mask = np.asarray(outs[0]).reshape(-1)
                 cols = [np.asarray(o).reshape(-1) for o in outs[1:]]
                 idx = np.flatnonzero(mask)

@@ -24,6 +24,7 @@ from bigslice_tpu.slicetype import Schema
 from bigslice_tpu import sliceio
 from bigslice_tpu.ops.base import Combiner, Dep, Slice, make_name
 from bigslice_tpu.parallel import segment
+from bigslice_tpu.parallel.jitutil import wide_scope
 
 
 _TRACE_CACHE: dict = {}
@@ -52,7 +53,8 @@ def _vals_traceable(fn: Callable, schema: Schema) -> bool:
         key = hit = None
     if hit is not None:
         return hit
-    out = _vals_traceable_uncached(fn, schema)
+    with wide_scope(schema.wide):
+        out = _vals_traceable_uncached(fn, schema)
     if key is not None:
         _TRACE_CACHE[key] = out
         while len(_TRACE_CACHE) > _TRACE_CACHE_MAX:
@@ -99,9 +101,11 @@ class FrameCombiner:
             else None
         )
         # Dense-key declaration (parallel/dense.py): keys are int32
-        # codes in [0, dense_keys). dense_ops is the per-column
-        # add/max/min classification; None (fn unclassifiable, wrong
-        # key shape/dtype, host tier) quietly keeps the sort lowering.
+        # codes in [0, dense_keys) — for a key of several columns, a
+        # tuple of per-column sizes (the dictionaries' lengths).
+        # dense_ops is the per-column add/max/min classification; None
+        # (fn unclassifiable, wrong key shape/dtype, host tier) quietly
+        # keeps the sort lowering.
         self.dense_keys = None
         self.dense_ops = None
         # Executors may auto-discover a dense bound from the data (a
@@ -114,27 +118,28 @@ class FrameCombiner:
             self.try_declare_dense(dense_keys)
 
     def dense_eligible(self) -> bool:
-        """Structural half of the dense contract: single scalar int32
-        key on the device tier. (The fn-classification half is checked
-        by try_declare_dense.)"""
-        return (self.device and self.nkeys == 1
-                and np.dtype(self.schema.cols[0].dtype)
-                == np.dtype(np.int32)
-                and self.schema.cols[0].shape == ())
+        """Structural half of the dense contract: scalar int32 key
+        columns on the device tier. (The fn-classification half is
+        checked by try_declare_dense.)"""
+        return self.device and all(
+            np.dtype(ct.dtype) == np.dtype(np.int32) and ct.shape == ()
+            for ct in self.schema.key)
 
-    def try_declare_dense(self, dense_keys: int) -> bool:
-        """Declare keys dense in [0, dense_keys); True if the dense
-        lowering engaged. Oversized/invalid bounds quietly keep the
-        sort path (callers derive the bound from data size — e.g.
+    def try_declare_dense(self, dense_keys) -> bool:
+        """Declare keys dense in [0, dense_keys) — an int for the one
+        key column, a tuple of sizes for a key of several; True if the
+        dense lowering engaged. Oversized/invalid bounds quietly keep
+        the sort path (callers derive the bound from data size — e.g.
         dictenc's len(vocab) — and must not start crashing when the
         data grows past the table cap). Vector VALUE columns are fine
         (rows scatter whole); the KEY must be scalar."""
-        if not self.dense_eligible():
-            return False
         from bigslice_tpu.parallel import dense
 
+        dims = dense.key_dims(dense_keys)
+        if not self.dense_eligible() or len(dims) != self.nkeys:
+            return False
         ops = None
-        if 0 < dense_keys <= dense.MAX_DENSE_KEYS:
+        if min(dims) > 0 and dense.key_space(dims) <= dense.MAX_DENSE_KEYS:
             ops = dense.classified_ops_cached(
                 self.fn, self.nvals,
                 tuple(np.dtype(ct.dtype) for ct in self.schema.values),
@@ -142,7 +147,7 @@ class FrameCombiner:
             )
         if ops is None:
             return False
-        self.dense_keys = int(dense_keys)
+        self.dense_keys = dims[0] if len(dims) == 1 else dims
         self.dense_ops = ops
         return True
 
@@ -176,13 +181,13 @@ class FrameCombiner:
 
 
 class Reduce(Slice):
-    def __init__(self, slice_: Slice, fn: Callable,
-                 dense_keys: Optional[int] = None):
-        """``dense_keys``: optional declaration that the (single int32)
-        key column holds dense codes in ``[0, dense_keys)`` —
-        dictionary encodings, categorical ids. When the combine fn
-        classifies as per-column add/max/min, the mesh executor lowers
-        the combine+shuffle to the sort-free dense-table path
+    def __init__(self, slice_: Slice, fn: Callable, dense_keys=None):
+        """``dense_keys``: optional declaration that the int32 key
+        holds dense codes in ``[0, dense_keys)`` — dictionary
+        encodings, categorical ids; for a key of several columns, a
+        tuple of their sizes (``(len(flags), len(statuses))``). When
+        the combine fn classifies as per-column add/max/min, the mesh
+        executor lowers the combine to the sort-free dense-table path
         (parallel/dense.py); otherwise the declaration is ignored.
         Keys outside the declared range fail the run loudly."""
         typecheck.check(

@@ -109,9 +109,12 @@ def _partitioner_traceable(fn: Callable, slice_: Slice) -> bool:
     try:
         import jax
 
-        specs = [jax.ShapeDtypeStruct((), ct.dtype)
-                 for ct in slice_.schema.key]
-        out = jax.eval_shape(fn, *specs, np.int32(2))
+        from bigslice_tpu.parallel.jitutil import wide_scope
+
+        with wide_scope(slice_.schema.wide):
+            specs = [jax.ShapeDtypeStruct((), ct.dtype)
+                     for ct in slice_.schema.key]
+            out = jax.eval_shape(fn, *specs, np.int32(2))
         if isinstance(out, (tuple, list)):
             return False
         return out.shape == () and np.dtype(out.dtype).kind in ("i", "u")

@@ -39,10 +39,52 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
+from bigslice_tpu.parallel.jitutil import any_wide, wide_scope
+
 # Largest declared key space the dense path accepts: beyond this the
 # per-shard tables (K rows x nvals columns) start competing with the
 # data itself for memory and the sort pipeline wins anyway.
 MAX_DENSE_KEYS = 1 << 22
+
+# Largest table filled by compare-and-sum instead of scatters: one
+# [slots, rows] compare a column, which the TPU's vector unit does at
+# memory speed, where a scatter of a wave's rows runs row by row
+# (PERF.md §5: 2^17 rows into 2 or 5 bins, 0.02 ms against 1.17 ms).
+SMALL_TABLE = 128
+
+
+def key_dims(dense_keys) -> Tuple[int, ...]:
+    """A ``dense_keys`` declaration as per-key-column sizes: an int for
+    one key column, a tuple (the dictionaries' sizes) for several."""
+    if isinstance(dense_keys, (tuple, list)):
+        return tuple(int(d) for d in dense_keys)
+    return (int(dense_keys),)
+
+
+def key_space(dense_keys) -> int:
+    """How many keys a declaration spans (the table's rows)."""
+    return int(np.prod(key_dims(dense_keys), dtype=np.int64))
+
+
+def dense_code(keys, dims):
+    """``(code, in_range)``: the key columns as one row-major dense
+    code in ``[0, prod(dims))``, and whether every column is inside its
+    declared range (the code of a row that is not means nothing)."""
+    code = ok = None
+    for k, d in zip(keys, dims):
+        inside = (k >= 0) & (k < d)
+        ok = inside if ok is None else ok & inside
+        code = k if code is None else code * np.int32(d) + k
+    return code, ok
+
+
+def dense_decode(code, dims) -> tuple:
+    """The key columns of dense codes (``dense_code``'s inverse)."""
+    cols = []
+    for d in reversed(dims[1:]):
+        cols.append(code % np.int32(d))
+        code = code // np.int32(d)
+    return tuple(reversed(cols + [code]))
 
 
 def classify_combine_ops(cfn, val_dtypes: Sequence,
@@ -75,10 +117,11 @@ def classify_combine_ops(cfn, val_dtypes: Sequence,
         # Probe scalar-wise under vmap — the same application shape the
         # segment kernels use, so anything the device tier accepts
         # classifies consistently.
-        out = jax.vmap(lambda xs, ys: cfn(xs, ys))(
-            tuple(jnp.asarray(x) for x in a),
-            tuple(jnp.asarray(x) for x in b),
-        )
+        with wide_scope(any_wide(a)):
+            out = jax.vmap(lambda xs, ys: cfn(xs, ys))(
+                tuple(jnp.asarray(x) for x in a),
+                tuple(jnp.asarray(x) for x in b),
+            )
         out = [np.asarray(o) for o in out]
     except Exception:
         return None
@@ -167,6 +210,14 @@ def _scatter_tables(idx, vals, ops, idents, size: int):
     drop lane). Returns (present bool[size], tables)."""
     import jax.numpy as jnp
 
+    if size <= SMALL_TABLE and all(v.ndim == 1 for v in vals):
+        # Few slots: each is a masked reduction over the rows.
+        hit = idx[None, :] == jnp.arange(size, dtype=np.int32)[:, None]
+        reduce_ = {"add": jnp.sum, "max": jnp.max, "min": jnp.min}
+        return hit.any(axis=1), [
+            reduce_[op](jnp.where(hit, v[None, :], ident),
+                        axis=1).astype(v.dtype)
+            for v, op, ident in zip(vals, ops, idents)]
     present = jnp.zeros((size,), bool).at[idx].set(True)
     tables = []
     for v, op, ident in zip(vals, ops, idents):
@@ -205,29 +256,32 @@ def routing_tables(K: int, nparts: int, seed: int) -> Tuple[np.ndarray, int]:
     return slot_table, maxc
 
 
-def make_dense_combine(K: int, ops: Tuple[str, ...],
+def make_dense_combine(dense_keys, ops: Tuple[str, ...],
                        val_dtypes: Sequence):
     """Shuffle-free dense combine for a single partition (or the
-    map-side stage of a 1-device mesh): one scatter-accumulate pass
-    into a [K] table, unpacked to (key, vals) rows under a presence
-    mask. ``masked(valid, key, *vals) -> (mask, (key,), vals)`` — the
-    make_segmented_reduce_masked contract (output size K instead of the
-    input size; downstream mask-chaining handles both)."""
+    map-side stage before a routing shuffle of the table's rows): one
+    accumulate pass into a [K] table, unpacked to (keys, vals) rows
+    under a presence mask. ``dense_keys`` is the declaration (an int,
+    or the per-column sizes of a several-column key, which becomes one
+    row-major code). ``masked(valid, keys, vals) -> (mask, keys, vals)``
+    — the make_segmented_reduce_masked contract (output size K instead
+    of the input size; downstream mask-chaining handles both)."""
     import jax.numpy as jnp
 
+    dims = key_dims(dense_keys)
+    K = key_space(dense_keys)
     idents = [_identity(op, dt) for op, dt in zip(ops, val_dtypes)]
 
     def masked(valid, keys, vals):
-        (key,) = keys
-        in_range = (key >= 0) & (key < K)
+        code, in_range = dense_code(keys, dims)
         # Out-of-range keys route to the drop lane; the CALLER counts
         # them into the pipeline's bad signal (this contract has no
         # channel for it) so declared-range violations still fail the
         # run loudly instead of dropping rows.
-        idx = jnp.where(valid & in_range, key, np.int32(K))
+        idx = jnp.where(valid & in_range, code, np.int32(K))
         present, tables = _scatter_tables(idx, vals, ops, idents, K + 1)
-        out_key = jnp.arange(K, dtype=np.int32)
-        return present[:K], (out_key,), tuple(t[:K] for t in tables)
+        out_keys = dense_decode(jnp.arange(K, dtype=np.int32), dims)
+        return present[:K], out_keys, tuple(t[:K] for t in tables)
 
     return masked
 
@@ -315,7 +369,9 @@ def classified_fold_op_cached(fn, acc_dtype, val_dtype) -> Optional[str]:
     if acc is None or v is None:
         return None
     try:
-        out = np.asarray(jax.vmap(fn)(jnp.asarray(acc), jnp.asarray(v)))
+        with wide_scope(any_wide((acc, v))):
+            out = np.asarray(
+                jax.vmap(fn)(jnp.asarray(acc), jnp.asarray(v)))
     except Exception:
         return None
     op = _match_op(out, acc, v.astype(accd))

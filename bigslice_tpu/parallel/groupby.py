@@ -23,7 +23,7 @@ from typing import Sequence
 
 import numpy as np
 
-from bigslice_tpu.parallel.jitutil import bucket_size, pad_cols
+from bigslice_tpu.parallel.jitutil import bucket_size, jit, pad_cols
 
 
 class DeviceGroupByKey:
@@ -55,7 +55,7 @@ class DeviceGroupByKey:
             return (n_groups, packed[:nkeys], packed[nkeys],
                     packed[nkeys + 1])
 
-        self._jitted = jax.jit(kernel)
+        self._jitted = jit(kernel)
 
     def __call__(self, key_cols: Sequence, val_col, n: int):
         import jax.numpy as jnp

@@ -49,7 +49,7 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
-from bigslice_tpu.parallel.jitutil import jit_maybe_donate
+from bigslice_tpu.parallel.jitutil import jit, jit_maybe_donate
 from bigslice_tpu.parallel.meshutil import get_shard_map
 from bigslice_tpu.parallel.shuffle import (
     bucket_exchange,
@@ -476,7 +476,7 @@ class HierMeshShuffle:
             out_count, overflow, out_cols = body(n, *cols)
             return (out_count.reshape(1), overflow, tuple(out_cols))
 
-        self._jitted = jax.jit(
+        self._jitted = jit(
             shard_map(stepped, mesh=mesh, in_specs=in_specs,
                       out_specs=out_specs, check_rep=False)
         )

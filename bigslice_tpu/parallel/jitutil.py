@@ -9,6 +9,7 @@ compiled programs regardless of data skew.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Callable, Optional, Sequence, Tuple
 
@@ -50,7 +51,71 @@ def donation_supported() -> bool:
     return _DONATION_OK
 
 
-def jit_maybe_donate(fn: Callable, donate_argnums: Sequence[int] = ()):
+_WIDE_DTYPES = (np.dtype(np.int64), np.dtype(np.uint64))
+
+
+def any_wide(args) -> bool:
+    """Is any array among ``args`` (nested tuples / lists of columns
+    included) a 64-bit integer array?"""
+    for a in args:
+        if isinstance(a, (tuple, list)):
+            if any_wide(a):
+                return True
+        elif getattr(a, "dtype", None) in _WIDE_DTYPES:
+            return True
+    return False
+
+
+def wide_scope(wide: bool = True):
+    """JAX's 64-bit mode for the calling thread while the block runs,
+    when ``wide``; nothing otherwise. THE way 64-bit integer columns
+    reach XLA: the mode is scoped to the uploads and programs that
+    carry such a column, never set for the process — that would turn
+    every weak Python scalar and ``jnp.arange`` of every 32-bit program
+    into 64 bits, and the TPU emulates 64-bit integers."""
+    if not wide:
+        return contextlib.nullcontext()
+    import jax
+
+    return jax.enable_x64(True)
+
+
+class ScopedJit:
+    """A jitted callable that is traced, lowered and run under
+    ``wide_scope`` exactly when it carries 64 bits: an argument is a
+    64-bit integer array, or the builder said so (``wide=True``: the
+    arguments do not show it — a Map from int32 columns to an int64
+    one). A program without such a column is the jit it always was."""
+
+    __slots__ = ("_jitted", "_wide")
+
+    def __init__(self, jitted, wide: bool = False):
+        self._jitted = jitted
+        self._wide = bool(wide)
+
+    def __call__(self, *args, **kw):
+        with wide_scope(self._wide or any_wide(args)):
+            return self._jitted(*args, **kw)
+
+    def lower(self, *args, **kw):
+        with wide_scope(self._wide or any_wide(args)):
+            return self._jitted.lower(*args, **kw)
+
+    def __getattr__(self, name):
+        # ``__name__`` and the rest of the jit's own surface.
+        return getattr(self._jitted, name)
+
+
+def jit(fn: Callable, wide: bool = False, **jit_kwargs) -> ScopedJit:
+    """``jax.jit`` for every program of the package that columns pass
+    through (see ``ScopedJit``)."""
+    import jax
+
+    return ScopedJit(jax.jit(fn, **jit_kwargs), wide)
+
+
+def jit_maybe_donate(fn: Callable, donate_argnums: Sequence[int] = (),
+                     wide: bool = False):
     """``jax.jit`` with donation applied only when requested AND the
     backend honors it — THE one place donated program variants are
     built, so every caller (the mesh executor's SPMD programs, the
@@ -59,8 +124,6 @@ def jit_maybe_donate(fn: Callable, donate_argnums: Sequence[int] = ()):
     distinct compilations; callers key their caches on the donation
     signature (a bool / tuple of bools), which bounds the blowup at
     2× per cache, not one entry per call site."""
-    import jax
-
     nums = tuple(donate_argnums)
     if nums and donation_supported():
         global _DONATION_FILTER_INSTALLED
@@ -77,8 +140,8 @@ def jit_maybe_donate(fn: Callable, donate_argnums: Sequence[int] = ()):
                 "ignore", message="Some donated buffers were not usable"
             )
             _DONATION_FILTER_INSTALLED = True
-        return jax.jit(fn, donate_argnums=nums)
-    return jax.jit(fn)
+        return jit(fn, wide, donate_argnums=nums)
+    return jit(fn, wide)
 
 
 def bucket_size(n: int, minimum: int = 8) -> int:
@@ -123,14 +186,17 @@ class PaddedVmap:
 
     def __init__(self, fn: Callable):
         self.fn = fn
-        # (ncols, nextra, donate) -> jitted vmapped fn. The donate bit
+        # (ncols, nextra, donate, wide) -> jitted vmapped fn; ``wide``:
+        # the caller's output schema has a 64-bit column its input
+        # columns do not show (ScopedJit). The donate bit
         # keys the cache so donated and undonated callers of the SAME
         # shared instance (get_padded_vmap) coexist at a bounded 2×,
         # instead of thrashing one entry back and forth.
         self._jitted = {}
 
-    def _get(self, ncols: int, nextra: int, donate: bool = False):
-        key = (ncols, nextra, donate)
+    def _get(self, ncols: int, nextra: int, donate: bool = False,
+             wide: bool = False):
+        key = (ncols, nextra, donate, wide)
         j = self._jitted.get(key)
         if j is None:
             import jax
@@ -139,14 +205,15 @@ class PaddedVmap:
                 self.fn, in_axes=(0,) * ncols + (None,) * nextra
             )
             j = jit_maybe_donate(
-                vf, tuple(range(ncols)) if donate else ()
+                vf, tuple(range(ncols)) if donate else (), wide
             )
             self._jitted[key] = j
         return j
 
     def __call__(self, cols: Sequence, n: int,
                  extra: Sequence = (),
-                 donate: bool = False) -> Tuple[list, int]:
+                 donate: bool = False,
+                 wide: bool = False) -> Tuple[list, int]:
         """Apply to n valid rows of equal-length columns; returns (out
         columns sliced to n, n).
 
@@ -157,7 +224,8 @@ class PaddedVmap:
         transfer copy is the program's to donate."""
         target = bucket_size(n)
         padded = pad_cols(cols, n, target)
-        out = self._get(len(cols), len(extra), donate)(*padded, *extra)
+        out = self._get(len(cols), len(extra), donate, wide)(
+            *padded, *extra)
         if not isinstance(out, (tuple, list)):
             out = (out,)
         # Slice on the host: an eager device slice would compile one XLA

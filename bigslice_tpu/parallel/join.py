@@ -23,6 +23,7 @@ from typing import Callable, Sequence, Tuple
 
 import numpy as np
 
+from bigslice_tpu.parallel import jitutil
 from bigslice_tpu.parallel.meshutil import get_shard_map, mesh_axis
 from bigslice_tpu.parallel import shuffle as shuffle_mod
 
@@ -125,7 +126,7 @@ class MeshJoinAggregate:
             return (n_out.reshape(1),) + tuple(packed)
 
         col = P(axis)
-        self._align = jax.jit(shard_map(
+        self._align = jitutil.jit(shard_map(
             align, mesh=mesh,
             in_specs=(col, col, col, col, col, col),
             out_specs=(col, col, col, col),

@@ -199,6 +199,7 @@ def hash_partition(keys, nparts: int, seed: int = 0,
     (ids int32[n], counts int32[nparts] | None). Bit-identical to the
     stock-XLA path: hash_device_column/combine_hashes % nparts.
     """
+    import jax
     import jax.numpy as jnp
 
     from bigslice_tpu.frame import ops as frame_ops
@@ -230,7 +231,11 @@ def hash_partition(keys, nparts: int, seed: int = 0,
         tuple(str(k.dtype) for k in key_list), _interpret(),
         with_counts,
     )
-    ids2d, counts = fn(mask2d, *keys2d)
+    # The kernel's operands are 32-bit whatever program holds it; traced
+    # inside a program of JAX's 64-bit mode its index maps would come
+    # out as i64, which Mosaic refuses.
+    with jax.enable_x64(False):
+        ids2d, counts = fn(mask2d, *keys2d)
     ids = ids2d.reshape(-1)[:n]
     if not with_counts:
         return ids, None

@@ -19,7 +19,7 @@ from typing import Callable, Sequence, Tuple
 
 import numpy as np
 
-from bigslice_tpu.parallel.jitutil import bucket_size, pad_cols
+from bigslice_tpu.parallel.jitutil import bucket_size, jit, pad_cols
 from bigslice_tpu.frame.frame import obj_col as _obj_col
 
 
@@ -235,7 +235,7 @@ class DeviceReduceByKey:
         def kernel(n, *cols):
             return core(n, cols[:nkeys], cols[nkeys:])
 
-        self._jitted = jax.jit(kernel)
+        self._jitted = jit(kernel)
 
     def __call__(self, key_cols: Sequence, val_cols: Sequence, n: int):
         import jax.numpy as jnp
@@ -342,7 +342,7 @@ class DeviceSortedFold:
             )
             return count, packed[:nkeys], packed[nkeys:]
 
-        self._jitted = jax.jit(kernel)
+        self._jitted = jit(kernel)
 
     def __call__(self, key_cols, val_cols, n: int):
         import jax.numpy as jnp

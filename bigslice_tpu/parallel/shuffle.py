@@ -27,7 +27,12 @@ from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from bigslice_tpu.parallel.jitutil import jit_maybe_donate
+from bigslice_tpu.parallel.jitutil import (
+    any_wide,
+    jit,
+    jit_maybe_donate,
+    wide_scope,
+)
 from bigslice_tpu.parallel.meshutil import get_shard_map, mesh_axis
 
 
@@ -639,23 +644,26 @@ def place_global_columns(mesh, globs: Sequence[np.ndarray], counts):
     # flat and hierarchical shuffles see identical placements.
     sharding = NamedSharding(mesh, P(tuple(mesh.axis_names)))
     counts_host = np.asarray(counts, np.int32)
-    if not is_multiprocess_mesh(mesh):
-        placed = jax.device_put(list(globs) + [counts_host], sharding)
-        return placed[:-1], placed[-1]
-    pid = jax.process_index()
-    local = [i for i, d in enumerate(mesh.devices.flat)
-             if d.process_index == pid]
+    # A put outside JAX's 64-bit mode narrows a 64-bit integer column.
+    with wide_scope(any_wide(globs)):
+        if not is_multiprocess_mesh(mesh):
+            placed = jax.device_put(list(globs) + [counts_host],
+                                    sharding)
+            return placed[:-1], placed[-1]
+        pid = jax.process_index()
+        local = [i for i, d in enumerate(mesh.devices.flat)
+                 if d.process_index == pid]
 
-    def place(glob):
-        rows_per = glob.shape[0] // nshards
-        local_rows = np.concatenate([
-            glob[i * rows_per : (i + 1) * rows_per] for i in local
-        ])
-        return jax.make_array_from_process_local_data(
-            sharding, local_rows, glob.shape
-        )
+        def place(glob):
+            rows_per = glob.shape[0] // nshards
+            local_rows = np.concatenate([
+                glob[i * rows_per : (i + 1) * rows_per] for i in local
+            ])
+            return jax.make_array_from_process_local_data(
+                sharding, local_rows, glob.shape
+            )
 
-    return [place(g) for g in globs], place(counts_host)
+        return [place(g) for g in globs], place(counts_host)
 
 
 # Most bytes of bucketed prefixes one readback keeps in flight to the
@@ -784,7 +792,7 @@ def _prefix_program():
     def bs_prefix(b, *cols):
         return tuple(c[:b] for c in cols)
 
-    return jax.jit(bs_prefix, static_argnums=0)
+    return jit(bs_prefix, static_argnums=0)
 
 
 def _slices_on_device() -> bool:

@@ -14,7 +14,7 @@ from typing import Sequence
 
 import numpy as np
 
-from bigslice_tpu.parallel.jitutil import bucket_size, pad_cols
+from bigslice_tpu.parallel.jitutil import bucket_size, jit, pad_cols
 
 
 class DeviceRunSort:
@@ -35,7 +35,7 @@ class DeviceRunSort:
                            is_stable=True)
             return srt[1:]
 
-        self._jitted = jax.jit(kernel)
+        self._jitted = jit(kernel)
 
     def __call__(self, cols: Sequence, n: int):
         import jax.numpy as jnp

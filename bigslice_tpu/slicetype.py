@@ -9,7 +9,7 @@ TPU-first difference: instead of arbitrary Go ``reflect.Type`` columns, a
 column is either
 
 - a **device** column: a fixed-width numpy dtype resident as a jax Array
-  (int8/16/32, uint8/16/32, float16/bfloat16/float32, bool), or
+  (int8/16/32/64, uint8/16/32/64, float16/bfloat16/float32, bool), or
 - a **host** column: arbitrary Python objects (strings, lists, tuples)
   carried in numpy object arrays on the host, never shipped to the device.
 
@@ -25,9 +25,14 @@ from typing import Any, Iterable, Sequence, Tuple
 
 import numpy as np
 
-# Device-supported dtypes. 64-bit ints/floats are deliberately excluded from
-# the device tier: TPUs (and jax's default 32-bit mode) are 32-bit-first.
-# 64-bit numeric data is carried as a host column or downcast explicitly.
+# Device-supported dtypes. The device tier is 32-bit-first: ``int`` is
+# int32 and an UNDECLARED 64-bit integer input narrows to 32 bits (checked:
+# a value that does not fit raises, frame.Frame). A column DECLARED
+# ``np.int64`` / ``np.uint64`` (a Const's ``schema=``, a Map's ``out=``, a
+# reader's schema) is a device column of 64 bits end to end — exact sums
+# past 2^31; the programs that carry one run in JAX's 64-bit mode, scoped
+# to them (parallel/jitutil.py). float64 is not a device dtype: a float64
+# input narrows to float32, as it always has.
 
 
 def _bfloat16_dtype():
@@ -92,10 +97,18 @@ def coltype(spec: Any) -> ColType:
         return ColType(dt)
     if dt not in _device_dtypes():
         raise TypeError(
-            f"dtype {dt} is not supported on the device tier; use a 32-bit "
-            f"dtype, or declare the column as a host column (object/str)"
+            f"dtype {dt} is not supported on the device tier; use an "
+            f"integer dtype or a float dtype of at most 32 bits, or "
+            f"declare the column as a host column (object/str)"
         )
     return ColType(dt)
+
+
+def is_wide(dtype) -> bool:
+    """Is ``dtype`` a 64-bit integer — a column only JAX's 64-bit mode
+    carries without narrowing it?"""
+    dt = np.dtype(dtype)
+    return dt.kind in "iu" and dt.itemsize == 8
 
 
 def _device_dtypes() -> frozenset:
@@ -110,9 +123,11 @@ def _device_dtypes() -> frozenset:
                 np.int8,
                 np.int16,
                 np.int32,
+                np.int64,
                 np.uint8,
                 np.uint16,
                 np.uint32,
+                np.uint64,
                 np.float16,
                 np.float32,
             )
@@ -133,7 +148,7 @@ class Schema:
     ``schema.prefix``.
     """
 
-    __slots__ = ("cols", "prefix")
+    __slots__ = ("cols", "prefix", "wide")
 
     def __init__(self, cols: Iterable[Any], prefix: int = 1):
         self.cols: Tuple[ColType, ...] = tuple(coltype(c) for c in cols)
@@ -142,6 +157,9 @@ class Schema:
                 f"prefix {prefix} out of range for {len(self.cols)} columns"
             )
         self.prefix = prefix
+        #: Does any column hold 64-bit integers (``is_wide``)?
+        self.wide = any(is_wide(ct.dtype) for ct in self.cols
+                        if ct.is_device)
 
     def __len__(self) -> int:
         return len(self.cols)

@@ -160,6 +160,14 @@ class _OpRecord:
         self.combine_in_rows = 0
         self.combine_out_rows = 0
         self.combine_boundaries = 0
+        # The mesh executor's map-side combine: rows its waves staged
+        # since the last boundary (they become combine_in_rows there),
+        # the lowering picked, the 64-bit value columns carried.
+        self.staged_rows = 0
+        self.combine_lowering = ""
+        self.combine_wide_columns = 0
+        # -- host seconds of the op's dispatch / settle spans
+        self.wave_host_s: Dict[str, float] = {}
 
 
 class DeadlineStats:
@@ -637,20 +645,31 @@ class TelemetryHub:
         return ratio, max_shard, median, total
 
     def record_combine_input(self, op: str, inv: Optional[int],
-                             in_rows: int, out_rows: int) -> None:
+                             in_rows: Optional[int], out_rows: int,
+                             lowering: str = "host",
+                             wide_columns: int = 0) -> None:
         """One producer task's map-side combine cardinality: rows INTO
         the boundary's combiner and rows out (~distinct keys for the
         full boundary once every producer reports). The executor calls
         this per combine-bearing task (exec/local.py); post-combine
         shuffle sizes alone understate cardinality by exactly the
-        combine's collapse factor."""
-        in_rows = max(0, int(in_rows))
+        combine's collapse factor. The mesh executor calls it once a
+        group with ``in_rows`` None — the rows the group's waves staged
+        since the op's last boundary (``record_wave_staging``) — and
+        says which ``lowering`` ran and how many 64-bit value columns
+        it carried."""
         out_rows = max(0, int(out_rows))
         with self._lock:
             rec = self._op(op, inv)
+            if in_rows is None:
+                in_rows = rec.staged_rows
+            in_rows = max(0, int(in_rows))
+            rec.staged_rows = 0
             rec.combine_in_rows += in_rows
             rec.combine_out_rows += out_rows
             rec.combine_boundaries += 1
+            rec.combine_lowering = lowering
+            rec.combine_wide_columns = int(wide_columns)
         self._emit("bigslice:combineInput", op=op, inv=inv,
                    in_rows=in_rows, out_rows=out_rows)
 
@@ -697,6 +716,7 @@ class TelemetryHub:
             rec.staging_s += dur_s
             rec.exposed_s += exposed_s
             rec.staged_waves += 1
+            rec.staged_rows += int((breakdown or {}).get("rows", 0))
             rec.max_wave = max(rec.max_wave, int(wave))
             for k, v in clean.items():
                 rec.stage_phases[k] = rec.stage_phases.get(k, 0.0) + v
@@ -705,6 +725,14 @@ class TelemetryHub:
                    exposed_ms=round(exposed_s * 1e3, 3),
                    **{k[:-2] + "_ms": round(v * 1e3, 3)
                       for k, v in clean.items()})
+
+    def record_wave_host(self, op: str, inv: Optional[int],
+                         field: str, dur_s: float) -> None:
+        """Host seconds of one wave's ``dispatch_s`` or ``settle_s``
+        (the spans of those names), summed by op."""
+        with self._lock:
+            host = self._op(op, inv).wave_host_s
+            host[field] = host.get(field, 0.0) + max(0.0, float(dur_s))
 
     def record_wave_compute(self, op: str, inv: Optional[int],
                             wave: int, dur_s: float) -> None:
@@ -903,8 +931,19 @@ class TelemetryHub:
                             k: round(v, 6)
                             for k, v in rec.stage_phases.items()
                         }
+                    for k, v in rec.wave_host_s.items():
+                        entry["waves"][k] = round(v, 6)
                     total_staging += rec.staging_s
                     total_hidden += hidden
+                if rec.combine_boundaries:
+                    # The op's map-side combine (record_combine_input).
+                    entry["combine"] = {
+                        "boundaries": rec.combine_boundaries,
+                        "rows_in": rec.combine_in_rows,
+                        "rows_out": rec.combine_out_rows,
+                        "lowering": rec.combine_lowering,
+                        "wide_columns": rec.combine_wide_columns,
+                    }
                 ex = exchanged.get(op)
                 if ex and ex["ici_messages"] + ex["dcn_messages"]:
                     # A shuffle whose collective moved something (a

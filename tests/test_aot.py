@@ -259,3 +259,61 @@ def test_aot_fused_map_side_wave_holds_no_scatter(topo, tpu_branches,
     assert text.count(" sort(") == 3
     assert _mosaic_calls_in(text, pk.HASH_PARTITION_KERNEL) == 1
     assert ("all-to-all" in text) == (chips > 1)
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_aot_wide_dense_map_side_wave_compiles_for_tpu(topo, tpu_branches,
+                                                       chips):
+    """The map-side wave of TPC-H Q1's shape, in JAX's 64-bit mode as
+    ``jitutil.ScopedJit`` runs it: a widening Map, the two-column key as
+    one dense code, a 6-slot table of five int64 sums and one int32
+    filled by compare-and-sum (no scatter), then the table's rows
+    through the routing shuffle and the packing sort. The Mosaic
+    partitioner inside it is traced in 32-bit mode — with i64 index maps
+    Mosaic refuses the kernel (what the chip said first: PERF.md §6,
+    PR 32)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from bigslice_tpu.parallel import dense, segment, shuffle
+    from bigslice_tpu.parallel import pallas_kernels as pk
+    from bigslice_tpu.parallel.jitutil import jit
+    from bigslice_tpu.parallel.meshutil import get_shard_map
+
+    mesh = Mesh(np.array(topo.devices[:chips]), ("shards",))
+    size = 4096
+    dtypes = [np.int64] * 5 + [np.int32]
+    core = dense.make_dense_combine((3, 2), ("add",) * 6, dtypes)
+    routed = shuffle.make_shuffle_fn(chips, 2, 6, "shards",
+                                     slack=float(chips),
+                                     nparts=46 * chips)
+
+    def widen(flag, status, qty, price, disc, tax, day):
+        price = price.astype(np.int64)
+        disc_price = price * (100 - disc)
+        return (flag, status, qty.astype(np.int64), price, disc_price,
+                disc_price * (100 + tax), disc.astype(np.int64),
+                np.int32(1))
+
+    def body(n, *cols):
+        mask = (jnp.arange(size, dtype=np.int32) < n[0]) \
+            & (cols[6] <= 2436)
+        cols = jax.vmap(widen)(*cols)
+        m, keys, vals = core(mask, tuple(cols[:2]), tuple(cols[2:]))
+        rm, ov, bad, oc = routed.masked(m, *keys, *vals)
+        n_out, packed = segment.compact_by_mask(rm, oc)
+        return n_out.reshape(1), ov, bad, tuple(packed)
+
+    row = P("shards")
+    fn = jit(get_shard_map()(
+        body, mesh=mesh, in_specs=(row,) * 8,
+        out_specs=(row, P(), P(), (row,) * 9), check_rep=False,
+    ), wide=True)
+    S = lambda rows: jax.ShapeDtypeStruct(  # noqa: E731
+        (chips * rows,), np.int32)
+    text = fn.lower(S(1), *[S(size)] * 7).compile().as_text()
+    assert " scatter(" not in text
+    assert "s64[" in text
+    assert _mosaic_calls_in(text, pk.HASH_PARTITION_KERNEL) == 1
+    assert ("all-to-all" in text) == (chips > 1)

@@ -694,13 +694,11 @@ def test_row_indexed_scatter_predicate_tells_the_two_kinds_apart():
     assert _row_indexed_scatters(text) == ["64x1xi32"]
 
 
-@pytest.mark.parametrize("ndev,rows", [(1, 1 << 17), (4, 1 << 13)])
-def test_wave_programs_hold_no_row_indexed_scatter(ndev, rows):
-    """The map-side, reduce-side and filter wave programs of a keyed
-    Reduce + Filter, as the executor builds them (``rows`` a shard, two
-    waves): compaction and the bucket fill are sorts and slices. A
-    scatter of a wave's rows runs row by row on the TPU (PERF.md §5,
-    PR 31)."""
+def _wave_program_texts(ndev, rows):
+    """``{program name: lowered text}`` of the map-side, reduce-side
+    and filter wave programs of a keyed Reduce + Filter — the pipeline
+    of the ``q18agg`` cells — as the executor builds them (``rows`` a
+    shard, two waves)."""
     import re
 
     from jax.sharding import Mesh
@@ -722,7 +720,7 @@ def test_wave_programs_hold_no_row_indexed_scatter(ndev, rows):
         with sess.executor._lock:
             programs = [p for p, _ in sess.executor._programs.values()
                         if getattr(p, "_kind", None) == "group"]
-        seen = {}
+        texts = {}
         for prog in programs:
             for sig in prog._compiled:
                 # (shape, dtype[, sharding]) an argument.
@@ -731,13 +729,47 @@ def test_wave_programs_hold_no_row_indexed_scatter(ndev, rows):
                     if len(a) > 2 else jax.ShapeDtypeStruct(*a)
                     for a in sig]).as_text()
                 (name,) = set(re.findall(r"bs_group_\w+", text))
-                seen[name] = (_row_indexed_scatters(text),
-                              text.count("stablehlo.sort"))
+                texts[name] = text
     finally:
         sess.shutdown()
+    return texts
+
+
+@pytest.mark.parametrize("ndev,rows", [(1, 1 << 17), (4, 1 << 13)])
+def test_wave_programs_hold_no_row_indexed_scatter(ndev, rows):
+    """The map-side, reduce-side and filter wave programs of a keyed
+    Reduce + Filter, as the executor builds them (``rows`` a shard, two
+    waves): compaction and the bucket fill are sorts and slices. A
+    scatter of a wave's rows runs row by row on the TPU (PERF.md §5,
+    PR 31)."""
+    seen = {name: (_row_indexed_scatters(text),
+                   text.count("stablehlo.sort"))
+            for name, text in _wave_program_texts(ndev, rows).items()}
     # Sorts: the fused (validity, lane, subid, key) sort, the lane
     # grouping and the packing of what arrived; (validity, key) and
     # the packing; the packing.
     assert seen == {"bs_group_shuffle": ([], 3),
                     "bs_group_combine": ([], 2),
                     "bs_group_filter": ([], 1)}
+
+
+@pytest.mark.parametrize("ndev,rows", [(1, 4096), (8, 512)])
+def test_wave_programs_of_32_bit_columns_hold_no_64_bit_type(ndev, rows):
+    """The same three programs hold no 64-bit tensor: 64-bit integer
+    columns reach XLA through JAX's 64-bit mode scoped to the programs
+    that carry one (jitutil.ScopedJit), and a pipeline of 32-bit
+    columns — both ``q18agg`` cells — must not be widened by it, now
+    or by accident later (the TPU emulates 64-bit integers)."""
+    import re
+
+    texts = _wave_program_texts(ndev, rows)
+    assert set(texts) == {"bs_group_shuffle", "bs_group_combine",
+                          "bs_group_filter"}
+    for name, text in texts.items():
+        # Element types of the tensors a program computes on: MLIR
+        # spells its own attributes (dimensions, paddings, replica
+        # groups) in i64 whatever the program computes in.
+        text = re.sub(r"dense<[^>]*> : tensor<[^>]*>", "", text)
+        wide = re.findall(
+            r"tensor<(?:[0-9?]+x)*(?:[su]?i64|f64)>", text)
+        assert not wide, f"{name}: {sorted(set(wide))}"
