@@ -1917,7 +1917,7 @@ class MeshExecutor:
     # signals for operators, and a telemetry failure must never fail a
     # wave. Costs are bounded: staging/compute records are O(1) host
     # arithmetic; the shuffle-size record syncs nmesh int32 counts from
-    # a program whose signal scalars the caller already synced.
+    # a program whose signal vector the caller already synced.
 
     def _telemetry_hub(self):
         sess = getattr(self, "session", None)
@@ -2104,17 +2104,19 @@ class MeshExecutor:
             pass
 
     def _telemetry_wave_host(self, task0: Task, field: str,
-                             dur_s: float) -> None:
+                             dur_s: float,
+                             ready: Optional[int] = None) -> None:
         """Host seconds of one wave's ``dispatch`` or ``settle`` span,
         by op: the span table knows them by name only, and which
         GROUP's waves cost the host what is the question a job of many
-        nearly empty reduce-side waves asks."""
+        nearly empty reduce-side waves asks. A settle also says whether
+        its signals were ``ready`` when it opened."""
         hub = self._telemetry_hub()
         if hub is None:
             return
         try:
             hub.record_wave_host(task0.name.op, task0.name.inv_index,
-                                 field, dur_s)
+                                 field, dur_s, ready=ready)
         except Exception:
             pass
 
@@ -2464,12 +2466,14 @@ class MeshExecutor:
         # computation limit blocks INSIDE the jit call holding the GIL,
         # starving the prefetch thread of the very overlap this
         # pipeline exists for — whereas the settle wait (device→host
-        # sync of the signal scalars) releases the GIL and lets staging
+        # sync of the signal vector) releases the GIL and lets staging
         # proceed. So on CPU each wave settles before the next
         # dispatches (staging still overlaps compute, during the
         # settle wait); on TPU/GPU, whose dispatch queues are deep and
         # non-blocking, up to ``depth`` waves stay in flight so the
-        # device never drains across the per-wave signal sync.
+        # device never drains across the per-wave signal sync — and a
+        # wave's signals, whose host copy its dispatch started, are
+        # home by the time it is settled after the next one's dispatch.
         import jax
 
         window = 0 if jax.default_backend() == "cpu" else depth
@@ -2897,12 +2901,24 @@ class MeshExecutor:
             ]
             raw = program(np.int32(wave), *counts_list, *cols_flat,
                           *extras)
+            # The program call returned at enqueue: the signals' copy
+            # to the host queues behind the wave on the device's
+            # stream, and the settle collects it (_read_signals).
+            raw[1].copy_to_host_async()
             if any(k == "shuffle" for k, _, _ in stages):
                 # Every dispatched attempt (first run and slack retries
                 # alike) put its buckets on the wire.
                 self._telemetry_exchange(task0, wave, inputs, slack)
         self._telemetry_wave_host(task0, "dispatch_s", sp.seconds)
         return raw, stages, slack
+
+    @staticmethod
+    def _read_signals(signals) -> Tuple[int, int, int, int]:
+        """A wave's ``(overflow, badrange, gbover, hashov)`` on the
+        host: the ONE device-to-host read of a settle. The vector is
+        replicated, so every process reads its own addressable copy,
+        and the transfer is the one ``_dispatch_wave_on`` started."""
+        return tuple(np.asarray(signals).tolist())
 
     @staticmethod
     def _owned_buffers(inputs):
@@ -2964,20 +2980,20 @@ class MeshExecutor:
             attempt += 1
             # Sync THIS attempt's signals (a pipeline-dispatched one on
             # the first pass); the loop only re-runs on retry.
-            (out_counts, overflow, badrange, gbover, hashov,
-             out_cols), stages, slack = first
+            (out_counts, signals, out_cols), stages, slack = first
             first = None
             has_shuffle = any(k == "shuffle" for k, _, _ in stages)
-            with span("settle", wave=wave) as settling:
-                # The first read waits for the wave's program; this is
-                # where the host is blocked on the device.
-                gbover = int(np.asarray(gbover))
-                badrange = int(np.asarray(badrange))
-                hashov = int(np.asarray(hashov))
-                overflow = (int(np.asarray(overflow))
-                            if has_shuffle or is_cogroup else 0)
+            # ``ready``: the wave had finished when the settle opened,
+            # so the read finds the copy that the dispatch started;
+            # otherwise this is where the host is blocked on the device.
+            ready = int(signals.is_ready())
+            with span("settle", wave=wave, ready=ready) as settling:
+                overflow, badrange, gbover, hashov = (
+                    self._read_signals(signals))
             self._telemetry_wave_host(tasks[0], "settle_s",
-                                      settling.seconds)
+                                      settling.seconds, ready=ready)
+            if not (has_shuffle or is_cogroup):
+                overflow = 0
             if gbover > 0:
                 # Checked BEFORE badrange: a strict capacity overflow
                 # must never trigger the auto-dense retraction path.
@@ -4594,15 +4610,18 @@ class MeshExecutor:
                     cols = list(cols)
                     overflow = overflow + ov
                     badrange = badrange + nb
+            # The wave's four signals as ONE replicated vector, in the
+            # order _read_signals unpacks: one output buffer for a
+            # dispatch to wrap, one device-to-host copy for a settle.
+            signals = jnp.stack(
+                [overflow, badrange, gbover, hashov]).astype(np.int32)
             if not mask_dirty:
                 # Map-only single-input chain: counts pass through.
                 return (jnp.asarray(counts_list[0][0]).reshape(1),
-                        overflow, badrange, gbover, hashov,
-                        tuple(cols))
+                        signals, tuple(cols))
             # Final compaction to the front-packed (cols, count) contract.
             out_n, cols = segment.compact_by_mask(mask, cols)
-            return (out_n.reshape(1), overflow, badrange, gbover,
-                    hashov, tuple(cols))
+            return out_n.reshape(1), signals, tuple(cols)
 
         if stages and stages[0][0] == "cogroup":
             # Device view of the ragged output: keys, then per input
@@ -4619,7 +4638,7 @@ class MeshExecutor:
             + tuple(col_spec for _ in range(sum(in_ncols)))
             + tuple(P() for _ in range(n_extras))
         )
-        out_specs = (P(axis), P(), P(), P(), P(),
+        out_specs = (P(axis), P(),
                      tuple(col_spec for _ in range(ncols_out)))
         # Donation: argument order is (wave, counts..., cols..., extras)
         # — a donated input contributes its counts argnum and its

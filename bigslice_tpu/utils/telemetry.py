@@ -166,8 +166,11 @@ class _OpRecord:
         self.staged_rows = 0
         self.combine_lowering = ""
         self.combine_wide_columns = 0
-        # -- host seconds of the op's dispatch / settle spans
+        # -- host seconds of the op's dispatch / settle spans, and of
+        # its settles how many found their signals already computed
         self.wave_host_s: Dict[str, float] = {}
+        self.settles = 0
+        self.settles_ready = 0
 
 
 class DeadlineStats:
@@ -727,12 +730,19 @@ class TelemetryHub:
                       for k, v in clean.items()})
 
     def record_wave_host(self, op: str, inv: Optional[int],
-                         field: str, dur_s: float) -> None:
+                         field: str, dur_s: float,
+                         ready: Optional[int] = None) -> None:
         """Host seconds of one wave's ``dispatch_s`` or ``settle_s``
-        (the spans of those names), summed by op."""
+        (the spans of those names), summed by op. A settle passes
+        ``ready``, the span's field of that name: ``settles`` counts
+        them and ``settles_ready`` those whose wave had finished."""
         with self._lock:
-            host = self._op(op, inv).wave_host_s
+            rec = self._op(op, inv)
+            host = rec.wave_host_s
             host[field] = host.get(field, 0.0) + max(0.0, float(dur_s))
+            if ready is not None:
+                rec.settles += 1
+                rec.settles_ready += bool(ready)
 
     def record_wave_compute(self, op: str, inv: Optional[int],
                             wave: int, dur_s: float) -> None:
@@ -933,6 +943,10 @@ class TelemetryHub:
                         }
                     for k, v in rec.wave_host_s.items():
                         entry["waves"][k] = round(v, 6)
+                    if rec.settles:
+                        entry["waves"]["settles"] = rec.settles
+                        entry["waves"]["settles_ready"] = (
+                            rec.settles_ready)
                     total_staging += rec.staging_s
                     total_hidden += hidden
                 if rec.combine_boundaries:

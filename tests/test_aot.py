@@ -245,12 +245,14 @@ def test_aot_fused_map_side_wave_holds_no_scatter(topo, tpu_branches,
         mask = jnp.arange(size, dtype=np.int32) < n[0]
         rm, ov, bad, oc = fused.masked(mask, k, v)
         n_out, packed = segment.compact_by_mask(rm, oc)
-        return n_out.reshape(1), ov, bad, packed
+        # The signals as meshexec._program returns them: one vector.
+        zero = jnp.int32(0)
+        return n_out.reshape(1), jnp.stack([ov, bad, zero, zero]), packed
 
     row = P("shards")
     fn = jax.jit(get_shard_map()(
         body, mesh=mesh, in_specs=(row,) * 3,
-        out_specs=(row, P(), P(), (row,) * 3), check_rep=False,
+        out_specs=(row, P(), (row,) * 3), check_rep=False,
     ))
     S = lambda rows: jax.ShapeDtypeStruct(  # noqa: E731
         (chips * rows,), np.int32)
@@ -303,12 +305,14 @@ def test_aot_wide_dense_map_side_wave_compiles_for_tpu(topo, tpu_branches,
         m, keys, vals = core(mask, tuple(cols[:2]), tuple(cols[2:]))
         rm, ov, bad, oc = routed.masked(m, *keys, *vals)
         n_out, packed = segment.compact_by_mask(rm, oc)
-        return n_out.reshape(1), ov, bad, tuple(packed)
+        zero = jnp.int32(0)
+        signals = jnp.stack([ov, bad, zero, zero]).astype(np.int32)
+        return n_out.reshape(1), signals, tuple(packed)
 
     row = P("shards")
     fn = jit(get_shard_map()(
         body, mesh=mesh, in_specs=(row,) * 8,
-        out_specs=(row, P(), P(), (row,) * 9), check_rep=False,
+        out_specs=(row, P(), (row,) * 9), check_rep=False,
     ), wide=True)
     S = lambda rows: jax.ShapeDtypeStruct(  # noqa: E731
         (chips * rows,), np.int32)

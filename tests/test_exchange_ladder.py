@@ -178,3 +178,52 @@ def test_mesh_of_one_has_no_exchange_block_and_no_ladder():
     moved = summary["device"]["exchange"]
     assert moved and all(
         e["ici_bytes"] == 0 and e["retries"] == 0 for e in moved.values())
+
+
+# ------------------------- the ladder with a wave in flight behind it
+
+@pytest.mark.parametrize("ndev", [2, 4])
+def test_slack_only_climbs_when_waves_settle_after_the_next_dispatch(
+        monkeypatch, ndev):
+    """The pipelined loop as a TPU runs it (on the CPU its in-flight
+    window is 0, so it is given another backend name to find): every
+    wave overflows slack 1.0, each was dispatched before the one ahead
+    of it retried, and the op's memoised slack is read at every settle:
+    it never falls, every retry is dispatched on a rung at least the
+    memo's, and the job's answer is the serial loop's."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "in-flight")
+    mesh = Mesh(np.array(jax.devices()[:ndev]), ("shards",))
+    ex = MeshExecutor(mesh, prefetch_depth=1)
+    seen, attempts = [], []
+    read, dispatch = ex._read_signals, ex._dispatch_wave_on
+
+    def logged_read(signals):
+        got = read(signals)
+        seen.append((max(ex._slack_memo.values(), default=1.0), got[0]))
+        return got
+
+    def logged_dispatch(tasks, wave, inputs, attempt=0):
+        out = dispatch(tasks, wave, inputs, attempt)
+        if out[1][-1][0] == "shuffle":
+            attempts.append((wave, attempt, out[2]))
+        return out
+
+    ex._read_signals, ex._dispatch_wave_on = logged_read, logged_dispatch
+    sess = Session(executor=ex)
+    try:
+        res, groups = distinct_keys_job(sess, 3, ndev, seed=5)
+        assert len(res.rows()) == groups
+        (block,) = exchange_blocks(sess.telemetry_summary()).values()
+    finally:
+        sess.shutdown()
+    memos = [m for m, _ in seen]
+    assert memos == sorted(memos) and memos[-1] == block["slack"] > 1.0
+    firsts = [a for a in attempts if a[1] == 0]
+    retries = [a for a in attempts if a[1] > 0]
+    assert [w for w, _, _ in firsts] == [0, 1, 2]
+    assert firsts[0][2] == firsts[1][2] == 1.0   # wave 1: before the retry
+    assert block["retries"] == len(retries) >= 2
+    running = 1.0
+    for _, _, slack in retries:
+        assert slack >= running
+        running = slack

@@ -256,6 +256,30 @@ def test_waved_reduce_leaves_every_span_of_the_table(waved):
     assert all(v["self_s"] <= v["total_s"] + 1e-12 for v in spans.values())
 
 
+def test_a_settle_says_whether_its_signals_were_ready(waved):
+    """Every ``settle`` span carries ``ready`` (0 / 1: had the wave
+    finished when the settle opened), and the per-op ``waves`` blocks
+    count the same settles beside their seconds."""
+    summary, doc, _ = waved
+    settles = [e for e in doc["traceEvents"]
+               if e.get("pid") == trace_mod.SPAN_PID
+               and e["name"] == "settle"]
+    assert len(settles) == summary["spans"]["settle"]["count"]
+    assert {e["args"]["ready"] for e in settles} <= {0, 1}
+    blocks = [op["waves"] for op in summary["ops"].values()
+              if "settles" in op.get("waves", {})]
+    # Map side and reduce side of both jobs, WAVES settles each.
+    assert [b["settles"] for b in blocks] == [WAVES] * 4
+    assert all(0 <= b["settles_ready"] <= b["settles"] for b in blocks)
+    assert sum(b["settles_ready"] for b in blocks) == \
+        sum(e["args"]["ready"] for e in settles)
+    assert sum(b["settle_s"] for b in blocks) == pytest.approx(
+        summary["spans"]["settle"]["total_s"], abs=2e-5)
+    assert not any("ready" in e["args"] for e in doc["traceEvents"]
+                   if e.get("pid") == trace_mod.SPAN_PID
+                   and e["name"] == "dispatch")
+
+
 def scanned_output(sess, res):
     """The executor's waved group output behind a scanned result."""
     from bigslice_tpu.exec.meshexec import WavedGroupOutput

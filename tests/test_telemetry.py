@@ -231,6 +231,28 @@ def test_wave_overlap_accounting_pipelined_vs_serial():
     assert piped["overlap_efficiency"] is not None
 
 
+def test_wave_host_record_counts_settles_and_those_found_ready():
+    """``record_wave_host``: seconds by field; a settle also passes
+    ``ready`` and is counted, a dispatch passes none and is not."""
+    hub = telemetry_mod.TelemetryHub()
+    hub.record_wave_compute("reduce@x", 1, 0, 0.5)
+    hub.record_wave_compute("const@x", 1, 0, 0.5)
+    for ready in (1, 0, 1, True):
+        hub.record_wave_host("reduce@x", 1, "dispatch_s", 0.001)
+        hub.record_wave_host("reduce@x", 1, "settle_s", 0.002,
+                             ready=ready)
+    hub.record_wave_host("const@x", 1, "dispatch_s", 0.001)
+    ops = hub.summary()["ops"]
+    waves = ops["reduce@x"]["waves"]
+    assert waves["dispatch_s"] == pytest.approx(0.004)
+    assert waves["settle_s"] == pytest.approx(0.008)
+    assert (waves["settles"], waves["settles_ready"]) == (4, 3)
+    # No settle recorded: no count of them, not a count of 0.
+    assert "settles" not in ops["const@x"]["waves"]
+    assert "settles_ready" not in ops["const@x"]["waves"]
+    json.dumps(ops)
+
+
 # ---------------------------------------------- monitor hardening
 
 def test_raising_monitor_does_not_break_evaluation(capsys):

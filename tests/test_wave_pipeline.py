@@ -773,3 +773,305 @@ def test_wave_programs_of_32_bit_columns_hold_no_64_bit_type(ndev, rows):
         wide = re.findall(
             r"tensor<(?:[0-9?]+x)*(?:[su]?i64|f64)>", text)
         assert not wide, f"{name}: {sorted(set(wide))}"
+
+
+# ------------------------------------------------ the signal vector
+#
+# A wave's four signals come home as ONE replicated int32[4] whose host
+# copy its dispatch started; a settle reads it once (_read_signals) and
+# acts on it before the wave's output is delivered. On the CPU the
+# pipelined loop's in-flight window is 0, so these tests give
+# _execute_waves_pipelined another backend name to find: wave w-1 is
+# then settled after wave w's dispatch, as on a TPU.
+
+def _mesh_of(ndev):
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(jax.devices()[:ndev]), ("shards",))
+
+
+def _loop_executor(monkeypatch, ndev, loop, **kw):
+    """A MeshExecutor running its waved groups on the ``serial`` loop
+    (prefetch_depth 0) or the ``pipelined`` one with a wave in flight,
+    and the list its dispatches and signal reads are logged to:
+    ``("dispatch", wave, attempt)`` / ``("read", (overflow, badrange,
+    gbover, hashov))``."""
+    if loop == "pipelined":
+        monkeypatch.setattr(jax, "default_backend", lambda: "in-flight")
+    ex = MeshExecutor(_mesh_of(ndev),
+                      prefetch_depth=0 if loop == "serial" else 1, **kw)
+    log = []
+    dispatch, read = ex._dispatch_wave_on, ex._read_signals
+
+    def logged_dispatch(tasks, wave, inputs, attempt=0):
+        log.append(("dispatch", wave, attempt))
+        return dispatch(tasks, wave, inputs, attempt)
+
+    def logged_read(signals):
+        got = read(signals)
+        log.append(("read", got))
+        return got
+
+    ex._dispatch_wave_on, ex._read_signals = logged_dispatch, logged_read
+    return ex, log
+
+
+def _in_flight_settles(log):
+    """Reads that came after a LATER wave's first dispatch: the i-th
+    dispatched attempt is the i-th read, so a read preceded by more
+    dispatches than reads settles a wave with another in flight."""
+    ahead, dispatched, read = 0, 0, 0
+    for e in log:
+        if e[0] == "dispatch":
+            dispatched += 1
+        else:
+            read += 1
+            ahead += dispatched > read
+    return ahead
+
+
+def _sum_oracle(keys, vals):
+    uniq, inv = np.unique(keys, return_inverse=True)
+    return dict(zip(uniq.tolist(),
+                    np.bincount(inv, weights=vals).astype(int).tolist()))
+
+
+def _case_bucket_overflow(sess, ndev):
+    """All-distinct keys: at slack 1.0 the fullest bucket of every wave
+    lacks rows, the slack ladder retries it."""
+    n = 3 * ndev * 512
+    keys = np.random.default_rng(ndev).permutation(n).astype(np.int32)
+    res = sess.run(bs.Reduce(bs.Const(3 * ndev, keys,
+                                      np.ones(n, np.int32)),
+                             lambda a, b: a + b))
+    assert dict(res.rows()) == {k: 1 for k in range(n)}
+    memo = sess.executor._slack_memo
+    assert len(memo) == 1 and max(memo.values()) > 1.0
+    return "overflow"
+
+
+def _case_cogroup_deficit(sess, ndev):
+    """A group of 300 rows against a starting capacity of 8: the
+    deficit rides ``overflow`` into the capacity retry."""
+    rng = np.random.default_rng(5)
+    keys = np.concatenate([np.zeros(300, np.int32),
+                           rng.integers(1, 10, 212).astype(np.int32)])
+    vals = np.arange(512, dtype=np.int32)
+    perm = rng.permutation(512)
+    keys, vals = keys[perm], vals[perm]
+    got = {int(k): sorted(int(v) for v in g) for k, g in
+           sess.run(bs.Cogroup(bs.Const(2 * ndev, keys, vals))).rows()}
+    want = {}
+    for k, v in zip(keys.tolist(), vals.tolist()):
+        want.setdefault(k, []).append(v)
+    assert got == {k: sorted(v) for k, v in want.items()}
+    assert max(sess.executor._cogroup_caps.values()) >= 300
+    return "overflow"
+
+
+def _case_groupby_capacity(sess, ndev):
+    """One group of 40 rows a shard against ``capacity=4`` with
+    ``on_overflow='error'``: raises, and names the capacity."""
+    from bigslice_tpu.exec.task import TaskError
+
+    n = 2 * ndev * 40
+    g = bs.GroupByKey(bs.Const(2 * ndev, np.zeros(n, np.int32),
+                               np.arange(n, dtype=np.int32)),
+                      capacity=4, on_overflow="error")
+    with pytest.raises((TaskError, ValueError), match="capacity"):
+        sess.run(g).rows()
+    return "gbover"
+
+
+def _case_partition_out_of_range(sess, ndev):
+    """A partitioner that returns ``nparts``: a user error, raised as
+    the host tier raises it, not chased up the slack ladder."""
+    from bigslice_tpu.exec.task import TaskError
+
+    rp = bs.Repartition(
+        bs.Const(2 * ndev, np.arange(2 * ndev * 32, dtype=np.int32)),
+        lambda k, nparts: (k % nparts) + 1)
+    with pytest.raises(TaskError, match="outside"):
+        sess.run(rp)
+    assert not sess.executor._slack_memo
+    return "badrange"
+
+
+def _case_hash_cascade(sess, ndev):
+    """All-distinct keys at load factor 1: the claim cascade cannot
+    place them, the op rebuilds on the sort path for good."""
+    n = 2 * ndev * (8192 if ndev == 1 else 2048)
+    keys = (np.random.default_rng(13).permutation(n).astype(np.int32)
+            + (1 << 20))
+    res = sess.run(bs.Reduce(bs.Const(2 * ndev, keys,
+                                      np.ones(n, np.int32)),
+                             lambda a, b: a + b))
+    assert dict(res.rows()) == {k: 1 for k in keys.tolist()}
+    assert len(sess.executor._hash_off) == 1
+    return "hashov"
+
+
+#: name -> (job, executor options, mesh sizes it can happen on).
+SIGNAL_CASES = {
+    # A mesh of one exchanges nothing: no bucket to overflow.
+    "bucket_overflow": (_case_bucket_overflow, {}, (8,)),
+    "cogroup_deficit": (_case_cogroup_deficit, {}, (1, 8)),
+    "groupby_capacity": (_case_groupby_capacity, {}, (1, 8)),
+    "partition_out_of_range": (_case_partition_out_of_range, {}, (1, 8)),
+    "hash_cascade": (_case_hash_cascade,
+                     {"auto_dense": False, "hash_aggregate": True},
+                     (1, 8)),
+}
+_SIGNAL_ORDER = ("overflow", "badrange", "gbover", "hashov")
+
+
+@pytest.mark.parametrize("loop", ["serial", "pipelined"])
+@pytest.mark.parametrize("case,ndev", [
+    (c, n) for c, (_, _, meshes) in sorted(SIGNAL_CASES.items())
+    for n in meshes])
+def test_each_signal_raises_or_retries_on_both_loops(monkeypatch, case,
+                                                     ndev, loop):
+    job, opts, _ = SIGNAL_CASES[case]
+    ex, log = _loop_executor(monkeypatch, ndev, loop, **opts)
+    sess = Session(executor=ex)
+    try:
+        signal = job(sess, ndev)
+    finally:
+        sess.shutdown()
+    reads = [e[1] for e in log if e[0] == "read"]
+    dispatches = [e for e in log if e[0] == "dispatch"]
+    # A wave raises one signal at a time, each in its own slot of the
+    # vector, and the one this case is about came home in its own.
+    assert all(sum(1 for v in r if v) <= 1 for r in reads)
+    at = _SIGNAL_ORDER.index(signal)
+    mine = [r for r in reads if r[at] > 0]
+    assert mine
+    retries = [d for d in dispatches if d[2] > 0]
+    if signal in ("gbover", "badrange"):
+        # An error, raised at the first wave that says so: nothing is
+        # read after it, and the wave in flight behind it is dropped
+        # unread and undelivered.
+        assert len(mine) == 1 and reads[-1] == mine[0]
+        assert log[-1][0] == "read"
+        assert len(dispatches) - len(reads) == (loop == "pipelined")
+    else:
+        # Every dispatched attempt was read, once; a retry dispatches
+        # the SAME wave again right after the read that asked for it.
+        assert len(reads) == len(dispatches)
+        assert len(retries) >= 1
+        for i, e in enumerate(log):
+            if e[0] == "dispatch" and e[2] > 0:
+                assert log[i - 1][0] == "read" and any(log[i - 1][1])
+    assert (_in_flight_settles(log) > 0) == (loop == "pipelined")
+
+
+@pytest.mark.parametrize("ndev,rows", [(1, 512), (8, 512)])
+def test_wave_program_returns_one_replicated_signal_vector(ndev, rows):
+    """The lowered wave programs hold ONE int32[4] signal output — not
+    four scalars — between the counts and the columns; replicated: four
+    elements whatever the mesh, where the counts have one a device."""
+    import re
+
+    texts = _wave_program_texts(ndev, rows)
+    assert set(texts) == {"bs_group_shuffle", "bs_group_combine",
+                          "bs_group_filter"}
+    for name, text in texts.items():
+        (results,) = re.findall(
+            r"func\.func public @main\(.*?\)\s*->\s*\((.*?)\)\s*\{",
+            text, re.S)
+        types = re.findall(
+            r'(tensor<[^>]*>) \{jax\.result_info = "([^"]*)"', results)
+        assert types[0] == (f"tensor<{ndev}xi32>", "result[0]"), name
+        assert types[1] == ("tensor<4xi32>", "result[1]"), name
+        assert all(info.startswith("result[2][") for _, info in types[2:])
+        assert not [t for t, _ in types if t == "tensor<i32>"], name
+
+
+@pytest.mark.parametrize("loop", ["serial", "pipelined"])
+def test_a_settle_is_one_host_read(monkeypatch, loop):
+    """Every ``settle`` span goes through ``_read_signals`` once, and
+    that turns ONE device array into a host array."""
+    ex, log = _loop_executor(monkeypatch, 8, loop)
+    logged_read = ex._read_signals
+    conversions = []
+
+    class Counted:
+        def __init__(self, signals):
+            self.signals = signals
+
+        def __array__(self, *args, **kwargs):
+            conversions.append(self.signals.shape)
+            return np.asarray(self.signals)
+
+    ex._read_signals = lambda signals: logged_read(Counted(signals))
+    sess = Session(executor=ex)
+    try:
+        keys = np.tile(np.arange(64, dtype=np.int32), 24 * 4)
+        res = sess.run(bs.Reduce(bs.Const(24, keys, np.ones_like(keys)),
+                                 lambda a, b: a + b))
+        assert dict(res.rows()) == {k: 96 for k in range(64)}
+        spans = sess.telemetry_summary()["spans"]
+    finally:
+        sess.shutdown()
+    waves = 2 * 3                         # map side + reduce side
+    assert spans["settle"]["count"] == spans["dispatch"]["count"] == waves
+    assert conversions == [(4,)] * waves
+    assert len([e for e in log if e[0] == "read"]) == waves
+
+
+@pytest.mark.parametrize("ndev", [4, 8])
+def test_overflow_settled_after_the_next_dispatch_reruns_alone(
+        monkeypatch, ndev):
+    """Wave 0 overflows a bucket and is settled AFTER wave 1 was
+    dispatched (at slack 1.0): wave 0 alone is dispatched again, on the
+    rung its signal sized; wave 1, which fits, is not; its settle does
+    not lower the op's memoised slack, and wave 2 is dispatched on it."""
+    rows = 512
+    rng = np.random.default_rng(33 + ndev)
+    n0 = ndev * rows
+    # Wave 0: all-distinct keys. Waves 1, 2: 16 keys, on every shard.
+    keys = np.concatenate([
+        rng.permutation(n0).astype(np.int32) + 1000,
+        np.tile(np.arange(16, dtype=np.int32), 2 * n0 // 16)])
+    vals = rng.integers(1, 9, len(keys)).astype(np.int32)
+
+    def run(loop):
+        ex, log = _loop_executor(monkeypatch, ndev, loop)
+        slacks = []
+        program = ex._program
+
+        def logged_program(task, caps, slack, **kw):
+            slacks.append(slack)
+            return program(task, caps, slack, **kw)
+
+        ex._program = logged_program
+        sess = Session(executor=ex)
+        try:
+            res = sess.run(bs.Reduce(bs.Const(3 * ndev, keys, vals),
+                                     lambda a, b: a + b))
+            rows_out = dict(res.rows())
+            blocks = [rec["exchange"] for rec in
+                      sess.telemetry_summary()["ops"].values()
+                      if "exchange" in rec]
+            memo = dict(ex._slack_memo)
+        finally:
+            sess.shutdown()
+            monkeypatch.undo()
+        return rows_out, blocks, memo, log, slacks
+
+    got, (block,), memo, log, slacks = run("pipelined")
+    assert got == _sum_oracle(keys, vals)
+    assert got == run("serial")[0]
+    map_side = log[:log.index(("dispatch", 0, 0), 1)]
+    rung = block["slack"]
+    assert block["retries"] == 1 and rung > 1.0
+    assert list(memo.values()) == [rung]
+    kinds = [e if e[0] == "dispatch" else ("read", e[1][0] > 0)
+             for e in map_side]
+    assert kinds == [
+        ("dispatch", 0, 0), ("dispatch", 1, 0),
+        ("read", True),                   # wave 0, wave 1 in flight
+        ("dispatch", 0, 1), ("read", False),
+        ("dispatch", 2, 0), ("read", False),    # wave 1, at slack 1.0
+        ("read", False)]
+    assert slacks[:4] == [1.0, 1.0, rung, rung]
