@@ -44,7 +44,11 @@ from bigslice_tpu.ops.mapops import Map, MapBatches, Filter, Flatmap, Head, Scan
 from bigslice_tpu.ops.reduce import Reduce
 from bigslice_tpu.ops.fold import Fold
 from bigslice_tpu.ops.cogroup import Cogroup
-from bigslice_tpu.ops.join import JoinAggregate
+from bigslice_tpu.ops.join import (
+    DuplicateBuildKeyError,
+    JoinAggregate,
+    JoinLookup,
+)
 from bigslice_tpu.ops.groupby import GroupByKey
 from bigslice_tpu.ops.attention import SelfAttend
 from bigslice_tpu.ops.parquet import ParquetReader
@@ -80,6 +84,8 @@ __all__ = [
     "Fold",
     "Cogroup",
     "JoinAggregate",
+    "JoinLookup",
+    "DuplicateBuildKeyError",
     "GroupByKey",
     "SelfAttend",
     "ParquetReader",

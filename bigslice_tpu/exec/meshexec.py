@@ -1364,7 +1364,7 @@ class MeshExecutor:
         from bigslice_tpu.ops.const import Const
         from bigslice_tpu.ops.fold import Fold
         from bigslice_tpu.ops.groupby import GroupByKey
-        from bigslice_tpu.ops.join import JoinAggregate
+        from bigslice_tpu.ops.join import JoinAggregate, JoinLookup
         from bigslice_tpu.ops.mapops import (
             Filter,
             Flatmap,
@@ -1423,6 +1423,15 @@ class MeshExecutor:
                 if s is not task.chain[-1]:
                     return False
                 if not all(fc.device for fc in s.frame_combiners):
+                    return False
+                if not all(ct.is_device and ct.shape == ()
+                           for d in s.deps() for ct in d.slice.schema):
+                    return False
+                continue
+            if isinstance(s, JoinLookup):
+                # Two-input stage, innermost only, scalar device
+                # columns on both sides (they ride one sort).
+                if s is not task.chain[-1]:
                     return False
                 if not all(ct.is_device and ct.shape == ()
                            for d in s.deps() for ct in d.slice.schema):
@@ -2171,6 +2180,28 @@ class MeshExecutor:
             wide_columns=sum(is_wide(ct.dtype)
                              for ct in task0.schema.values))
 
+    def _settle_lookup(self, task0: Task, joined) -> None:
+        """One wave of a ``JoinLookup`` group, from its signals: a
+        build side with two rows of one key is the user's error; the
+        rows probed, built on and matched feed the op's ``join``
+        block."""
+        dup, probe_rows, build_rows, matched_rows = joined
+        if dup:
+            from bigslice_tpu.ops.join import DuplicateBuildKeyError
+
+            raise DuplicateBuildKeyError(task0.name.op, dup)
+        hub = self._telemetry_hub()
+        if hub is None:
+            return
+        from bigslice_tpu.slicetype import is_wide
+
+        hub.record_join(
+            task0.name.op, task0.name.inv_index, probe_rows,
+            build_rows, matched_rows, lowering="sort",
+            wide_columns=sum(
+                is_wide(ct.dtype)
+                for ct in task0.chain[-1].schema.values))
+
     def _telemetry_compute(self, task0: Task, wave: int,
                            dur_s: float) -> None:
         hub = self._telemetry_hub()
@@ -2913,11 +2944,13 @@ class MeshExecutor:
         return raw, stages, slack
 
     @staticmethod
-    def _read_signals(signals) -> Tuple[int, int, int, int]:
+    def _read_signals(signals) -> Tuple[int, ...]:
         """A wave's ``(overflow, badrange, gbover, hashov)`` on the
-        host: the ONE device-to-host read of a settle. The vector is
-        replicated, so every process reads its own addressable copy,
-        and the transfer is the one ``_dispatch_wave_on`` started."""
+        host — behind them, from a lookup join's program, ``(dup,
+        probe_rows, build_rows, matched_rows)``: the ONE device-to-host
+        read of a settle. The vector is replicated, so every process
+        reads its own addressable copy, and the transfer is the one
+        ``_dispatch_wave_on`` started."""
         return tuple(np.asarray(signals).tolist())
 
     @staticmethod
@@ -2988,7 +3021,7 @@ class MeshExecutor:
             # otherwise this is where the host is blocked on the device.
             ready = int(signals.is_ready())
             with span("settle", wave=wave, ready=ready) as settling:
-                overflow, badrange, gbover, hashov = (
+                overflow, badrange, gbover, hashov, *joined = (
                     self._read_signals(signals))
             self._telemetry_wave_host(tasks[0], "settle_s",
                                       settling.seconds, ready=ready)
@@ -3087,6 +3120,9 @@ class MeshExecutor:
             if dev is not None:
                 dev.record_exchange_retry(task0.name.op,
                                           task0.name.inv_index)
+        if joined:
+            # The attempt that stands: a retried one is not counted.
+            self._settle_lookup(task0, joined)
         # Donation effectiveness: how much of what this wave handed to
         # XLA under donate_argnums was actually consumed (aliased).
         self._telemetry_donation(task0, inputs)
@@ -3943,7 +3979,7 @@ class MeshExecutor:
         from bigslice_tpu.ops.cogroup import Cogroup
         from bigslice_tpu.ops.fold import Fold
         from bigslice_tpu.ops.groupby import GroupByKey
-        from bigslice_tpu.ops.join import JoinAggregate
+        from bigslice_tpu.ops.join import JoinAggregate, JoinLookup
         from bigslice_tpu.ops.mapops import Filter, Flatmap, Head, Map
         from bigslice_tpu.ops.reduce import Reduce
 
@@ -4015,6 +4051,8 @@ class MeshExecutor:
                      s.num_shards),
                     s,
                 ))
+            elif isinstance(s, JoinLookup):
+                stages.append(("joinlookup", s.prefix, s))
         if task.num_partition > 1:
             fc = task.partitioner.combiner
             pf = task.partitioner.partition_fn
@@ -4241,6 +4279,9 @@ class MeshExecutor:
             # the retry loop never confuses it with bucket-slack skew
             # or cogroup capacity deficits (which share `overflow`).
             hashov = jnp.int32(0)
+            # A lookup join's own signals, behind the four: duplicate
+            # build keys, then the rows it probed, built on and matched.
+            joined = None
             run_stages = stages
             if stages and stages[0][0] == "join":
                 mask, cols, jbad, jov = join_prelude(
@@ -4248,6 +4289,19 @@ class MeshExecutor:
                 )
                 badrange = badrange + jbad
                 hashov = hashov + jov
+                run_stages = stages[1:]
+            elif stages and stages[0][0] == "joinlookup":
+                # N:1 lookup (parallel/join.make_lookup_align): the
+                # producer shuffles routed both sides' equal keys here,
+                # so one sort of the union with the build rows ahead
+                # and one segmented carry join this device's region.
+                from bigslice_tpu.parallel.join import make_lookup_align
+
+                mask, cols, dup = make_lookup_align(stages[0][1])(
+                    masks[0], col_sets[0], masks[1], col_sets[1])
+                joined = lax.psum(jnp.stack(
+                    [dup, masks[0].sum(), masks[1].sum(), mask.sum()]
+                ).astype(np.int32), axis)
                 run_stages = stages[1:]
             elif stages and stages[0][0] == "cogroup":
                 # N-ary ragged grouping: one tagged sort over the
@@ -4610,11 +4664,14 @@ class MeshExecutor:
                     cols = list(cols)
                     overflow = overflow + ov
                     badrange = badrange + nb
-            # The wave's four signals as ONE replicated vector, in the
-            # order _read_signals unpacks: one output buffer for a
-            # dispatch to wrap, one device-to-host copy for a settle.
+            # The wave's four signals (a lookup join's four behind
+            # them) as ONE replicated vector, in the order the settle
+            # unpacks: one output buffer for a dispatch to wrap, one
+            # device-to-host copy for a settle.
             signals = jnp.stack(
                 [overflow, badrange, gbover, hashov]).astype(np.int32)
+            if joined is not None:
+                signals = jnp.concatenate([signals, joined])
             if not mask_dirty:
                 # Map-only single-input chain: counts pass through.
                 return (jnp.asarray(counts_list[0][0]).reshape(1),
@@ -4719,7 +4776,8 @@ class MeshExecutor:
                 out.append((kind, s.fanout))
             elif kind == "filter":
                 out.append((kind,))
-            elif kind in ("head", "groupby", "attend", "cogroup"):
+            elif kind in ("head", "groupby", "attend", "cogroup",
+                          "joinlookup"):
                 # These struct ids are already id()-free (scalars,
                 # dtypes, discovered capacities) — pass them through.
                 out.append((kind, sid))

@@ -225,7 +225,24 @@ class Result(Slice):
             if id(t) not in seen:
                 seen.add(id(t))
                 stack.extend(p for d in t.deps for p in d.tasks)
-        stack = list(self.tasks)
+        self._discard_behind(self.tasks, seen)
+
+    def discard_inputs(self) -> None:
+        """Keep this result's own stored output and drop everything it
+        was computed from: the stored outputs of every task behind its
+        own. The result still reads and still feeds a later run; what
+        is gone is recomputed only if this result's own output is lost.
+        A job that keeps its answer for a later one and wants the rest
+        of its memory back calls this in place of ``discard_graph``."""
+        mine = {id(t) for t in self.tasks}
+        self._discard_behind(
+            [p for t in self.tasks for d in t.deps for p in d.tasks],
+            mine)
+
+    def _discard_behind(self, tasks, seen: set) -> None:
+        """Discard ``tasks`` and everything behind them, but for the
+        tasks whose ids ``seen`` holds (and what only they lead to)."""
+        stack = list(tasks)
         while stack:
             t = stack.pop()
             if id(t) not in seen:

@@ -81,6 +81,54 @@ def make_align(nkeys: int, nvals_a: int, nvals_b: int):
     return align
 
 
+def make_lookup_align(nkeys: int):
+    """Build the N:1 lookup kernel of the Slice tier's ``JoinLookup``
+    groups (meshexec).
+
+    ``align(mask_p, cols_p, mask_b, cols_b) -> (match, out_cols, dup)``:
+    the probe side keeps every selected row, the build side holds at
+    most one selected row a key. The build rows are concatenated AHEAD
+    of the probe rows and the union is stable-sorted by (validity,
+    keys) alone, so a key's build row leads its segment without a tag
+    among the sort keys; one segmented carry
+    (``segment.carry_segment_head``) then hands the build row's values
+    to the probe rows behind it. ``match`` selects the probe rows whose
+    key has a build row; ``out_cols`` is keys + probe values + build
+    values in sorted position (callers chain the mask or compact).
+    ``dup`` counts the build rows that follow another build row of
+    their key — the evidence of a build side that is not unique.
+    """
+    import jax.numpy as jnp
+
+    from bigslice_tpu.parallel import segment
+
+    def align(mask_p, cols_p, mask_b, cols_b):
+        size_p = cols_p[0].shape[0]
+        size_b = cols_b[0].shape[0]
+        keys = [jnp.concatenate([b, p])
+                for b, p in zip(cols_b[:nkeys], cols_p[:nkeys])]
+        is_build = jnp.concatenate([jnp.ones(size_b, np.int32),
+                                    jnp.zeros(size_p, np.int32)])
+        pvals = [jnp.concatenate([jnp.zeros((size_b,), v.dtype), v])
+                 for v in cols_p[nkeys:]]
+        bvals = [jnp.concatenate([v, jnp.zeros((size_p,), v.dtype)])
+                 for v in cols_b[nkeys:]]
+        s_inv, s_keys, s_pay, diff = segment.sort_and_segment(
+            nkeys, jnp.concatenate([mask_b, mask_p]), keys,
+            [is_build] + pvals + bvals,
+        )
+        s_isb = s_pay[0]
+        s_pvals = s_pay[1 : 1 + len(pvals)]
+        head = segment.carry_segment_head(
+            diff, (s_isb,) + tuple(s_pay[1 + len(pvals):]))
+        dup = jnp.sum(((s_isb == 1) & ~diff).astype(np.int32))
+        match = (s_isb == 0) & (head[0] == 1) & (s_inv == 0)
+        return (match, list(s_keys) + list(s_pvals) + list(head[1:]),
+                dup)
+
+    return align
+
+
 class MeshJoinAggregate:
     """Inner-join two keyed, single-value-column sides after per-side
     reduction. ``__call__`` takes per-side (keys, vals, counts) global

@@ -170,6 +170,16 @@ def segmented_combine(diff, s_vals, cfn):
     return is_last, tuple(red)
 
 
+def carry_segment_head(diff, cols):
+    """Every row reads its segment's FIRST row: the segmented scan of
+    ``segmented_combine`` with "keep the earlier" as the combine.
+    ``diff`` marks segment starts; returns the carried columns. What a
+    lookup join needs after its sort — the build row leads its key's
+    segment, and the probe rows behind it read its values."""
+    _, carried = segmented_combine(diff, cols, lambda a, b: a)
+    return carried
+
+
 def make_segmented_reduce_masked(nkeys: int, nvals: int, cfn,
                                  compact: bool = False):
     """Mask-based variant of the segmented reduce core.

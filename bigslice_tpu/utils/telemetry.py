@@ -171,6 +171,9 @@ class _OpRecord:
         self.wave_host_s: Dict[str, float] = {}
         self.settles = 0
         self.settles_ready = 0
+        # -- a lookup join's waves (record_join): None for every other
+        # op, which then has no ``join`` block
+        self.join: Optional[dict] = None
 
 
 class DeadlineStats:
@@ -676,6 +679,29 @@ class TelemetryHub:
         self._emit("bigslice:combineInput", op=op, inv=inv,
                    in_rows=in_rows, out_rows=out_rows)
 
+    def record_join(self, op: str, inv: Optional[int],
+                    probe_rows: int, build_rows: int,
+                    matched_rows: int, lowering: str,
+                    wide_columns: int = 0) -> None:
+        """One wave of a lookup join (``JoinLookup``), from the wave's
+        own signals: the rows of the probe and of the build side that
+        reached the join, the probe rows that found their build row,
+        which ``lowering`` joined them and how many 64-bit value
+        columns the output carries. Sums since the session began, so a
+        window reads them as deltas."""
+        with self._lock:
+            rec = self._op(op, inv)
+            if rec.join is None:
+                rec.join = {"waves": 0, "probe_rows": 0,
+                            "build_rows": 0, "matched_rows": 0}
+            j = rec.join
+            j["waves"] += 1
+            j["probe_rows"] += int(probe_rows)
+            j["build_rows"] += int(build_rows)
+            j["matched_rows"] += int(matched_rows)
+            j["lowering"] = lowering
+            j["wide_columns"] = int(wide_columns)
+
     def record_deadline(self, outcome: str, tenant: str = "",
                         deadline_s=None, source: str = "") -> None:
         """One deadline-ladder outcome (met / expired /
@@ -958,6 +984,9 @@ class TelemetryHub:
                         "lowering": rec.combine_lowering,
                         "wide_columns": rec.combine_wide_columns,
                     }
+                if rec.join is not None:
+                    # The op's lookup join (record_join).
+                    entry["join"] = dict(rec.join)
                 ex = exchanged.get(op)
                 if ex and ex["ici_messages"] + ex["dcn_messages"]:
                     # A shuffle whose collective moved something (a

@@ -52,3 +52,36 @@ def pytest_configure(config):
         "slow: full-matrix recompile variants outside the tier-1 "
         "'not slow' budget",
     )
+
+
+@pytest.fixture
+def benchmark_modules():
+    """``(root, run, control)``: the benchmark's two commands as
+    modules, for the tests that rehearse a cell end to end."""
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for p in (root, os.path.join(root, "benchmarks")):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    import control
+    import run
+
+    return root, run, control
+
+
+@pytest.fixture
+def compile_cache_as_found():
+    """The benchmark's command places JAX's persistent compile cache
+    for its process; a test process takes it away again."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    found = {n: getattr(jax.config, n) for n in names}
+    yield
+    for n, v in found.items():
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()

@@ -274,3 +274,34 @@ def test_result_discard_graph_keeps_the_named_results(sess):
     # base survived: a second round over it gives the same answer.
     assert dict(run().rows()) == {k: 2 * v for k, v in want.items()}
     assert sum(v for _, v in base.rows()) == 64
+
+
+def test_result_discard_inputs_keeps_its_own_output_only(sess):
+    """discard_inputs frees every task behind a result and none of its
+    own: the result reads without recomputing anything, and feeds a
+    later run."""
+    keys = np.arange(96, dtype=np.int32) % 7
+    want = {int(k): int((keys == k).sum()) for k in range(7)}
+
+    def add(a, b):
+        return a + b
+
+    doubled = bs.Map(bs.Const(4, keys, np.ones(96, np.int32)),
+                     lambda k, v: (k, v * 2))
+    res = sess.run(bs.Reduce(doubled, add))
+    discarded, submitted = [], []
+    ex = sess.executor
+    real_discard, real_submit = ex.discard, ex.submit
+    ex.discard = lambda t: (discarded.append(t), real_discard(t))[1]
+    res.discard_inputs()
+    mine = {id(t) for t in res.tasks}
+    behind = {id(p) for t in res.tasks for d in t.deps for p in d.tasks}
+    assert discarded and behind <= {id(t) for t in discarded}
+    assert not mine & {id(t) for t in discarded}
+    # Its own output is still stored: a read submits no task.
+    ex.submit = lambda t: (submitted.append(t), real_submit(t))[1]
+    assert dict(res.rows()) == {k: 2 * v for k, v in want.items()}
+    assert not submitted
+    ex.discard, ex.submit = real_discard, real_submit
+    again = sess.run(bs.Map(res, lambda k, v: (k, v + 1)))
+    assert dict(again.rows()) == {k: 2 * v + 1 for k, v in want.items()}

@@ -240,45 +240,15 @@ def test_declared_int32_column_checks_too():
 # -- TPC-H Q1, the deployment that drives all of it ----------------------
 
 
-def _benchmarks():
-    import os
-    import sys
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    for p in (root, os.path.join(root, "benchmarks")):
-        if p not in sys.path:
-            sys.path.insert(0, p)
-    import control
-    import run
-
-    return root, run, control
-
-
-@pytest.fixture
-def compile_cache_as_found():
-    """The benchmark's command places JAX's persistent compile cache
-    for its process; a test process takes it away again."""
-    from jax.experimental.compilation_cache import compilation_cache
-
-    names = ("jax_compilation_cache_dir",
-             "jax_persistent_cache_min_compile_time_secs",
-             "jax_persistent_cache_min_entry_size_bytes")
-    found = {n: getattr(jax.config, n) for n in names}
-    yield
-    for n, v in found.items():
-        jax.config.update(n, v)
-    compilation_cache.reset_cache()
-
-
 @pytest.mark.parametrize("seed", [5, 2147483999])
 def test_q1_cell_rehearses_correct_with_the_dense_table(
-        capsys, compile_cache_as_found, seed):
+        capsys, benchmark_modules, compile_cache_as_found, seed):
     """``benchmarks/run.py --workload q1.sf1 --cpu-rehearsal``: the
     pipeline as the cell runs it, every sum equal to the ``int64``
     reference, on the mesh, through the dense table."""
     import json
 
-    root, run, _ = _benchmarks()
+    root, run, _ = benchmark_modules
     rc = run.main(["--workload", "q1.sf1", "--seed", str(seed),
                    "--seconds", "0.5", "--trace", "1",
                    "--cpu-rehearsal"], root=root)
@@ -297,12 +267,12 @@ def test_q1_cell_rehearses_correct_with_the_dense_table(
 
 
 @pytest.mark.parametrize("seed", [1, 2, 3000000019])
-def test_q1_controls_come_out_not_correct(seed):
+def test_q1_controls_come_out_not_correct(benchmark_modules, seed):
     """A row left out, sums carried in 32 bits (what the parent
     computed), ``<`` for ``<=``: each reads at least one wrong row."""
     from benchmarks.harness import discover
 
-    root, _, control = _benchmarks()
+    root, _, control = benchmark_modules
     cell = discover.find_cell(root, "q1.sf1", rehearsal=True)
     readings = control.control_readings(cell, seed)
     assert set(readings) == {"row_dropped", "sums_in_int32",
