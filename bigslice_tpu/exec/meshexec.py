@@ -314,10 +314,16 @@ class DeviceGroupOutput:
     def __init__(self, cols, counts, capacity: int, schema,
                  partitioned: bool, subid: bool = False,
                  nmesh: Optional[int] = None,
-                 subid_ordered: bool = False):
+                 subid_ordered: bool = False,
+                 rows_max: Optional[int] = None):
         self.cols = cols
         self.counts = counts
         self.capacity = capacity
+        # The valid rows of the fullest device, on the host: the fifth
+        # element of the signal vector that settled the wave, so known
+        # without a read of ``counts``. None where no settle produced
+        # this output (a merged, offloaded or resized one).
+        self.rows_max = rows_max
         self.schema = schema
         # Mesh size at production time: partition/shard → device
         # indexing must use THIS, not the executor's current mesh
@@ -2180,6 +2186,26 @@ class MeshExecutor:
             wide_columns=sum(is_wide(ct.dtype)
                              for ct in task0.schema.values))
 
+    def _telemetry_merge(self, task0, caps, full, bounds) -> None:
+        """One cross-wave merge, for the per-op ``merge`` block: the
+        slots it read a wave (``caps``) against the waves' capacities
+        (``full``) and the rows they can hold (``bounds``: each wave's
+        ``rows_max``, its capacity where it has none) — host integers
+        all, over every device."""
+        hub = self._telemetry_hub()
+        if hub is None:
+            return
+        try:
+            hub.record_merge(
+                task0.name.op, task0.name.inv_index, len(caps),
+                slots=sum(caps) * self.nmesh,
+                slots_full=sum(full) * self.nmesh,
+                rows_bound=self.nmesh * sum(
+                    c if b is None else b
+                    for b, c in zip(bounds, full)))
+        except Exception:
+            pass
+
     def _settle_lookup(self, task0: Task, joined) -> None:
         """One wave of a ``JoinLookup`` group, from its signals: a
         build side with two rows of one key is the user's error; the
@@ -2881,13 +2907,24 @@ class MeshExecutor:
                 donate = ()
         return caps, counts_list, cols_flat, subids, donate
 
+    def _full_slack(self, task0: Task) -> float:
+        """The ladder's top rung: the bucket slack at which the shuffle
+        of ``task0`` cannot overflow — its destination devices, since a
+        source can send at most ``capacity`` rows to one destination
+        lane. The hierarchical exchange needs the full mesh bound: stage
+        2's per-group buckets must absorb a stage-1 receive buffer that
+        worst-case concentrates I devices' whole capacity on one group
+        (cap2 = cap·s/D ≥ I·cap ⇒ s ≥ D·I)."""
+        if self.topo.is_hier:
+            return float(self.nmesh)
+        return float(max(1, min(task0.num_partition, self.nmesh)))
+
     def _wave_slack(self, task0: Task) -> float:
         # Skew handling: retry with geometrically larger per-destination
-        # bucket slack; slack == nmesh makes overflow impossible (a
-        # source can send at most `capacity` rows to one destination).
-        # This is the recompile-averse bucketing strategy from SURVEY.md
-        # §7.3(1)/(5) — a bounded set of compiled programs, no dynamic
-        # shapes.
+        # bucket slack, up to the rung at which overflow is impossible
+        # (_full_slack). This is the recompile-averse bucketing strategy
+        # from SURVEY.md §7.3(1)/(5) — a bounded set of compiled
+        # programs, no dynamic shapes.
         #
         # Combiner-bearing shuffles start at slack 1.0: map-side
         # combining bounds each destination's load by the shard's
@@ -2896,12 +2933,14 @@ class MeshExecutor:
         # combine must sort, the pipeline's single largest pass.
         # Low-reduction data overflows once, retries bigger, and the
         # adapted slack is remembered per op so the probe cost is paid
-        # once per session, not per wave/run.
+        # once per session, not per wave/run. No shuffle starts above
+        # the top rung: to one destination a bucket of slack 1.0 holds
+        # every row a wave can send.
         has_combiner = (task0.num_partition > 1
                         and task0.partitioner.combiner is not None)
-        return self._slack_memo.get(
-            _op_base(task0.name.op), 1.0 if has_combiner else 2.0
-        )
+        start = min(1.0 if has_combiner else 2.0,
+                    self._full_slack(task0))
+        return self._slack_memo.get(_op_base(task0.name.op), start)
 
     def _dispatch_wave_on(self, tasks: List[Task], wave: int, inputs,
                           attempt: int = 0):
@@ -2945,12 +2984,12 @@ class MeshExecutor:
 
     @staticmethod
     def _read_signals(signals) -> Tuple[int, ...]:
-        """A wave's ``(overflow, badrange, gbover, hashov)`` on the
-        host — behind them, from a lookup join's program, ``(dup,
-        probe_rows, build_rows, matched_rows)``: the ONE device-to-host
-        read of a settle. The vector is replicated, so every process
-        reads its own addressable copy, and the transfer is the one
-        ``_dispatch_wave_on`` started."""
+        """A wave's ``(overflow, badrange, gbover, hashov, rows_max)``
+        on the host — behind them, from a lookup join's program,
+        ``(dup, probe_rows, build_rows, matched_rows)``: the ONE
+        device-to-host read of a settle. The vector is replicated, so
+        every process reads its own addressable copy, and the transfer
+        is the one ``_dispatch_wave_on`` started."""
         return tuple(np.asarray(signals).tolist())
 
     @staticmethod
@@ -3021,8 +3060,8 @@ class MeshExecutor:
             # otherwise this is where the host is blocked on the device.
             ready = int(signals.is_ready())
             with span("settle", wave=wave, ready=ready) as settling:
-                overflow, badrange, gbover, hashov, *joined = (
-                    self._read_signals(signals))
+                (overflow, badrange, gbover, hashov, rows_max,
+                 *joined) = self._read_signals(signals)
             self._telemetry_wave_host(tasks[0], "settle_s",
                                       settling.seconds, ready=ready)
             if not (has_shuffle or is_cogroup):
@@ -3082,16 +3121,7 @@ class MeshExecutor:
                 continue
             if not has_shuffle or overflow == 0:
                 break
-            # slack == ndest makes overflow impossible (a source can
-            # send at most `capacity` rows to one destination lane).
-            # The hierarchical exchange needs the full mesh bound:
-            # stage 2's per-group buckets must absorb a stage-1
-            # receive buffer that worst-case concentrates I devices'
-            # whole capacity on one group (cap2 = cap·s/D ≥ I·cap ⇒
-            # s ≥ D·I).
-            full_slack = float(max(
-                2, self.nmesh if self.topo.is_hier else ndest
-            ))
+            full_slack = self._full_slack(task0)
             if slack >= full_slack:
                 raise RuntimeError(
                     f"mesh shuffle overflow in group {task0.name.op} "
@@ -3134,6 +3164,7 @@ class MeshExecutor:
             list(out_cols), out_counts, out_capacity, task0.schema,
             partitioned=task0.num_partition > 1,
             subid=has_shuffle and out_subid, nmesh=self.nmesh,
+            rows_max=rows_max,
         )
 
     def _merge_outputs(self, outs: List[DeviceGroupOutput],
@@ -3167,8 +3198,17 @@ class MeshExecutor:
         ncols = len(outs[0].cols)
         dtypes = ((("int32",) if outs[0].subid else ())
                   + tuple(str(ct.dtype) for ct in task0.schema))
-        caps = tuple(o.capacity for o in outs)
+        full = tuple(o.capacity for o in outs)
         W = len(outs)
+        # A wave's output is front-packed, so everything behind its
+        # fullest device's count is padding: read each wave up to the
+        # bucket of the largest count the settles brought home, full
+        # capacity where a wave has none.
+        bounds = [o.rows_max for o in outs]
+        caps = full
+        if None not in bounds:
+            B = bucket_size(max(bounds))
+            caps = tuple(min(c, B) for c in full)
         fc = task0.partitioner.combiner
         mc = (task0.partitioner.combine_key
               and fc is not None and getattr(fc, "device", False)
@@ -3181,8 +3221,11 @@ class MeshExecutor:
         # merge donates them wholesale: the W-way concat reuses their
         # HBM instead of holding W waves + the merge result live.
         donate = self._donation_on()
+        # The parent's key where nothing is left out; the capacities
+        # the slices are cut from where something is.
+        sliced = (full,) if caps != full else ()
         key = ("merge", ncols, caps, dtypes, donate, has_subid,
-               (id(fc.fn), fc.nkeys, fc.nvals) if mc else None)
+               (id(fc.fn), fc.nkeys, fc.nvals) if mc else None) + sliced
         with self._lock:
             cached = self._programs.get(key)
         if cached is not None:
@@ -3203,8 +3246,10 @@ class MeshExecutor:
                     for w in range(W)
                 ])
                 merged = [
-                    jnp.concatenate([flat[w * ncols + j]
-                                     for w in range(W)])
+                    jnp.concatenate([
+                        flat[w * ncols + j] if caps[w] == full[w]
+                        else flat[w * ncols + j][:caps[w]]
+                        for w in range(W)])
                     for j in range(ncols)
                 ]
                 if mc:
@@ -3249,7 +3294,8 @@ class MeshExecutor:
             # the trace branches on).
             prog = self._obs_program(
                 prog, "merge",
-                (ncols, caps, dtypes, donate, bool(mc), bool(has_subid)),
+                (ncols, caps, dtypes, donate, bool(mc), bool(has_subid))
+                + sliced,
                 fns=(fc.fn,) if mc else (),
                 extra=(fc.nkeys, fc.nvals) if mc else None,
             )
@@ -3261,6 +3307,7 @@ class MeshExecutor:
             *[o.counts for o in outs],
             *[c for o in outs for c in o.cols],
         )
+        self._telemetry_merge(task0, caps, full, bounds)
         return DeviceGroupOutput(
             list(cols), counts, sum(caps), task0.schema,
             partitioned=True, subid=has_subid, nmesh=self.nmesh,
@@ -4664,20 +4711,29 @@ class MeshExecutor:
                     cols = list(cols)
                     overflow = overflow + ov
                     badrange = badrange + nb
-            # The wave's four signals (a lookup join's four behind
-            # them) as ONE replicated vector, in the order the settle
-            # unpacks: one output buffer for a dispatch to wrap, one
+            if mask_dirty:
+                # Final compaction to the front-packed (cols, count)
+                # contract.
+                out_n, cols = segment.compact_by_mask(mask, cols)
+            else:
+                # Map-only single-input chain: counts pass through.
+                out_n = jnp.asarray(counts_list[0][0])
+            # The output rows of the fullest device, which the
+            # cross-wave merge sizes its reads by. Each device fills its
+            # own slot of a vector and the slots are summed: a psum as
+            # the other signals are, so the compiler folds it into
+            # their all-reduce where a pmax would be one more a wave.
+            mine = jnp.arange(nmesh, dtype=np.int32) == lax.axis_index(axis)
+            rows_max = lax.psum(jnp.where(mine, out_n, 0), axis).max()
+            # The wave's signals as ONE replicated vector, in the order
+            # the settle unpacks — a lookup join's four behind the
+            # five: one output buffer for a dispatch to wrap, one
             # device-to-host copy for a settle.
             signals = jnp.stack(
-                [overflow, badrange, gbover, hashov]).astype(np.int32)
+                [overflow, badrange, gbover, hashov, rows_max]
+            ).astype(np.int32)
             if joined is not None:
                 signals = jnp.concatenate([signals, joined])
-            if not mask_dirty:
-                # Map-only single-input chain: counts pass through.
-                return (jnp.asarray(counts_list[0][0]).reshape(1),
-                        signals, tuple(cols))
-            # Final compaction to the front-packed (cols, count) contract.
-            out_n, cols = segment.compact_by_mask(mask, cols)
             return out_n.reshape(1), signals, tuple(cols)
 
         if stages and stages[0][0] == "cogroup":

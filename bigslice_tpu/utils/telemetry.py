@@ -174,6 +174,9 @@ class _OpRecord:
         # -- a lookup join's waves (record_join): None for every other
         # op, which then has no ``join`` block
         self.join: Optional[dict] = None
+        # -- the cross-wave merges of a waved shuffle's outputs
+        # (record_merge): None for an op that merged nothing
+        self.merge: Optional[dict] = None
 
 
 class DeadlineStats:
@@ -702,6 +705,28 @@ class TelemetryHub:
             j["lowering"] = lowering
             j["wide_columns"] = int(wide_columns)
 
+    def record_merge(self, op: str, inv: Optional[int], waves: int,
+                     slots: int, slots_full: int,
+                     rows_bound: int) -> None:
+        """One cross-wave merge of a waved shuffle's outputs, from host
+        integers: how many ``waves`` it concatenated, the ``slots`` it
+        read and sorted over all devices, the waves' whole capacities
+        (``slots_full``: what a merge at full capacity reads) and
+        ``rows_bound``, the rows those waves can hold at most (a
+        wave's fullest device's count times the devices). Sums since
+        the session began, so a window reads them as deltas."""
+        with self._lock:
+            rec = self._op(op, inv)
+            if rec.merge is None:
+                rec.merge = {"merges": 0, "waves": 0, "slots": 0,
+                             "slots_full": 0, "rows_bound": 0}
+            m = rec.merge
+            m["merges"] += 1
+            m["waves"] += int(waves)
+            m["slots"] += int(slots)
+            m["slots_full"] += int(slots_full)
+            m["rows_bound"] += int(rows_bound)
+
     def record_deadline(self, outcome: str, tenant: str = "",
                         deadline_s=None, source: str = "") -> None:
         """One deadline-ladder outcome (met / expired /
@@ -987,6 +1012,9 @@ class TelemetryHub:
                 if rec.join is not None:
                     # The op's lookup join (record_join).
                     entry["join"] = dict(rec.join)
+                if rec.merge is not None:
+                    # The op's cross-wave merges (record_merge).
+                    entry["merge"] = dict(rec.merge)
                 ex = exchanged.get(op)
                 if ex and ex["ici_messages"] + ex["dcn_messages"]:
                     # A shuffle whose collective moved something (a

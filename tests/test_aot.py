@@ -15,6 +15,8 @@ only one process may load the TPU's library, so nothing here runs at
 import time, and all such tests stay in one file (one xdist worker).
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -220,6 +222,17 @@ def test_aot_hash_reduce_compiles_for_tpu(mesh, tpu_branches):
     assert "all-to-all" in compiled.as_text()
 
 
+def _rows_max(n_out, chips):
+    """The wave's fifth signal as ``meshexec._program`` computes it:
+    every device fills its own slot of a vector, the slots are summed
+    and the fullest is taken — a psum, like the signals ahead of it."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    mine = jnp.arange(chips, dtype=np.int32) == lax.axis_index("shards")
+    return lax.psum(jnp.where(mine, n_out, 0), "shards").max()
+
+
 @pytest.mark.parametrize("chips", [1, 4])
 def test_aot_fused_map_side_wave_holds_no_scatter(topo, tpu_branches,
                                                   chips):
@@ -245,9 +258,11 @@ def test_aot_fused_map_side_wave_holds_no_scatter(topo, tpu_branches,
         mask = jnp.arange(size, dtype=np.int32) < n[0]
         rm, ov, bad, oc = fused.masked(mask, k, v)
         n_out, packed = segment.compact_by_mask(rm, oc)
-        # The signals as meshexec._program returns them: one vector.
+        # The signals as meshexec._program returns them: one vector,
+        # the fullest device's output rows behind the four.
         zero = jnp.int32(0)
-        return n_out.reshape(1), jnp.stack([ov, bad, zero, zero]), packed
+        signals = jnp.stack([ov, bad, zero, zero, _rows_max(n_out, chips)])
+        return n_out.reshape(1), signals, packed
 
     row = P("shards")
     fn = jax.jit(get_shard_map()(
@@ -261,6 +276,10 @@ def test_aot_fused_map_side_wave_holds_no_scatter(topo, tpu_branches,
     assert text.count(" sort(") == 3
     assert _mosaic_calls_in(text, pk.HASH_PARTITION_KERNEL) == 1
     assert ("all-to-all" in text) == (chips > 1)
+    # The row count rides the all-reduce the overflow and bad-range
+    # signals already share: one a wave on four chips, none on one.
+    assert len(re.findall(r"= .* all-reduce(?:-start)?\(", text)) == (
+        chips > 1)
 
 
 @pytest.mark.parametrize("chips", [1, 4])
@@ -306,7 +325,9 @@ def test_aot_wide_dense_map_side_wave_compiles_for_tpu(topo, tpu_branches,
         rm, ov, bad, oc = routed.masked(m, *keys, *vals)
         n_out, packed = segment.compact_by_mask(rm, oc)
         zero = jnp.int32(0)
-        signals = jnp.stack([ov, bad, zero, zero]).astype(np.int32)
+        signals = jnp.stack(
+            [ov, bad, zero, zero, _rows_max(n_out, chips)]
+        ).astype(np.int32)
         return n_out.reshape(1), signals, tuple(packed)
 
     row = P("shards")

@@ -227,3 +227,101 @@ def test_slack_only_climbs_when_waves_settle_after_the_next_dispatch(
     for _, _, slack in retries:
         assert slack >= running
         running = slack
+
+
+# ------------------------------------ where the ladder starts and ends
+
+def _shuffling_task(combiner, nparts=46):
+    from types import SimpleNamespace as NS
+
+    return NS(num_partition=nparts, name=NS(op="shuffle@here"),
+              partitioner=NS(combiner=combiner))
+
+
+@pytest.mark.parametrize("ndev,combiner,start", [
+    (1, None, 1.0), (4, None, 2.0), (8, None, 2.0),
+    (1, object(), 1.0), (4, object(), 1.0), (8, object(), 1.0),
+])
+def test_a_shuffle_starts_no_higher_than_the_top_rung(ndev, combiner,
+                                                      start):
+    """A plain shuffle starts at slack 2.0, a combiner-bearing one at
+    1.0 — and neither above the rung at which overflow is impossible:
+    to one destination a bucket of slack 1.0 holds a wave's every row."""
+    ex = MeshExecutor(Mesh(np.array(jax.devices()[:ndev]), ("shards",)))
+    assert ex._full_slack(_shuffling_task(combiner)) == float(ndev)
+    assert ex._wave_slack(_shuffling_task(combiner)) == start
+    # Fewer partitions than devices: the destinations are what counts.
+    assert ex._wave_slack(_shuffling_task(None, nparts=2)) == min(
+        2.0, float(min(2, ndev)))
+    # What a retry remembered stands.
+    ex._slack_memo["shuffle@here"] = 1.25
+    assert ex._wave_slack(_shuffling_task(combiner)) == 1.25
+
+
+def test_top_rung_of_the_ladder_and_of_the_start_are_one_function():
+    """``_full_slack`` alone says where the ladder ends: lowered to 1.0
+    on a mesh of four, a plain shuffle starts there, and the wave whose
+    buckets overflow it has no rung left to retry on."""
+    from bigslice_tpu.exec.task import TaskError
+
+    ex = MeshExecutor(Mesh(np.array(jax.devices()[:4]), ("shards",)))
+    asked = []
+    ex._full_slack = lambda task: asked.append(task.num_partition) or 1.0
+    assert ex._wave_slack(_shuffling_task(None)) == 1.0
+    assert asked == [46]
+    sess = Session(executor=ex)
+    try:
+        with pytest.raises((TaskError, RuntimeError),
+                           match="even at full slack"):
+            distinct_keys_job(sess, 3, 4, seed=7)
+    finally:
+        sess.shutdown()
+    # The start of every dispatched wave, and the retry that found no
+    # rung: the job's own shuffle of 12 partitions.
+    assert set(asked[1:]) == {12} and len(asked) > 2
+    assert not ex._slack_memo
+
+
+def test_every_row_to_one_partition_on_a_mesh_of_one_settles_at_1():
+    """A plain shuffle on a mesh of one has one destination: at slack
+    1.0 its bucket is the wave's capacity, so the most skewed routing
+    there is — every row to partition 0 — fits without a retry, and the
+    wave emits ``cap`` slots, not 2 × ``cap``."""
+    ex = MeshExecutor(Mesh(np.array(jax.devices()[:1]), ("shards",)))
+    dispatch, settle = ex._dispatch_wave_on, ex._execute_wave_on_locked
+    slacks, outs = [], []
+
+    def logged_dispatch(tasks, wave, inputs, attempt=0):
+        out = dispatch(tasks, wave, inputs, attempt)
+        if any(kind == "shuffle" for kind, _, _ in out[1]):
+            slacks.append((attempt, out[2], inputs[0][2]))
+        return out
+
+    def logged_settle(*args):
+        out = settle(*args)
+        if out.partitioned:
+            outs.append(out.capacity)
+        return out
+
+    ex._dispatch_wave_on = logged_dispatch
+    ex._execute_wave_on_locked = logged_settle
+    sess = Session(executor=ex)
+    try:
+        n = 3 * ROWS
+        keys = np.arange(n, dtype=np.int32)
+        res = sess.run(bs.Repartition(bs.Const(3, keys, keys),
+                                      lambda k, nparts: 0 * k))
+        assert sorted(res.rows()) == [(k, k) for k in range(n)]
+        summary = sess.telemetry_summary()
+    finally:
+        sess.shutdown()
+    assert slacks == [(0, 1.0, ROWS)] * 3
+    assert outs == [ROWS] * 3
+    assert not ex._slack_memo
+    assert all(e["retries"] == 0
+               for e in summary["device"]["exchange"].values())
+    # The merge behind it: three full waves, nothing to leave out.
+    (merge,) = [op["merge"] for op in summary["ops"].values()
+                if "merge" in op]
+    assert merge == {"merges": 1, "waves": 3, "slots": n,
+                     "slots_full": n, "rows_bound": n}

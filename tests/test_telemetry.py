@@ -253,6 +253,76 @@ def test_wave_host_record_counts_settles_and_those_found_ready():
     json.dumps(ops)
 
 
+def test_merge_record_sums_slots_read_full_and_the_rows_bound():
+    """``record_merge``: one call a cross-wave merge, host integers
+    only; the block holds sums since the session began, so a window
+    reads them as the difference of two summaries; an op that merged
+    nothing has no block."""
+    hub = telemetry_mod.TelemetryHub()
+    hub.record_wave_compute("reduce@x", 1, 0, 0.5)
+    hub.record_merge("const@x", 1, 46, slots=46 * 1024,
+                     slots_full=46 * 135168, rows_bound=46 * 640)
+    before = hub.summary()["ops"]
+    assert before["const@x"]["merge"] == {
+        "merges": 1, "waves": 46, "slots": 47104,
+        "slots_full": 6217728, "rows_bound": 29440}
+    assert "merge" not in before["reduce@x"]
+    hub.record_merge("const@x", 2, 12, slots=12 * 65536,
+                     slots_full=12 * 262144, rows_bound=12 * 63700)
+    after = hub.summary()["ops"]["const@x"]["merge"]
+    grown = {k: after[k] - before["const@x"]["merge"][k] for k in after}
+    assert grown == {"merges": 1, "waves": 12, "slots": 786432,
+                     "slots_full": 3145728, "rows_bound": 764400}
+    # The summary hands out a copy, not the hub's own record.
+    after["slots"] = 0
+    assert hub.summary()["ops"]["const@x"]["merge"]["slots"] == \
+        47104 + 786432
+    json.dumps(hub.summary()["ops"])
+
+
+@pytest.mark.parametrize("ndev", [1, 8])
+def test_waved_shuffle_feeds_the_merge_block_from_its_settles(ndev):
+    """A waved Filter + Reduce: the map side's merge is recorded once a
+    job under the op that shuffled, in whole devices — slots read,
+    the waves' capacities, and the waves' ``rows_max`` as the bound —
+    and a second job adds to it."""
+    import jax
+    from jax.sharding import Mesh
+
+    from bigslice_tpu.exec.meshexec import MeshExecutor
+
+    rows, waves = 256, 3
+    n = waves * ndev * rows
+    keys = np.random.default_rng(7).integers(0, 1 << 20, n).astype(np.int32)
+    sess = Session(executor=MeshExecutor(
+        Mesh(np.array(jax.devices()[:ndev]), ("shards",))))
+    try:
+        def job():
+            kept = bs.Filter(bs.Const(waves * ndev, keys, np.ones_like(keys)),
+                             lambda k, v: k % 8 == 0)
+            return len(sess.run(bs.Reduce(kept, lambda a, b: a + b)).rows())
+
+        def blocks():
+            return [(op, rec["merge"]) for op, rec in
+                    sess.telemetry_summary()["ops"].items()
+                    if "merge" in rec]
+
+        assert job() == len(np.unique(keys[keys % 8 == 0]))
+        ((op, first),) = blocks()
+        job()
+        (second,) = [m for o, m in blocks() if o != op]
+    finally:
+        sess.shutdown()
+    for m in (first, second):
+        assert m["merges"] == 1 and m["waves"] == waves
+        assert m["slots_full"] == waves * ndev * rows
+        # An eighth of the rows pass: their bucket, not the capacity.
+        assert m["rows_bound"] <= m["slots"] < m["slots_full"]
+        assert m["slots"] % (waves * ndev) == 0
+        assert m["rows_bound"] >= len(np.unique(keys[keys % 8 == 0]))
+    assert first == second
+
+
 # ---------------------------------------------- monitor hardening
 
 def test_raising_monitor_does_not_break_evaluation(capsys):

@@ -658,6 +658,112 @@ def test_machine_combined_merge_is_ordered_and_sliced():
     assert kinds == {"subidsplit": True}
 
 
+# ------------------------- the merge reads a wave up to its rows' bucket
+#
+# A settled wave carries its fullest device's row count on the host
+# (``rows_max``, the fifth signal); the cross-wave merge reads wave w up
+# to ``min(cap_w, bucket_size(largest count))`` slots. What it leaves
+# out was masked out before, so the merged rows are the full-capacity
+# merge's bit for bit.
+
+#: name -> (caps of the waves, rows a device keeps of ``cap``, the wave
+#: that carries no ``rows_max``)
+FILL_CASES = {
+    "about_half": ((128, 128, 128),
+                   lambda rng, cap: rng.binomial(cap, .375), None),
+    "under_one_percent": ((256, 256, 256, 256),
+                          lambda rng, cap: rng.randint(0, 3), None),
+    "all": ((64, 64, 64), lambda rng, cap: cap, None),
+    "none": ((64, 64, 64), lambda rng, cap: 0, None),
+    # A slack retry between waves: the later waves' buckets are larger,
+    # and the bucket of the largest count cuts some waves and not all.
+    "unequal_caps": ((32, 66, 80), lambda rng, cap: rng.randint(20, 33),
+                     None),
+    "one_wave_without_a_count": (
+        (128, 128, 128), lambda rng, cap: rng.binomial(cap, .375), 1),
+}
+
+
+def _settled_waves(case, ndev, known=True, seed=0):
+    """(executor, wave outputs as a settle leaves them, per-device
+    counts a wave): subid outputs of two int32 columns whose rows past
+    a wave's count are garbage. ``known`` False: the same waves with no
+    ``rows_max``, which merge at full capacity."""
+    caps, keep, blind = FILL_CASES[case]
+    ex = MeshExecutor(_mesh_of(ndev))
+    rng = np.random.RandomState(seed)
+    schema = [_ct("int32"), _ct("int32")]
+    outs, kept = [], []
+    for w, cap in enumerate(caps):
+        counts = np.array([keep(rng, cap) for _ in range(ndev)], np.int32)
+        cols = [rng.randint(0, 4, ndev * cap).astype(np.int32),
+                rng.randint(0, 1 << 20, ndev * cap).astype(np.int32),
+                rng.randint(1, 50, ndev * cap).astype(np.int32)]
+        gcols, gcounts = shuffle_mod.place_global_columns(
+            ex.mesh, cols, counts)
+        outs.append(DeviceGroupOutput(
+            list(gcols), gcounts, cap, schema, partitioned=True,
+            subid=True, nmesh=ndev,
+            rows_max=int(counts.max()) if known and w != blind else None,
+        ))
+        kept.append(counts)
+    return ex, outs, _task0(schema), kept
+
+
+def _merge_key(ex):
+    (key,) = [k for k in ex._programs if k[0] == "merge"]
+    return key
+
+
+def _assert_same_merged_rows(got, want, ndev):
+    counts = np.asarray(want.counts)
+    np.testing.assert_array_equal(np.asarray(got.counts), counts)
+    for g, w in zip(got.cols, want.cols):
+        for d in range(ndev):
+            n = counts[d]
+            g_d, w_d = _per_device(g, ndev)[d], _per_device(w, ndev)[d]
+            np.testing.assert_array_equal(g_d[:n], w_d[:n])
+            assert not g_d[n:].any()
+
+
+@pytest.mark.parametrize("ndev", [1, 8])
+@pytest.mark.parametrize("case", sorted(FILL_CASES) + ["machine_combined"])
+def test_merge_reads_each_wave_up_to_the_bucket_of_the_largest_count(
+        case, ndev):
+    fc = None
+    if case == "machine_combined":
+        case = "about_half"
+        fc = SimpleNamespace(fn=lambda a, b: a + b, nkeys=1, nvals=1,
+                             device=True)
+    caps, _, blind = FILL_CASES[case]
+    ex, outs, task0, kept = _settled_waves(case, ndev)
+    full_ex, full_outs, _, _ = _settled_waves(case, ndev, known=False)
+    task0 = _task0(task0.schema, combiner=fc)
+    merged = ex._merge_waves(outs, task0)
+    at_full = full_ex._merge_waves(full_outs, task0)
+    # The same rows a partition, in the same order, zeros behind them.
+    assert at_full.capacity == sum(caps)
+    assert merged.subid_ordered and merged.rows_max is None
+    _assert_same_merged_rows(merged, at_full, ndev)
+    if fc is None:
+        np.testing.assert_array_equal(np.asarray(merged.counts),
+                                      np.sum(kept, axis=0))
+    B = bucket_size(max(int(c.max()) for c in kept))
+    read = tuple(min(cap, B) for cap in caps)
+    if blind is not None or case == "all":
+        # Nothing to leave out, or a wave whose count nobody knows: the
+        # parent's shapes, and its program under its key.
+        read = caps
+        assert _merge_key(ex) == _merge_key(full_ex)
+    else:
+        assert sum(read) < sum(caps)
+        assert _merge_key(ex) == (
+            _merge_key(full_ex)[:2] + (read,) + _merge_key(full_ex)[3:]
+            + (caps,))
+    assert merged.capacity == sum(read)
+    assert merged.cols[0].shape[0] == ndev * sum(read)
+
+
 # ------------------------------------- the wave programs hold no scatter
 
 def _row_indexed_scatters(text):
@@ -777,9 +883,10 @@ def test_wave_programs_of_32_bit_columns_hold_no_64_bit_type(ndev, rows):
 
 # ------------------------------------------------ the signal vector
 #
-# A wave's four signals come home as ONE replicated int32[4] whose host
-# copy its dispatch started; a settle reads it once (_read_signals) and
-# acts on it before the wave's output is delivered. On the CPU the
+# A wave's four signals and its output's row count come home as ONE
+# replicated int32[5] whose host copy its dispatch started; a settle
+# reads it once (_read_signals) and acts on it before the wave's output
+# is delivered. On the CPU the
 # pipelined loop's in-flight window is 0, so these tests give
 # _execute_waves_pipelined another backend name to find: wave w-1 is
 # then settled after wave w's dispatch, as on a TPU.
@@ -795,7 +902,7 @@ def _loop_executor(monkeypatch, ndev, loop, **kw):
     (prefetch_depth 0) or the ``pipelined`` one with a wave in flight,
     and the list its dispatches and signal reads are logged to:
     ``("dispatch", wave, attempt)`` / ``("read", (overflow, badrange,
-    gbover, hashov))``."""
+    gbover, hashov, rows_max))``."""
     if loop == "pipelined":
         monkeypatch.setattr(jax, "default_backend", lambda: "in-flight")
     ex = MeshExecutor(_mesh_of(ndev),
@@ -942,7 +1049,7 @@ def test_each_signal_raises_or_retries_on_both_loops(monkeypatch, case,
     dispatches = [e for e in log if e[0] == "dispatch"]
     # A wave raises one signal at a time, each in its own slot of the
     # vector, and the one this case is about came home in its own.
-    assert all(sum(1 for v in r if v) <= 1 for r in reads)
+    assert all(sum(1 for v in r[:4] if v) <= 1 for r in reads)
     at = _SIGNAL_ORDER.index(signal)
     mine = [r for r in reads if r[at] > 0]
     assert mine
@@ -967,8 +1074,8 @@ def test_each_signal_raises_or_retries_on_both_loops(monkeypatch, case,
 
 @pytest.mark.parametrize("ndev,rows", [(1, 512), (8, 512)])
 def test_wave_program_returns_one_replicated_signal_vector(ndev, rows):
-    """The lowered wave programs hold ONE int32[4] signal output — not
-    four scalars — between the counts and the columns; replicated: four
+    """The lowered wave programs hold ONE int32[5] signal output — not
+    five scalars — between the counts and the columns; replicated: five
     elements whatever the mesh, where the counts have one a device."""
     import re
 
@@ -982,7 +1089,7 @@ def test_wave_program_returns_one_replicated_signal_vector(ndev, rows):
         types = re.findall(
             r'(tensor<[^>]*>) \{jax\.result_info = "([^"]*)"', results)
         assert types[0] == (f"tensor<{ndev}xi32>", "result[0]"), name
-        assert types[1] == ("tensor<4xi32>", "result[1]"), name
+        assert types[1] == ("tensor<5xi32>", "result[1]"), name
         assert all(info.startswith("result[2][") for _, info in types[2:])
         assert not [t for t, _ in types if t == "tensor<i32>"], name
 
@@ -1015,8 +1122,94 @@ def test_a_settle_is_one_host_read(monkeypatch, loop):
         sess.shutdown()
     waves = 2 * 3                         # map side + reduce side
     assert spans["settle"]["count"] == spans["dispatch"]["count"] == waves
-    assert conversions == [(4,)] * waves
+    assert conversions == [(5,)] * waves
     assert len([e for e in log if e[0] == "read"]) == waves
+
+
+def _job_keyed_shuffle(sess, ndev):
+    """A keyed Reduce: a shuffle program, then a reduce-side combine."""
+    n = 2 * ndev * 300
+    keys = np.random.default_rng(2).integers(0, 500, n).astype(np.int32)
+    res = sess.run(bs.Reduce(bs.Const(2 * ndev, keys, np.ones_like(keys)),
+                             lambda a, b: a + b))
+    assert sum(v for _, v in res.rows()) == n
+    return "bs_group_shuffle"
+
+
+def _job_map_only(sess, ndev):
+    """Two uploaded columns through one Map: the counts pass through,
+    and some shards of a wave hold a row fewer than the others."""
+    n = 2 * ndev * 100 - 37
+    x = np.arange(n, dtype=np.int32)
+    res = sess.run(bs.Map(bs.Const(2 * ndev, x, x), lambda a, b: (a, a + b)))
+    assert sorted(res.rows()) == [(i, 2 * i) for i in range(n)]
+    return "bs_group_map"
+
+
+def _job_lookup_join(sess, ndev):
+    pk = np.random.default_rng(4).integers(0, 64, 2 * ndev * 90)
+    bk = np.arange(0, 64, 2)
+    j = bs.JoinLookup(bs.Const(2 * ndev, pk.astype(np.int32),
+                               np.ones(len(pk), np.int32)),
+                      bs.Const(ndev, bk.astype(np.int32),
+                               bk.astype(np.int32)))
+    assert len(sess.run(j).rows()) == int(np.isin(pk, bk).sum())
+    return "bs_group_joinlookup"
+
+
+def _job_retried_shuffle(sess, ndev):
+    assert _case_bucket_overflow(sess, ndev) == "overflow"
+    return "bs_group_shuffle"
+
+
+@pytest.mark.parametrize("job,ndev", [
+    (_job_keyed_shuffle, 8), (_job_map_only, 8), (_job_lookup_join, 8),
+    (_job_retried_shuffle, 8), (_job_keyed_shuffle, 1)])
+def test_fifth_signal_is_the_fullest_devices_output_rows(job, ndev):
+    """Every dispatched attempt of every wave program — a shuffle, a
+    counts-pass-through Map, a lookup join (whose four ride behind), a
+    wave retried up the slack ladder — returns ``int32[5]`` (``[9]``)
+    whose element 4 is ``max(out_counts)``, and the attempt that stands
+    leaves it on its output as ``rows_max``."""
+    ex = MeshExecutor(_mesh_of(ndev))
+    dispatch, settle = ex._dispatch_wave_on, ex._execute_wave_on_locked
+    seen, settled = [], []
+
+    def logged_dispatch(tasks, wave, inputs, attempt=0):
+        out = dispatch(tasks, wave, inputs, attempt)
+        (counts, signals, _), stages, _ = out
+        seen.append((_program_name_of(stages), attempt, signals.shape,
+                     int(np.asarray(signals)[4]), np.asarray(counts)))
+        return out
+
+    def logged_settle(*args):
+        out = settle(*args)
+        settled.append((out.rows_max, int(np.asarray(out.counts).max())))
+        return out
+
+    ex._dispatch_wave_on = logged_dispatch
+    ex._execute_wave_on_locked = logged_settle
+    sess = Session(executor=ex)
+    try:
+        program = job(sess, ndev)
+    finally:
+        sess.shutdown()
+    assert any(name.startswith(program) for name, *_ in seen)
+    for name, _, shape, rows, counts in seen:
+        assert shape == ((9,) if "joinlookup" in name else (5,)), name
+        assert rows == counts.max(), name
+    assert any(attempt for _, attempt, *_ in seen) == (
+        job is _job_retried_shuffle)
+    assert settled and all(known == n for known, n in settled)
+    # The fullest device is not every device: a maximum, not a copy.
+    assert ndev == 1 or any(0 < counts.min() < counts.max()
+                            for *_, counts in seen)
+
+
+def _program_name_of(stages):
+    from bigslice_tpu.exec.meshexec import _program_name
+
+    return _program_name("group", tuple(k for k, _, _ in stages))
 
 
 @pytest.mark.parametrize("ndev", [4, 8])
