@@ -2038,12 +2038,15 @@ class MeshExecutor:
 
     def _telemetry_staging(self, task0: Task, wave: int, dur_s: float,
                            exposed_s: float,
-                           breakdown: Optional[dict] = None) -> None:
+                           breakdown: Optional[dict] = None,
+                           ready: Optional[int] = None) -> None:
         """One wave's input staging time, the portion of it the
         compute thread actually waited on (== dur_s on serial paths;
         the staged.get() wait on the pipelined path), and the
         read/decode/assemble/upload breakdown of where staging time
-        went (the *why* behind overlap_efficiency)."""
+        went (the *why* behind overlap_efficiency). A pipelined wave
+        also says whether it was staged already (``ready``) when the
+        compute thread asked for it."""
         hub = self._telemetry_hub()
         if hub is None:
             return
@@ -2051,7 +2054,7 @@ class MeshExecutor:
             hub.record_wave_staging(task0.name.op,
                                     task0.name.inv_index,
                                     wave, dur_s, exposed_s,
-                                    breakdown=breakdown)
+                                    breakdown=breakdown, ready=ready)
         except Exception:
             pass
 
@@ -2120,18 +2123,34 @@ class MeshExecutor:
 
     def _telemetry_wave_host(self, task0: Task, field: str,
                              dur_s: float,
-                             ready: Optional[int] = None) -> None:
+                             ready: Optional[int] = None,
+                             enqueue_s: Optional[float] = None) -> None:
         """Host seconds of one wave's ``dispatch`` or ``settle`` span,
         by op: the span table knows them by name only, and which
         GROUP's waves cost the host what is the question a job of many
         nearly empty reduce-side waves asks. A settle also says whether
-        its signals were ``ready`` when it opened."""
+        its signals were ``ready`` when it opened; a dispatch, how much
+        of it was its ``enqueue``."""
         hub = self._telemetry_hub()
         if hub is None:
             return
         try:
             hub.record_wave_host(task0.name.op, task0.name.inv_index,
-                                 field, dur_s, ready=ready)
+                                 field, dur_s, ready=ready,
+                                 enqueue_s=enqueue_s)
+        except Exception:
+            pass
+
+    def _telemetry_prefetch_blocked(self, task0: Task,
+                                    blocked_s: float) -> None:
+        """Once a pipelined group: how long its prefetch thread held a
+        staged wave the full queue would not take."""
+        hub = self._telemetry_hub()
+        if hub is None:
+            return
+        try:
+            hub.record_prefetch_blocked(task0.name.op,
+                                        task0.name.inv_index, blocked_s)
         except Exception:
             pass
 
@@ -2284,7 +2303,8 @@ class MeshExecutor:
                     out.counts, "is_fully_addressable", True):
                 import jax
 
-                rows, indices = self._addressable_counts(out.counts)
+                with span("sync.shuffle_counts"):
+                    rows, indices = self._addressable_counts(out.counts)
                 if rows:
                     hub.record_shuffle(
                         task0.name.op, task0.name.inv_index, rows,
@@ -2293,7 +2313,10 @@ class MeshExecutor:
                         rank=int(jax.process_index()),
                     )
                 return
-            counts = np.asarray(out.counts).reshape(-1)
+            # The host waits here for the group's last program (the
+            # cross-wave merge, where it was waved) to finish.
+            with span("sync.shuffle_counts", bytes=out.counts.nbytes):
+                counts = np.asarray(out.counts).reshape(-1)
             hub.record_shuffle(
                 task0.name.op, task0.name.inv_index,
                 [int(c) for c in counts],
@@ -2488,6 +2511,9 @@ class MeshExecutor:
         stop = threading.Event()
 
         group_span = trace_mod.current()
+        # Nanoseconds the stager held a wave the full queue would not
+        # take; handed to the hub once, when the group's waves are done.
+        blocked_ns = [0]
 
         def stage():
             for w in range(1, nwaves):
@@ -2506,12 +2532,18 @@ class MeshExecutor:
                     self._emit_phase(task0, PHASE_WAVE_PREFETCH, w)
                 except BaseException as e:  # noqa: BLE001 — re-raised
                     item = (None, e, 0.0, None)  # in wave order on the
-                while not stop.is_set():   # thread
+                t_full = 0                 # thread
+                while not stop.is_set():
                     try:
-                        staged.put(item, timeout=0.1)
+                        # Without blocking first: only a full queue —
+                        # the stager waiting for the compute thread —
+                        # is timed.
+                        staged.put(item, block=bool(t_full), timeout=0.1)
                         break
                     except queue_mod.Full:
-                        continue
+                        t_full = t_full or trace_mod.now_ns()
+                if t_full:
+                    blocked_ns[0] += trace_mod.now_ns() - t_full
                 if item[1] is not None:
                     return
 
@@ -2564,7 +2596,11 @@ class MeshExecutor:
                 if w == 0:
                     inputs = inputs0
                 else:
-                    with span("stage_wait", wave=w) as waited:
+                    # ``ready``: the wave was staged before this
+                    # thread (the queue's one consumer) asked for it.
+                    ready = int(not staged.empty())
+                    with span("stage_wait", wave=w,
+                              ready=ready) as waited:
                         inputs, err, stage_dur, wstats = staged.get()
                     if err is not None:
                         raise err
@@ -2573,7 +2609,8 @@ class MeshExecutor:
                     # stage_dur - exposed is the pipeline's win.
                     self._telemetry_staging(
                         task0, w, stage_dur,
-                        min(waited.seconds, stage_dur), wstats)
+                        min(waited.seconds, stage_dur), wstats,
+                        ready=ready)
                 self._emit_phase(task0, PHASE_WAVE_COMPUTE, w)
                 # Wave-slot atomicity: on the CPU backend window == 0,
                 # so dispatch + settle happen inside ONE mutex hold —
@@ -2607,6 +2644,7 @@ class MeshExecutor:
                     staged.get_nowait()
                 except queue_mod.Empty:
                     break
+            self._telemetry_prefetch_blocked(task0, blocked_ns[0] * 1e-9)
 
     def _hint_store_prefetch(self, wave_tasks: List[List[Task]],
                              lo: int, hi: int) -> None:
@@ -2678,10 +2716,11 @@ class MeshExecutor:
 
     @contextlib.contextmanager
     def _wave_slot(self):
-        """Hold the wave mutex; the wait for it is the ``mutex_wait``
-        span."""
-        with span("mutex_wait"):
-            self._wave_mutex.acquire()
+        """Hold the wave mutex; where another thread holds it, the
+        wait for it is the ``mutex_wait`` span."""
+        if not self._wave_mutex.acquire(blocking=False):
+            with span("mutex_wait"):
+                self._wave_mutex.acquire()
         try:
             yield
         finally:
@@ -2969,17 +3008,23 @@ class MeshExecutor:
                 for kind, _, s in stages if kind == "map"
                 for a in s.args
             ]
-            raw = program(np.int32(wave), *counts_list, *cols_flat,
-                          *extras)
-            # The program call returned at enqueue: the signals' copy
-            # to the host queues behind the wave on the device's
-            # stream, and the settle collects it (_read_signals).
-            raw[1].copy_to_host_async()
+            # ``enqueue`` is the runtime's part of a dispatch (with the
+            # compile seam's lookup in it); what ``dispatch`` holds
+            # outside it is this executor's.
+            with span("enqueue") as enq:
+                raw = program(np.int32(wave), *counts_list, *cols_flat,
+                              *extras)
+                # The program call returned at enqueue: the signals'
+                # copy to the host queues behind the wave on the
+                # device's stream, and the settle collects it
+                # (_read_signals).
+                raw[1].copy_to_host_async()
             if any(k == "shuffle" for k, _, _ in stages):
                 # Every dispatched attempt (first run and slack retries
                 # alike) put its buckets on the wire.
                 self._telemetry_exchange(task0, wave, inputs, slack)
-        self._telemetry_wave_host(task0, "dispatch_s", sp.seconds)
+        self._telemetry_wave_host(task0, "dispatch_s", sp.seconds,
+                                  enqueue_s=enq.seconds)
         return raw, stages, slack
 
     @staticmethod
@@ -3347,9 +3392,12 @@ class MeshExecutor:
         # Probe the per-(device, subid) row counts: the static region
         # capacity is the observed max (one tiny host sync per output,
         # no overflow ladder needed — the counts ARE the data).
-        per = np.asarray(
-            self._subid_count_program(W, cap)(out.counts, out.cols[0])
-        )
+        with span("sync.subid_count") as syncing:
+            per = np.asarray(
+                self._subid_count_program(W, cap)(out.counts,
+                                                  out.cols[0])
+            )
+            syncing.set(bytes=per.nbytes)
         capr = bucket_size(int(per.max()) if per.size else 1)
         budget = self.device_budget_bytes
         if budget:
@@ -3944,7 +3992,9 @@ class MeshExecutor:
         from bigslice_tpu.parallel import dense as dense_mod
 
         cols, counts, capacity, has_sub, _owned = inputs[0]
-        kmin, kmax = self._key_range(cols, counts, capacity, has_sub)
+        with span("sync.keyrange", bytes=8):   # int32[2] comes home
+            kmin, kmax = self._key_range(cols, counts, capacity,
+                                         has_sub)
         k = kmax + 1
         # League guard (dense_gate's heuristic): a table far larger
         # than the data beats nothing.

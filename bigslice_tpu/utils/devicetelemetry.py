@@ -189,6 +189,11 @@ class _InstrumentedProgram:
         return self._fn.lower(*args, **kw)
 
     def __call__(self, *args):
+        # Entry to the executable's call is the seam's own part of a
+        # program call (lock, signature, lookup): ``lookup_s``, which
+        # the cache-hit record below carries.
+        t0 = time.perf_counter()
+        hit = False
         with self._lock:
             if self._fell_back:
                 compiled = None
@@ -225,10 +230,13 @@ class _InstrumentedProgram:
                         else:
                             compiled = self._compile_locked(sig, args)
                 elif compiled is not None:
-                    self._rec.record_cache_hit(self._op, self._inv,
-                                               self._kind)
+                    hit = True
         if compiled is None:
             return self._fn(*args)
+        if hit:
+            self._rec.record_cache_hit(
+                self._op, self._inv, self._kind,
+                lookup_s=time.perf_counter() - t0)
         try:
             return compiled(*args)
         except (TypeError, ValueError) as e:
@@ -323,6 +331,7 @@ class _OpDeviceRecord:
         self.inv = inv
         self.compiles = 0
         self.cache_hits = 0
+        self.lookup_s = 0.0
         self.cross_session_hits = 0
         self.fallbacks = 0
         self.compile_wall_s = 0.0
@@ -376,6 +385,13 @@ class DeviceTelemetry:
         self._hbm_peak_bytes = 0
         self._hbm_limit_bytes: Optional[int] = None
         self._hbm_source: Optional[str] = None
+        # Samples taken and the seconds they took on the sampling
+        # thread (the window above forgets; these never do).
+        self._hbm_samples = 0
+        self._hbm_sample_s = 0.0
+        # The seam's seconds over every cache-hit call of the session:
+        # per op in the records, which MAX_OPS evicts; whole here.
+        self._lookup_s = 0.0
         self._eventer = eventer
 
     def _emit(self, name: str, **fields) -> None:
@@ -464,16 +480,20 @@ class DeviceTelemetry:
 
     def record_cache_hit(self, op: str, inv: Optional[int],
                          kind: str,
-                         cross_session: bool = False) -> None:
+                         cross_session: bool = False,
+                         lookup_s: float = 0.0) -> None:
         """``cross_session=True`` marks a hit served from the process-
         global program cache (serve/programcache.py) — an executable a
         *previous* Session compiled. Counted inside ``cache_hits`` (it
         is a hit) and again in the ``cross_session_hits`` subset (it
         is the zero-XLA-compile evidence the serving acceptance
-        criterion keys on)."""
+        criterion keys on). ``lookup_s``: what the seam took of this
+        call before it reached the executable."""
         with self._lock:
             rec = self._op(op, inv)
             rec.cache_hits += 1
+            rec.lookup_s += lookup_s
+            self._lookup_s += lookup_s
             if cross_session:
                 rec.cross_session_hits += 1
 
@@ -495,7 +515,9 @@ class DeviceTelemetry:
         ``memory_stats()`` where it reports (TPU/GPU), else the
         ``jax.live_arrays()`` byte sum (virtual CPU meshes report no
         allocator stats; the fallback must not raise — the CPU-backend
-        contract the tests pin). Returns the recorded sample."""
+        contract the tests pin). Returns the recorded sample. What
+        the sample cost the calling thread is summed as ``sample_s``."""
+        t0 = time.perf_counter()
         in_use = peak = 0
         limit: Optional[int] = None
         source = "memory_stats"
@@ -529,12 +551,14 @@ class DeviceTelemetry:
         except Exception:
             return None
         return self.record_hbm(in_use, peak, limit, source=source,
-                               op=op, inv=inv, wave=wave)
+                               op=op, inv=inv, wave=wave,
+                               sample_s=time.perf_counter() - t0)
 
     def record_hbm(self, bytes_in_use: int, peak_bytes: int,
                    limit_bytes: Optional[int], source: str = "",
                    op: Optional[str] = None, inv: Optional[int] = None,
-                   wave: Optional[int] = None) -> dict:
+                   wave: Optional[int] = None,
+                   sample_s: float = 0.0) -> dict:
         sample = {
             "bytes_in_use": int(bytes_in_use),
             "peak_bytes": int(max(peak_bytes, bytes_in_use)),
@@ -557,6 +581,8 @@ class DeviceTelemetry:
                 )
             if source:
                 self._hbm_source = source
+            self._hbm_samples += 1
+            self._hbm_sample_s += sample_s
             self._hbm.append(sample)
             if len(self._hbm) > MAX_HBM_SAMPLES:
                 del self._hbm[0]
@@ -753,6 +779,7 @@ class DeviceTelemetry:
                         "inv": rec.inv,
                         "compiles": rec.compiles,
                         "cache_hits": rec.cache_hits,
+                        "lookup_s": round(rec.lookup_s, 6),
                         "cross_session_hits": rec.cross_session_hits,
                         "fallbacks": rec.fallbacks,
                         "compile_s": round(rec.compile_wall_s, 6),
@@ -844,7 +871,8 @@ class DeviceTelemetry:
             hbm: dict = {}
             if self._hbm:
                 hbm = {
-                    "samples": len(self._hbm),
+                    "samples": self._hbm_samples,
+                    "sample_s": round(self._hbm_sample_s, 6),
                     "source": self._hbm_source,
                     "current_bytes": self._hbm[-1]["bytes_in_use"],
                     "peak_bytes": self._hbm_peak_bytes,
@@ -858,6 +886,7 @@ class DeviceTelemetry:
         totals = {
             "compiles": tot_compiles,
             "cache_hits": tot_hits,
+            "lookup_s": round(self._lookup_s, 6),
             "cross_session_hits": tot_cross,
             "fallbacks": tot_fb,
             "compile_s": round(tot_wall, 6),
@@ -936,7 +965,7 @@ class DeviceTelemetry:
                 }
             hbm: dict = {
                 "peak_bytes": self._hbm_peak_bytes,
-                "samples": len(self._hbm),
+                "samples": self._hbm_samples,
             }
             if self._hbm_limit_bytes:
                 hbm["limit_bytes"] = self._hbm_limit_bytes

@@ -171,6 +171,13 @@ class _OpRecord:
         self.wave_host_s: Dict[str, float] = {}
         self.settles = 0
         self.settles_ready = 0
+        # -- of the pipelined waves' ``stage_wait``s, how many found
+        # their wave staged already, and the seconds the prefetch
+        # thread waited for the compute thread to take one
+        # (record_prefetch_blocked): None until a pipelined group ended
+        self.stage_waits = 0
+        self.stage_waits_ready = 0
+        self.prefetch_blocked_s: Optional[float] = None
         # -- a lookup join's waves (record_join): None for every other
         # op, which then has no ``join`` block
         self.join: Optional[dict] = None
@@ -751,12 +758,16 @@ class TelemetryHub:
     def record_wave_staging(self, op: str, inv: Optional[int],
                             wave: int, dur_s: float,
                             exposed_s: float,
-                            breakdown: Optional[dict] = None) -> None:
+                            breakdown: Optional[dict] = None,
+                            ready: Optional[int] = None) -> None:
         """One wave's input staging: total duration, the portion the
         compute thread actually blocked on (== dur_s on the serial
         path; the wait in ``staged.get()`` on the pipelined path), and
         optionally the read/decode/assemble/upload breakdown of where
-        the staging time went."""
+        the staging time went. A pipelined wave passes ``ready``, its
+        ``stage_wait`` span's field of that name: ``stage_waits``
+        counts them and ``stage_waits_ready`` those whose wave the
+        prefetch thread had staged before the compute thread asked."""
         dur_s = max(0.0, float(dur_s))
         exposed_s = min(max(0.0, float(exposed_s)), dur_s)
         clean: Dict[str, float] = {}
@@ -772,6 +783,9 @@ class TelemetryHub:
             rec.staged_waves += 1
             rec.staged_rows += int((breakdown or {}).get("rows", 0))
             rec.max_wave = max(rec.max_wave, int(wave))
+            if ready is not None:
+                rec.stage_waits += 1
+                rec.stage_waits_ready += bool(ready)
             for k, v in clean.items():
                 rec.stage_phases[k] = rec.stage_phases.get(k, 0.0) + v
         self._emit("bigslice:waveStaging", op=op, inv=inv, wave=wave,
@@ -782,11 +796,15 @@ class TelemetryHub:
 
     def record_wave_host(self, op: str, inv: Optional[int],
                          field: str, dur_s: float,
-                         ready: Optional[int] = None) -> None:
+                         ready: Optional[int] = None,
+                         enqueue_s: Optional[float] = None) -> None:
         """Host seconds of one wave's ``dispatch_s`` or ``settle_s``
         (the spans of those names), summed by op. A settle passes
         ``ready``, the span's field of that name: ``settles`` counts
-        them and ``settles_ready`` those whose wave had finished."""
+        them and ``settles_ready`` those whose wave had finished. A
+        dispatch passes ``enqueue_s``, the seconds of its ``enqueue``
+        child (the jit call and the start of the signals' copy): what
+        is left of ``dispatch_s`` is the executor's own."""
         with self._lock:
             rec = self._op(op, inv)
             host = rec.wave_host_s
@@ -794,6 +812,19 @@ class TelemetryHub:
             if ready is not None:
                 rec.settles += 1
                 rec.settles_ready += bool(ready)
+            if enqueue_s is not None:
+                host["enqueue_s"] = (host.get("enqueue_s", 0.0)
+                                     + max(0.0, float(enqueue_s)))
+
+    def record_prefetch_blocked(self, op: str, inv: Optional[int],
+                                blocked_s: float) -> None:
+        """Once a pipelined group: the seconds its prefetch thread
+        held a staged wave that the full queue would not take — the
+        stager waiting for the compute thread."""
+        with self._lock:
+            rec = self._op(op, inv)
+            rec.prefetch_blocked_s = ((rec.prefetch_blocked_s or 0.0)
+                                      + max(0.0, float(blocked_s)))
 
     def record_wave_compute(self, op: str, inv: Optional[int],
                             wave: int, dur_s: float) -> None:
@@ -998,6 +1029,13 @@ class TelemetryHub:
                         entry["waves"]["settles"] = rec.settles
                         entry["waves"]["settles_ready"] = (
                             rec.settles_ready)
+                    if rec.stage_waits:
+                        entry["waves"]["stage_waits"] = rec.stage_waits
+                        entry["waves"]["stage_waits_ready"] = (
+                            rec.stage_waits_ready)
+                    if rec.prefetch_blocked_s is not None:
+                        entry["waves"]["prefetch_blocked_s"] = round(
+                            rec.prefetch_blocked_s, 6)
                     total_staging += rec.staging_s
                     total_hidden += hidden
                 if rec.combine_boundaries:

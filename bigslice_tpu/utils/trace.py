@@ -345,8 +345,11 @@ class span:
         interval: in the traces it is a marker where the charge is
         made, with ``seconds`` among its arguments; in the table it
         counts like any child, and this span's self time excludes
-        it."""
+        it. A charge of nothing (a source that decoded nothing) leaves
+        no marker and no row."""
         ns = int(seconds * 1e9)
+        if ns <= 0:
+            return
         ids = dict(self.ids, seconds=seconds)
         with TraceAnnotation(ANNOTATION_PREFIX + name, **ids):
             pass

@@ -126,6 +126,68 @@ def test_summary_totals_roll_up():
     assert t["flops"] == 150.0
 
 
+def test_lookup_seconds_grow_with_every_cache_hit_call():
+    """What the seam takes of a program call before it reaches the
+    executable, by op in the compile block and as one sum in the
+    totals — the first call of a signature compiles and adds none."""
+    import jax
+
+    dev = DeviceTelemetry()
+    progs = [dev.instrument(jax.jit(lambda x: x + 1), op, 1, "group", ())
+             for op in ("op_a", "op_b")]
+    x = np.arange(8, dtype=np.int32)
+    seen = []
+    for _ in range(3):
+        for prog in progs:
+            prog(x)
+        s = dev.summary()
+        seen.append((s["totals"]["lookup_s"],
+                     [s["compile"][op]["lookup_s"]
+                      for op in ("op_a", "op_b")]))
+    assert seen[0] == (0.0, [0.0, 0.0])         # compiles, not hits
+    assert 0 < seen[1][0] < seen[2][0] < 0.1
+    assert all(a < b for a, b in zip(seen[1][1], seen[2][1]))
+    assert seen[2][0] == pytest.approx(sum(seen[2][1]), abs=2e-6)
+    assert dev.summary()["compile"]["op_a"]["cache_hits"] == 2
+
+
+def test_total_lookup_seconds_outlive_an_evicted_op():
+    from bigslice_tpu.utils import devicetelemetry as dt
+
+    dev = DeviceTelemetry()
+    for i in range(dt.MAX_OPS + 10):
+        dev.record_cache_hit(f"op{i}", None, "group", lookup_s=0.001)
+    s = dev.summary()
+    assert s["totals"]["lookup_s"] == pytest.approx(
+        0.001 * (dt.MAX_OPS + 10))
+    assert sum(op["lookup_s"] for op in s["compile"].values()) == \
+        pytest.approx(0.001 * dt.MAX_OPS)
+
+
+def test_hbm_block_counts_every_sample_and_its_seconds():
+    """``samples`` and ``sample_s`` are sums since the session began,
+    past the window of samples the block keeps."""
+    import jax
+
+    from bigslice_tpu.utils import devicetelemetry as dt
+
+    dev = DeviceTelemetry()
+    assert dev.summary()["hbm"] == {}
+    devices = jax.devices()[:1]
+    for wave in range(3):
+        dev.sample_hbm(devices, op="op_a", inv=1, wave=wave)
+    hbm = dev.summary()["hbm"]
+    assert hbm["samples"] == 3 and 0 < hbm["sample_s"] < 1.0
+    for _ in range(dt.MAX_HBM_SAMPLES):
+        dev.record_hbm(1, 1, None, sample_s=0.001)
+    later = dev.summary()["hbm"]
+    assert later["samples"] == 3 + dt.MAX_HBM_SAMPLES
+    assert later["sample_s"] == pytest.approx(
+        hbm["sample_s"] + 0.001 * dt.MAX_HBM_SAMPLES, abs=2e-6)
+    assert len(dev._hbm) == dt.MAX_HBM_SAMPLES
+    assert dev.snapshot()["hbm"]["samples"] == later["samples"]
+
+
 def test_op_records_bounded():
     from bigslice_tpu.utils import devicetelemetry as dt
 

@@ -24,8 +24,12 @@ from bigslice_tpu.utils.trace import SpanRecorder, Tracer, span
 #: Every span of docs/observability.md's table.
 TABLE = ("session.run", "compile_tasks", "evaluate", "group",
          "shuffle_plan", "stage", "read", "decode", "assemble", "upload",
-         "stage_wait", "mutex_wait", "dispatch", "settle", "merge",
-         "split", "readback")
+         "stage_wait", "mutex_wait", "dispatch", "enqueue", "settle",
+         "sync.keyrange", "sync.subid_count", "sync.shuffle_counts",
+         "merge", "split", "readback")
+#: Of them, what one client's job over host rows does NOT leave: its
+#: source decodes nothing, and nobody else holds the wave mutex.
+QUIET = ("decode", "mutex_wait")
 WAVES = 4
 
 
@@ -168,6 +172,15 @@ def test_a_charged_child_counts_in_the_table_and_leaves_self_time():
     assert events(rec)["decode"]["args"]["parent"] == reading.id
 
 
+def test_a_charge_of_nothing_leaves_no_row_and_no_event():
+    rec = recorder()
+    with span("read", rec=rec) as reading:
+        reading.charge("decode", 0.0)
+    table = rec.hub.span_table()
+    assert set(table) == {"read"} == set(events(rec))
+    assert table["read"]["self_s"] == table["read"]["total_s"]
+
+
 def test_without_a_recorder_a_span_is_an_annotation_only():
     with span("group", rec=None, parent=None, op="x") as g:
         with span("dispatch", wave=0) as d:
@@ -236,23 +249,33 @@ def waved(tmp_path_factory):
 def test_waved_reduce_leaves_every_span_of_the_table(waved):
     summary, _, _ = waved
     spans = summary["spans"]
-    assert set(spans) == set(TABLE)
+    # No ``decode``: the rows were never encoded, and a charge of
+    # nothing leaves no row. No ``mutex_wait``: one client, so no
+    # acquire of the wave mutex ever waited.
+    assert set(spans) == set(TABLE) - set(QUIET)
     jobs, groups = 2, 2 * 2               # map side + reduce side a job
     waves = groups * WAVES
     want = {"session.run": jobs, "compile_tasks": jobs, "evaluate": jobs,
             "group": groups, "shuffle_plan": groups, "merge": jobs,
-            # The reduce side's views of the merged output, built once.
-            "split": jobs,
+            # The reduce side's views of the merged output, built once:
+            # the count of its regions comes home, then the split.
+            "split": jobs, "sync.subid_count": jobs,
             "stage": waves, "stage_wait": waves, "dispatch": waves,
-            "settle": waves,
+            "enqueue": waves, "settle": waves,
+            # The map side's merged counts, read for the skew record.
+            "sync.shuffle_counts": jobs,
+            # Wave 0 of the group with a dense candidate (the map
+            # side's combiner) probes its key column's range.
+            "sync.keyrange": jobs,
             # Only the map side reads its rows from the host.
-            "read": jobs * WAVES, "decode": jobs * WAVES,
+            "read": jobs * WAVES,
             "assemble": jobs * WAVES, "upload": jobs * WAVES,
             # The scanned result is ONE readback, of every wave.
             "readback": jobs}
     assert {k: spans[k]["count"] for k in want} == want
-    assert spans["mutex_wait"]["count"] >= waves
     assert spans["upload"]["bytes"] > 0 and spans["readback"]["bytes"] > 0
+    assert all(spans[k]["bytes"] > 0 for k in spans
+               if k.startswith("sync."))
     assert all(v["self_s"] <= v["total_s"] + 1e-12 for v in spans.values())
 
 
@@ -278,6 +301,172 @@ def test_a_settle_says_whether_its_signals_were_ready(waved):
     assert not any("ready" in e["args"] for e in doc["traceEvents"]
                    if e.get("pid") == trace_mod.SPAN_PID
                    and e["name"] == "dispatch")
+
+
+def span_events(doc, *names):
+    return [e for e in doc["traceEvents"]
+            if e.get("pid") == trace_mod.SPAN_PID and e["name"] in names]
+
+
+def test_an_enqueue_is_the_runtimes_part_of_its_dispatch(waved):
+    """``enqueue`` (the jit call and the start of the signals' copy) is
+    the one child of ``dispatch``: what ``dispatch`` keeps for itself is
+    the executor's part, and the per-op ``waves`` blocks sum the same
+    seconds beside ``dispatch_s``."""
+    summary, doc, _ = waved
+    spans = summary["spans"]
+    by_id = {e["args"]["id"]: e for e in span_events(doc, *TABLE)}
+    enqueues = span_events(doc, "enqueue")
+    assert len(enqueues) == spans["dispatch"]["count"]
+    for e in enqueues:
+        parent = by_id[e["args"]["parent"]]
+        assert parent["name"] == "dispatch"
+        assert parent["dur"] == pytest.approx(
+            parent["args"]["self_us"] + e["dur"], abs=1e-3)
+    assert spans["dispatch"]["self_s"] + spans["enqueue"]["total_s"] == \
+        pytest.approx(spans["dispatch"]["total_s"], abs=1e-9)
+    assert spans["enqueue"]["self_s"] == spans["enqueue"]["total_s"]
+    blocks = [op["waves"] for op in summary["ops"].values()
+              if "dispatch_s" in op.get("waves", {})]
+    assert len(blocks) == 4 and all(
+        0 < b["enqueue_s"] <= b["dispatch_s"] for b in blocks)
+    assert sum(b["enqueue_s"] for b in blocks) == pytest.approx(
+        spans["enqueue"]["total_s"], abs=2e-5)
+
+
+def test_every_blocking_read_of_a_group_has_a_sync_span(waved):
+    """The three device-to-host reads on a group's path that are
+    neither a settle nor a readback, each where it is made: the key
+    range probe under wave 0 of the map side's group, the subid counts
+    under the reduce side's first ``stage`` (ahead of ``split``), the
+    merged counts under the map side's group after its merge."""
+    _, doc, _ = waved
+    by_id = {e["args"]["id"]: e for e in span_events(doc, *TABLE)}
+    parent = lambda e: by_id[e["args"]["parent"]]      # noqa: E731
+    for e in span_events(doc, "sync.keyrange", "sync.shuffle_counts"):
+        assert parent(e)["name"] == "group"
+        assert parent(e)["args"]["op"].startswith("const@")
+        assert e["args"]["bytes"] > 0
+    for e in span_events(doc, "sync.subid_count"):
+        stage = parent(e)
+        assert stage["name"] == "stage" and stage["args"]["wave"] == 0
+        assert parent(stage)["name"] == "stage_wait"
+        assert parent(parent(stage))["args"]["op"].startswith("reduce@")
+        (split,) = [s for s in span_events(doc, "split")
+                    if s["args"]["parent"] == stage["args"]["id"]]
+        assert e["ts"] + e["dur"] <= split["ts"]
+        assert e["args"]["bytes"] > 0
+    invs = {e["args"]["inv"] for e in span_events(doc, "session.run")}
+    assert len(invs) == 2
+    for inv in invs:
+        key, counts = (
+            [e for e in span_events(doc, name) if e["args"]["inv"] == inv]
+            for name in ("sync.keyrange", "sync.shuffle_counts"))
+        (merge,) = [e for e in span_events(doc, "merge")
+                    if e["args"]["inv"] == inv]
+        first = min(e["ts"] for e in span_events(doc, "dispatch")
+                    if e["args"]["inv"] == inv)
+        assert len(key) == len(counts) == 1
+        assert key[0]["ts"] + key[0]["dur"] <= first
+        assert counts[0]["ts"] >= merge["ts"] + merge["dur"]
+
+
+def test_a_stage_wait_says_whether_its_wave_was_staged(waved):
+    """Every pipelined ``stage_wait`` carries ``ready`` (0 / 1: was the
+    wave in the queue when the compute thread asked), wave 0's inline
+    stage carries none, and the ``waves`` blocks count the same
+    waits."""
+    summary, doc, _ = waved
+    waits = span_events(doc, "stage_wait")
+    inline = [e for e in waits if "ready" not in e["args"]]
+    queued = [e for e in waits if "ready" in e["args"]]
+    assert {e["args"]["wave"] for e in inline} == {0}
+    assert len(inline) == 4 and len(queued) == 4 * (WAVES - 1)
+    assert {e["args"]["ready"] for e in queued} <= {0, 1}
+    blocks = [op["waves"] for op in summary["ops"].values()
+              if "stage_waits" in op.get("waves", {})]
+    assert [b["stage_waits"] for b in blocks] == [WAVES - 1] * 4
+    assert sum(b["stage_waits_ready"] for b in blocks) == \
+        sum(e["args"]["ready"] for e in queued)
+    assert all(b["prefetch_blocked_s"] >= 0 for b in blocks)
+
+
+def slowed(monkeypatch, ex, name, seconds):
+    """``ex.<name>`` as it is, ``seconds`` later."""
+    real = getattr(ex, name)
+
+    def slow(*args, **kw):
+        time.sleep(seconds)
+        return real(*args, **kw)
+
+    monkeypatch.setattr(ex, name, slow)
+
+
+def map_side_waves(sess):
+    """The ``waves`` block of the map side of the session's last job."""
+    return [op["waves"]
+            for name, op in sess.telemetry_summary()["ops"].items()
+            if name.startswith("const@")][-1]
+
+
+def test_a_slow_stager_is_waited_for_and_never_waits(monkeypatch):
+    sess = mesh_session()
+    try:
+        waved_reduce(sess, scan=False)        # compiled: waves are short
+        before = map_side_waves(sess)
+        slowed(monkeypatch, sess.executor, "_stage", 0.2)
+        waved_reduce(sess, seed=3, scan=False)
+        block = map_side_waves(sess)
+    finally:
+        sess.shutdown()
+    assert before is not block
+    assert before["stage_waits"] == block["stage_waits"] == WAVES - 1
+    assert block["stage_waits_ready"] == 0
+    assert block["prefetch_blocked_s"] == 0.0
+
+
+def test_a_stager_that_runs_ahead_is_ready_and_waits_for_the_compute_thread(
+        monkeypatch):
+    sess = mesh_session()
+    try:
+        waved_reduce(sess, scan=False)
+        slowed(monkeypatch, sess.executor, "_dispatch_wave", 0.2)
+        waved_reduce(sess, seed=3, scan=False)
+        block = map_side_waves(sess)
+    finally:
+        sess.shutdown()
+    assert block["stage_waits_ready"] == block["stage_waits"] == WAVES - 1
+    # Depth 1: with wave w + 1 in the queue the stager holds w + 2
+    # until the compute thread, 200 ms a wave, takes the first.
+    assert block["prefetch_blocked_s"] >= 0.1 * (WAVES - 2)
+
+
+def test_mutex_wait_is_a_contended_wait_only():
+    """One client never waits for the wave mutex and leaves no
+    ``mutex_wait``; a group that finds it held (by another group's
+    wave, here by this thread) waits under the span."""
+    sess = mesh_session()
+    ex = sess.executor
+    try:
+        waved_reduce(sess, scan=False)
+        assert "mutex_wait" not in sess.telemetry_summary()["spans"]
+        done = []
+        ex._wave_mutex.acquire()
+        try:
+            job = threading.Thread(target=lambda: done.append(
+                waved_reduce(sess, seed=4, scan=False)))
+            job.start()
+            time.sleep(1.0)                # its wave 0 is waiting by now
+            assert not done
+        finally:
+            ex._wave_mutex.release()
+        job.join(60)
+        assert done and not job.is_alive()
+        waited = sess.telemetry_summary()["spans"]["mutex_wait"]
+    finally:
+        sess.shutdown()
+    assert 1 <= waited["count"] <= 2 * WAVES * 2
+    assert 0.05 <= waited["total_s"] == waited["self_s"]
 
 
 def scanned_output(sess, res):
@@ -402,7 +591,7 @@ def test_staging_records_equal_what_the_spans_summed(waved):
     assert phases["read_s"] == pytest.approx(spans["read"]["self_s"],
                                              **tol)
     assert phases["decode_s"] == pytest.approx(
-        spans["decode"]["total_s"], **tol)
+        spans.get("decode", {"total_s": 0.0})["total_s"], **tol)
     assert phases["assemble_s"] == pytest.approx(
         spans["assemble"]["total_s"], **tol)
     assert phases["upload_s"] == pytest.approx(
@@ -473,7 +662,7 @@ def test_trace_file_has_span_events_slicetrace_still_loads(waved, capsys):
     _, doc, path = waved
     sp = [e for e in doc["traceEvents"]
           if e["ph"] == "X" and e.get("pid") == trace_mod.SPAN_PID]
-    assert {e["name"] for e in sp} == set(TABLE)
+    assert {e["name"] for e in sp} == set(TABLE) - set(QUIET)
     assert all({"id", "inv", "self_us"} <= set(e["args"]) for e in sp)
     assert doc["otherData"]["clock_unix_ns"] == trace_mod.CLOCK.unix_ns
     tasks = [e for e in doc["traceEvents"]

@@ -253,6 +253,33 @@ def test_wave_host_record_counts_settles_and_those_found_ready():
     json.dumps(ops)
 
 
+def test_wave_records_carry_the_enqueue_the_ready_waits_and_the_stager():
+    """The fields that ride on calls a wave already makes: a dispatch's
+    ``enqueue_s`` on ``record_wave_host``, a pipelined wait's ``ready``
+    on ``record_wave_staging``; and once a group
+    ``record_prefetch_blocked``. An op that passed none has none."""
+    hub = telemetry_mod.TelemetryHub()
+    for wave, ready in enumerate((None, 0, 1, 1)):   # wave 0 is inline
+        hub.record_wave_staging("const@x", 1, wave, 0.004, 0.001,
+                                ready=ready)
+        hub.record_wave_host("const@x", 1, "dispatch_s", 0.001,
+                             enqueue_s=0.0007)
+    hub.record_prefetch_blocked("const@x", 1, 0.25)
+    hub.record_prefetch_blocked("const@x", 1, 0.0)
+    hub.record_wave_staging("serial@x", 1, 0, 0.004, 0.004)
+    hub.record_wave_host("serial@x", 1, "dispatch_s", 0.001)
+    ops = hub.summary()["ops"]
+    waves = ops["const@x"]["waves"]
+    assert waves["dispatch_s"] == pytest.approx(0.004)
+    assert waves["enqueue_s"] == pytest.approx(0.0028)
+    assert (waves["stage_waits"], waves["stage_waits_ready"]) == (3, 2)
+    assert waves["staged"] == 4
+    assert waves["prefetch_blocked_s"] == 0.25
+    assert not {"enqueue_s", "stage_waits", "stage_waits_ready",
+                "prefetch_blocked_s"} & set(ops["serial@x"]["waves"])
+    json.dumps(ops)
+
+
 def test_merge_record_sums_slots_read_full_and_the_rows_bound():
     """``record_merge``: one call a cross-wave merge, host integers
     only; the block holds sums since the session began, so a window
