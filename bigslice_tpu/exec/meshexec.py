@@ -638,8 +638,8 @@ class MeshExecutor:
         # are bit-identical to the flat mesh's).
         self.topo = MeshTopology(mesh)
         # Wave pipelining (the overlapped wave pipeline): while wave w's
-        # SPMD program computes, a prefetcher thread stages wave
-        # w+1..w+depth's inputs (host-tier store reads + device_put),
+        # SPMD program computes, prefetch workers stage waves
+        # w+1..w+depth+1's inputs (host-tier store reads + device_put),
         # and up to `depth` dispatched waves stay in flight before their
         # overflow/badrange signals are synced — XLA's async dispatch
         # keeps the device busy across wave boundaries instead of
@@ -2042,7 +2042,7 @@ class MeshExecutor:
                            ready: Optional[int] = None) -> None:
         """One wave's input staging time, the portion of it the
         compute thread actually waited on (== dur_s on serial paths;
-        the staged.get() wait on the pipelined path), and the
+        the wait for the staged wave on the pipelined path), and the
         read/decode/assemble/upload breakdown of where staging time
         went (the *why* behind overlap_efficiency). A pipelined wave
         also says whether it was staged already (``ready``) when the
@@ -2141,16 +2141,18 @@ class MeshExecutor:
         except Exception:
             pass
 
-    def _telemetry_prefetch_blocked(self, task0: Task,
-                                    blocked_s: float) -> None:
-        """Once a pipelined group: how long its prefetch thread held a
-        staged wave the full queue would not take."""
+    def _telemetry_prefetch_blocked(self, task0: Task, blocked_s: float,
+                                    overlapped: int) -> None:
+        """Once a pipelined group: how long its prefetch workers could
+        begin nothing because the loop had not taken what was staged,
+        and how many of its stages began beside another."""
         hub = self._telemetry_hub()
         if hub is None:
             return
         try:
             hub.record_prefetch_blocked(task0.name.op,
-                                        task0.name.inv_index, blocked_s)
+                                        task0.name.inv_index, blocked_s,
+                                        overlapped)
         except Exception:
             pass
 
@@ -2490,11 +2492,14 @@ class MeshExecutor:
                                  wave_tasks: List[List[Task]],
                                  inputs0, depth: int, sink=None
                                  ) -> List[DeviceGroupOutput]:
-        """The pipelined loop: a prefetcher thread stages wave w+1's
-        inputs (store reads, host concat, device_put) while wave w
-        computes, and up to ``depth`` dispatched waves stay in flight
-        before their signal sync — the host never sits idle between
-        waves and the device queue never drains at a wave boundary.
+        """The pipelined loop: prefetch workers stage the waves ahead
+        (store reads, host concat, device_put) while wave w computes,
+        and up to ``depth`` dispatched waves stay in flight before
+        their signal sync — the host never sits idle between waves and
+        the device queue never drains at a wave boundary. A group
+        whose wave 0 was staged by an upload keeps two stages in
+        flight, a group of zero-copy views one (exec/wavestage.py);
+        at most ``depth + 1`` waves are begun and not yet taken here.
 
         Only STAGING runs off-thread; every program dispatch (and every
         collective) stays on this thread in wave order, so SPMD
@@ -2503,57 +2508,29 @@ class MeshExecutor:
         in wave order (identical to the serial loop's), and a retry
         signal on settle re-enters the blocking retry ladder for just
         that wave."""
-        import queue as queue_mod
         from collections import deque
 
+        from bigslice_tpu.exec import wavestage as wavestage_mod
+
         nwaves = len(wave_tasks)
-        staged: "queue_mod.Queue" = queue_mod.Queue(maxsize=depth)
-        stop = threading.Event()
-
         group_span = trace_mod.current()
-        # Nanoseconds the stager held a wave the full queue would not
-        # take; handed to the hub once, when the group's waves are done.
-        blocked_ns = [0]
 
-        def stage():
-            for w in range(1, nwaves):
-                if stop.is_set():
-                    return
-                try:
-                    # Read-ahead hints stay just ahead of staging (the
-                    # store's warm cache is small — hinting every wave
-                    # upfront would evict entries before their read).
-                    inputs, dur, wstats = self._stage(
-                        wave_tasks[w], w, cause=group_span,
-                        before=lambda: self._hint_store_prefetch(
-                            wave_tasks, w + 1, w + 1 + depth),
-                    )
-                    item = (inputs, None, dur, wstats)
-                    self._emit_phase(task0, PHASE_WAVE_PREFETCH, w)
-                except BaseException as e:  # noqa: BLE001 — re-raised
-                    item = (None, e, 0.0, None)  # in wave order on the
-                t_full = 0                 # thread
-                while not stop.is_set():
-                    try:
-                        # Without blocking first: only a full queue —
-                        # the stager waiting for the compute thread —
-                        # is timed.
-                        staged.put(item, block=bool(t_full), timeout=0.1)
-                        break
-                    except queue_mod.Full:
-                        t_full = t_full or trace_mod.now_ns()
-                if t_full:
-                    blocked_ns[0] += trace_mod.now_ns() - t_full
-                if item[1] is not None:
-                    return
+        def stage(w):
+            # Read-ahead hints stay just ahead of staging (the store's
+            # warm cache is small — hinting every wave upfront would
+            # evict entries before their read).
+            staged = self._stage(
+                wave_tasks[w], w, cause=group_span,
+                before=lambda: self._hint_store_prefetch(
+                    wave_tasks, w + 1, w + 1 + depth),
+            )
+            self._emit_phase(task0, PHASE_WAVE_PREFETCH, w)
+            return staged
 
-        stager = threading.Thread(target=stage, daemon=True,
-                                  name="meshwave-prefetch")
-        stager.start()
         # In-flight dispatch window: dispatched-but-unsettled waves to
         # carry. On the CPU PJRT client a dispatch beyond the in-flight
         # computation limit blocks INSIDE the jit call holding the GIL,
-        # starving the prefetch thread of the very overlap this
+        # starving the prefetch workers of the very overlap this
         # pipeline exists for — whereas the settle wait (device→host
         # sync of the signal vector) releases the GIL and lets staging
         # proceed. So on CPU each wave settles before the next
@@ -2587,6 +2564,9 @@ class MeshExecutor:
                 outs.append(out)
             self._telemetry_compute(task0, wv, dur)
 
+        # ``owned`` (an input's fifth field): wave 0 was uploaded.
+        stagers = wavestage_mod.WaveStagers(
+            nwaves, depth, any(i[4] for i in inputs0), stage)
         try:
             for w in range(nwaves):
                 if w:
@@ -2597,11 +2577,11 @@ class MeshExecutor:
                     inputs = inputs0
                 else:
                     # ``ready``: the wave was staged before this
-                    # thread (the queue's one consumer) asked for it.
-                    ready = int(not staged.empty())
+                    # thread (the one that takes them) asked for it.
+                    ready = int(stagers.ready(w))
                     with span("stage_wait", wave=w,
                               ready=ready) as waited:
-                        inputs, err, stage_dur, wstats = staged.get()
+                        inputs, err, stage_dur, wstats = stagers.take(w)
                     if err is not None:
                         raise err
                     # Exposed staging: the part of the stager's work
@@ -2638,13 +2618,9 @@ class MeshExecutor:
                 deliver(*s)
             return outs
         finally:
-            stop.set()
-            while True:  # drain so a parked put() never wedges staging
-                try:
-                    staged.get_nowait()
-                except queue_mod.Empty:
-                    break
-            self._telemetry_prefetch_blocked(task0, blocked_ns[0] * 1e-9)
+            stagers.close()
+            self._telemetry_prefetch_blocked(
+                task0, stagers.blocked_ns * 1e-9, stagers.overlapped)
 
     def _hint_store_prefetch(self, wave_tasks: List[List[Task]],
                              lo: int, hi: int) -> None:
@@ -2730,7 +2706,7 @@ class MeshExecutor:
                before=None):
         """Stage one wave's inputs under its ``stage`` span, on
         whichever thread calls: ``(inputs, seconds, breakdown)``. On the
-        prefetch thread ``cause`` is the group's span, which this one
+        prefetch workers ``cause`` is the group's span, which this one
         runs beside, and ``before`` issues the read-ahead hints first."""
         stats: dict = {}
         with span("stage", rec=self._span_recorder(), cause=cause,
@@ -3541,7 +3517,7 @@ class MeshExecutor:
         chains). ``owned`` marks inputs this call staged itself (fresh
         device arrays nothing else references — donation-eligible), as
         opposed to zero-copy references into live producer outputs.
-        Called from the wave-pipeline prefetcher thread as well as the
+        Called from the wave pipeline's prefetch workers as well as the
         group thread: staging is read-only against executor state plus
         local device_put, never a collective. ``stats`` (optional)
         accumulates the read/decode/assemble/upload breakdown the

@@ -15,8 +15,9 @@ From the ``.xplane.pb`` that ``jax.profiler`` writes (the benchmark's
   no group is open the instant goes to the innermost annotation on a
   thread that holds top-level ones (``bigslice:session.run``, and with
   ``--also bench:`` the benchmark's own); where nothing is open, to
-  ``(no span)``. A thread that only stages (``meshwave-prefetch``: its
-  ``bigslice:stage`` spans stand beside a group, in none) is left out.
+  ``(no span)``. A thread that only stages (a prefetch worker, named
+  ``meshwave-prefetch-<i>``: its ``bigslice:stage`` spans stand beside
+  a group, in none) is left out.
 
 The window is first start to last end of the host annotations kept
 (``bigslice:*`` and the ``--also`` prefixes). ``--jobs N`` prints

@@ -173,11 +173,13 @@ class _OpRecord:
         self.settles_ready = 0
         # -- of the pipelined waves' ``stage_wait``s, how many found
         # their wave staged already, and the seconds the prefetch
-        # thread waited for the compute thread to take one
+        # workers waited for the compute thread to take one, with the
+        # stages that began beside another of their group
         # (record_prefetch_blocked): None until a pipelined group ended
         self.stage_waits = 0
         self.stage_waits_ready = 0
         self.prefetch_blocked_s: Optional[float] = None
+        self.stages_overlapped = 0
         # -- a lookup join's waves (record_join): None for every other
         # op, which then has no ``join`` block
         self.join: Optional[dict] = None
@@ -762,12 +764,12 @@ class TelemetryHub:
                             ready: Optional[int] = None) -> None:
         """One wave's input staging: total duration, the portion the
         compute thread actually blocked on (== dur_s on the serial
-        path; the wait in ``staged.get()`` on the pipelined path), and
-        optionally the read/decode/assemble/upload breakdown of where
-        the staging time went. A pipelined wave passes ``ready``, its
-        ``stage_wait`` span's field of that name: ``stage_waits``
-        counts them and ``stage_waits_ready`` those whose wave the
-        prefetch thread had staged before the compute thread asked."""
+        path; the wait for the prefetch workers on the pipelined path),
+        and optionally the read/decode/assemble/upload breakdown of
+        where the staging time went. A pipelined wave passes ``ready``,
+        its ``stage_wait`` span's field of that name: ``stage_waits``
+        counts them and ``stage_waits_ready`` those whose wave a
+        prefetch worker had staged before the compute thread asked."""
         dur_s = max(0.0, float(dur_s))
         exposed_s = min(max(0.0, float(exposed_s)), dur_s)
         clean: Dict[str, float] = {}
@@ -817,14 +819,19 @@ class TelemetryHub:
                                      + max(0.0, float(enqueue_s)))
 
     def record_prefetch_blocked(self, op: str, inv: Optional[int],
-                                blocked_s: float) -> None:
-        """Once a pipelined group: the seconds its prefetch thread
-        held a staged wave that the full queue would not take — the
-        stager waiting for the compute thread."""
+                                blocked_s: float,
+                                overlapped: int = 0) -> None:
+        """Once a pipelined group: the seconds its prefetch workers
+        could begin no stage because the waves they had begun were not
+        taken yet — the stagers waiting for the compute thread, summed
+        over the workers — and ``stages_overlapped``, its stages that
+        began while another stage of the group was under way (0 for a
+        group with one worker)."""
         with self._lock:
             rec = self._op(op, inv)
             rec.prefetch_blocked_s = ((rec.prefetch_blocked_s or 0.0)
                                       + max(0.0, float(blocked_s)))
+            rec.stages_overlapped += int(overlapped)
 
     def record_wave_compute(self, op: str, inv: Optional[int],
                             wave: int, dur_s: float) -> None:
@@ -1036,6 +1043,8 @@ class TelemetryHub:
                     if rec.prefetch_blocked_s is not None:
                         entry["waves"]["prefetch_blocked_s"] = round(
                             rec.prefetch_blocked_s, 6)
+                        entry["waves"]["stages_overlapped"] = (
+                            rec.stages_overlapped)
                     total_staging += rec.staging_s
                     total_hidden += hidden
                 if rec.combine_boundaries:

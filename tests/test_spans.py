@@ -15,6 +15,7 @@ import jax
 from jax.sharding import Mesh
 
 import bigslice_tpu as bs
+from bigslice_tpu.exec import wavestage
 from bigslice_tpu.exec.meshexec import MeshExecutor, _program_name
 from bigslice_tpu.exec.session import Session
 from bigslice_tpu.utils import trace as trace_mod
@@ -409,7 +410,13 @@ def map_side_waves(sess):
             if name.startswith("const@")][-1]
 
 
-def test_a_slow_stager_is_waited_for_and_never_waits(monkeypatch):
+@pytest.mark.parametrize("workers", [1, 2])
+def test_a_slow_stager_is_waited_for_and_never_waits(monkeypatch, workers):
+    """One worker 200 ms a wave behind a short loop is never ready and
+    never blocked. Two run those stages in pairs: the first wave of a
+    pair is waited for, the second is staged (or all but) when the loop
+    comes for it."""
+    monkeypatch.setattr(wavestage, "STAGE_WORKERS", workers)
     sess = mesh_session()
     try:
         waved_reduce(sess, scan=False)        # compiled: waves are short
@@ -421,8 +428,14 @@ def test_a_slow_stager_is_waited_for_and_never_waits(monkeypatch):
         sess.shutdown()
     assert before is not block
     assert before["stage_waits"] == block["stage_waits"] == WAVES - 1
-    assert block["stage_waits_ready"] == 0
-    assert block["prefetch_blocked_s"] == 0.0
+    if workers == 1:
+        assert block["stage_waits_ready"] == 0
+        assert block["prefetch_blocked_s"] == 0.0
+        assert block["stages_overlapped"] == 0
+    else:
+        assert block["stage_waits_ready"] <= (WAVES - 1) // 2
+        assert block["prefetch_blocked_s"] < 0.1
+        assert block["stages_overlapped"] >= (WAVES - 1) // 2
 
 
 def test_a_stager_that_runs_ahead_is_ready_and_waits_for_the_compute_thread(

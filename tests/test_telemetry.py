@@ -264,8 +264,8 @@ def test_wave_records_carry_the_enqueue_the_ready_waits_and_the_stager():
                                 ready=ready)
         hub.record_wave_host("const@x", 1, "dispatch_s", 0.001,
                              enqueue_s=0.0007)
-    hub.record_prefetch_blocked("const@x", 1, 0.25)
-    hub.record_prefetch_blocked("const@x", 1, 0.0)
+    hub.record_prefetch_blocked("const@x", 1, 0.25, 3)
+    hub.record_prefetch_blocked("const@x", 1, 0.0)   # one worker: 0
     hub.record_wave_staging("serial@x", 1, 0, 0.004, 0.004)
     hub.record_wave_host("serial@x", 1, "dispatch_s", 0.001)
     ops = hub.summary()["ops"]
@@ -275,8 +275,10 @@ def test_wave_records_carry_the_enqueue_the_ready_waits_and_the_stager():
     assert (waves["stage_waits"], waves["stage_waits_ready"]) == (3, 2)
     assert waves["staged"] == 4
     assert waves["prefetch_blocked_s"] == 0.25
+    assert waves["stages_overlapped"] == 3
     assert not {"enqueue_s", "stage_waits", "stage_waits_ready",
-                "prefetch_blocked_s"} & set(ops["serial@x"]["waves"])
+                "prefetch_blocked_s", "stages_overlapped"} \
+        & set(ops["serial@x"]["waves"])
     json.dumps(ops)
 
 

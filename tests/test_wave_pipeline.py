@@ -1268,3 +1268,326 @@ def test_overflow_settled_after_the_next_dispatch_reruns_alone(
         ("dispatch", 2, 0), ("read", False),    # wave 1, at slack 1.0
         ("read", False)]
     assert slacks[:4] == [1.0, 1.0, rung, rung]
+
+
+# -- the prefetch workers (exec/wavestage.py): a group that uploads its
+# waves keeps two stages in flight and hands them over in wave order.
+
+STAGED_WAVES = 8        # Const(64, ...) on the 8-device mesh
+
+
+class _StageLog:
+    """``ex._stage``, ``ex._dispatch_wave`` and the loop's asks for a
+    staged wave, logged in the order they happen. A group is known by
+    whether its waves are uploaded (``up``: a Const's map side) or are
+    views of a device-resident output (the reduce side). ``delay(up,
+    wave)`` seconds pass before a stage, ``loop_delay`` before a
+    dispatch, and the stage at ``fail_at = (up, wave)`` raises."""
+
+    def __init__(self, monkeypatch, ex, delay=None, loop_delay=0.0,
+                 fail_at=None):
+        import threading
+        import time
+
+        from bigslice_tpu.exec import wavestage
+
+        self.events = []
+        lock = threading.Lock()
+        real_stage, real_dispatch = ex._stage, ex._dispatch_wave
+        real_take = wavestage.WaveStagers.take
+
+        def log(*event):
+            with lock:
+                self.events.append(event)
+
+        def stage(tasks, wave, cause=None, before=None):
+            up = not tasks[0].deps
+            log("begin", up, wave, threading.current_thread().name)
+            try:
+                if delay is not None:
+                    time.sleep(delay(up, wave))
+                if fail_at == (up, wave):
+                    raise RuntimeError("stage failed")
+                return real_stage(tasks, wave, cause=cause, before=before)
+            finally:
+                log("end", up, wave, threading.current_thread().name)
+
+        def dispatch(tasks, wave, inputs):
+            log("dispatch", not tasks[0].deps, wave, None)
+            time.sleep(loop_delay)
+            return real_dispatch(tasks, wave, inputs)
+
+        def take(stagers, wave):
+            log("ask", None, wave, None)        # BEFORE the take
+            return real_take(stagers, wave)
+
+        monkeypatch.setattr(ex, "_stage", stage)
+        monkeypatch.setattr(ex, "_dispatch_wave", dispatch)
+        monkeypatch.setattr(wavestage.WaveStagers, "take", take)
+
+    def of(self, what, up):
+        return [(wave, who) for kind, group, wave, who in self.events
+                if kind == what and group is up]
+
+    def workers(self, up):
+        return {who for wave, who in self.of("begin", up) if wave}
+
+
+def _staged_job(sess, seed=0):
+    rng = np.random.RandomState(seed)
+    n = 8 * STAGED_WAVES
+    keys = rng.randint(0, 97, n * 16).astype(np.int32)
+    vals = rng.randint(1, 9, n * 16).astype(np.int32)
+    res = sess.run(bs.Reduce(bs.Const(n, keys, vals), lambda a, b: a + b))
+    return sorted(res.rows())
+
+
+def _prefetch_threads():
+    import threading
+
+    return [t.name for t in threading.enumerate()
+            if t.name.startswith("meshwave-prefetch")]
+
+
+def _last_waves(sess, prefix):
+    """The ``waves`` block of the session's last pipelined op that
+    ``prefix`` names (a job's ops are its own)."""
+    return [op["waves"]
+            for name, op in sess.telemetry_summary()["ops"].items()
+            if name.startswith(prefix)
+            and "stage_waits" in op.get("waves", {})][-1]
+
+
+def _settled_sess(mesh, depth):
+    """A session that ran the job once: programs compiled, capacities
+    discovered, so the next run stages every wave of a group once."""
+    sess = _sess(mesh, depth)
+    return sess, _staged_job(sess)
+
+
+def test_a_wave_staged_before_its_predecessor_is_delivered_after_it(
+        mesh, monkeypatch):
+    """Wave 1 stages 400 ms slower than the others, so with two
+    workers wave 2 is staged before it — and is still dispatched
+    after it; the rows are the serial loop's."""
+    want = _staged_job(_sess(mesh, 0))
+    sess, rows = _settled_sess(mesh, 1)
+    assert rows == want
+    log = _StageLog(monkeypatch, sess.executor,
+                    delay=lambda up, wave: 0.4 * (up and wave == 1))
+    assert _staged_job(sess) == want
+    ended = [w for w, _ in log.of("end", True)]
+    assert ended.index(2) < ended.index(1)          # out of order
+    for up in (True, False):
+        assert [w for w, _ in log.of("dispatch", up)] == \
+            list(range(STAGED_WAVES))
+    assert _prefetch_threads() == []
+
+
+@pytest.mark.parametrize("k", [1, 4, STAGED_WAVES - 1])
+def test_a_stage_error_is_raised_in_wave_order_and_stops_the_workers(
+        mesh, monkeypatch, k):
+    """The stage of wave k raises while wave k-1's is still under way:
+    every wave before k is dispatched first, no wave beyond k+1 is
+    begun, and both workers have exited when the group has raised."""
+    from bigslice_tpu.exec.task import TaskError
+
+    sess, _ = _settled_sess(mesh, 1)
+    log = _StageLog(monkeypatch, sess.executor, fail_at=(True, k),
+                    delay=lambda up, wave: 0.1 * (up and wave == k - 1))
+    with pytest.raises(TaskError, match="stage failed"):
+        _staged_job(sess)
+    assert [w for w, _ in log.of("dispatch", True)] == list(range(k))
+    assert max(w for w, _ in log.of("begin", True)) <= k + 1
+    assert log.of("dispatch", False) == []
+    assert _prefetch_threads() == []
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_never_more_than_depth_plus_one_waves_begun_and_not_taken(
+        mesh, monkeypatch, depth):
+    """A loop 50 ms a wave behind workers that stage at once: they run
+    ahead as far as the bound lets them and no further, in both kinds
+    of group, and wait for the loop meanwhile."""
+    sess, _ = _settled_sess(mesh, depth)
+    log = _StageLog(monkeypatch, sess.executor, loop_delay=0.05)
+    _staged_job(sess)
+    # An ask is logged BEFORE its take: at every begin the loop has
+    # taken at most the wave it last asked this group's stagers for.
+    asked = {True: 0, False: 0}
+    ahead = {True: 0, False: 0}
+    up = True                       # the map side runs first
+    for kind, which, wave, _who in log.events:
+        if kind == "dispatch" and wave == 0:
+            up = which
+        elif kind == "ask":
+            asked[up] = wave
+        elif kind == "begin" and wave:
+            ahead[which] = max(ahead[which], wave - asked[which])
+    # On a machine that is not stalled both reach the bound exactly.
+    assert 0 < ahead[True] <= depth + 1
+    assert 0 < ahead[False] <= depth + 1
+    for prefix in ("const@", "reduce@"):
+        assert _last_waves(sess, prefix)["prefetch_blocked_s"] > 0
+    assert _prefetch_threads() == []
+
+
+def test_a_group_of_views_keeps_one_worker_and_an_uploading_group_two(
+        mesh, monkeypatch):
+    sess, _ = _settled_sess(mesh, 1)
+    log = _StageLog(monkeypatch, sess.executor,
+                    delay=lambda up, wave: 0.05)
+    _staged_job(sess)
+    const, reduce_ = (_last_waves(sess, p) for p in ("const@", "reduce@"))
+    assert log.workers(False) == {"meshwave-prefetch-0"}
+    assert log.workers(True) == {"meshwave-prefetch-0",
+                                 "meshwave-prefetch-1"}
+    assert reduce_["stages_overlapped"] == 0
+    assert 0 < const["stages_overlapped"] <= const["stage_waits"]
+    assert const["stage_waits"] == reduce_["stage_waits"] == \
+        STAGED_WAVES - 1
+    assert _prefetch_threads() == []
+
+
+def test_two_workers_are_ready_where_one_is_always_waited_for(
+        mesh, monkeypatch):
+    """A stage of 500 ms beside a loop of 250 ms a wave and whatever a
+    loaded machine adds: one worker is the slower side and its waves
+    are not ready (a wave or two may be, where the machine stalled the
+    loop for 250 ms more); two stage a wave every 250 ms, the loop is
+    the slower side, and but for the first pair's every wave is staged
+    when the loop asks."""
+    from bigslice_tpu.exec import wavestage
+
+    def counted(workers):
+        monkeypatch.setattr(wavestage, "STAGE_WORKERS", workers)
+        sess, _ = _settled_sess(mesh, 1)
+        with monkeypatch.context() as patch:
+            _StageLog(patch, sess.executor, loop_delay=0.25,
+                      delay=lambda up, wave: 0.5 * up)
+            _staged_job(sess)
+        got = _last_waves(sess, "const@")
+        assert got["stage_waits"] == STAGED_WAVES - 1
+        return got["stage_waits_ready"], got["stages_overlapped"]
+
+    one, overlapped = counted(1)
+    assert one <= 2 and overlapped == 0
+    two, overlapped = counted(2)
+    assert two >= STAGED_WAVES - 3 and overlapped > 0
+
+
+# -- WaveStagers alone: no executor, a stage is a sleep.
+
+def _stagers(nwaves, depth, uploads, stage):
+    from bigslice_tpu.exec import wavestage
+
+    return wavestage.WaveStagers(nwaves, depth, uploads, stage)
+
+
+@pytest.mark.parametrize("uploads", [False, True])
+@pytest.mark.parametrize("depth", [1, 3])
+def test_stagers_hand_every_wave_over_once_in_wave_order(depth, uploads):
+    import threading
+    import time
+
+    second = threading.Event()
+
+    def stage(w):
+        if w == 2:
+            second.set()
+        if w == 1 and uploads:
+            second.wait(10)               # two workers: 2 begins beside 1
+        time.sleep(0.002 * (w % 3))       # later waves finish first
+        return w, 0.0, {}
+
+    stagers = _stagers(12, depth, uploads, stage)
+    try:
+        got = [stagers.take(w) for w in range(1, 12)]
+    finally:
+        stagers.close()
+    assert got == [(w, None, 0.0, {}) for w in range(1, 12)]
+    assert (stagers.overlapped > 0) == uploads
+    assert _prefetch_threads() == []
+
+
+@pytest.mark.parametrize("uploads", [False, True])
+def test_stagers_closed_while_they_wait_for_the_loop_exit(uploads):
+    import time
+
+    begun = []
+    stagers = _stagers(9, 1, uploads, lambda w: (begun.append(w), 0.0, {}))
+    deadline = time.time() + 5
+    while len(begun) < 2 and time.time() < deadline:
+        time.sleep(0.001)
+    time.sleep(0.05)                      # the workers wait at the bound
+    stagers.close()
+    assert sorted(begun) == [1, 2]        # depth + 1, never taken
+    assert stagers.blocked_ns >= 0.02e9
+    assert _prefetch_threads() == []
+
+
+def test_stagers_begin_no_wave_after_one_that_raised():
+    import time
+
+    begun = []
+
+    def stage(w):
+        begun.append(w)
+        if w == 2:
+            raise ValueError("wave 2")
+        time.sleep(0.05)                  # wave 1 outlasts wave 2
+        return w, 0.0, {}
+
+    stagers = _stagers(9, 3, True, stage)
+    try:
+        assert stagers.take(1)[:2] == (1, None)
+        inputs, err, _dur, _stats = stagers.take(2)
+    finally:
+        stagers.close()
+    assert inputs is None and isinstance(err, ValueError)
+    assert sorted(begun) == [1, 2]
+    assert _prefetch_threads() == []
+
+
+def test_many_stagers_under_a_short_switch_interval_keep_order_and_bound(
+        monkeypatch):
+    """Sixteen workers, more than this machine's cores, switching every
+    microsecond: each of 400 waves is handed over once, in order, and
+    no stage begins more than ``depth + 1`` waves ahead of what the
+    loop has asked for (an ask is counted BEFORE its take)."""
+    import sys
+    import threading
+    import time
+
+    from bigslice_tpu.exec import wavestage
+
+    monkeypatch.setattr(wavestage, "STAGE_WORKERS", 16)
+    depth, nwaves = 15, 400
+    asked = [0]
+    ahead, begun, lock = [], [], threading.Lock()
+
+    def stage(w):
+        with lock:
+            begun.append(w)
+            ahead.append(w - asked[0])
+        return w, 0.0, None
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    deadline = time.time() + 60
+    try:
+        stagers = _stagers(nwaves, depth, True, stage)
+        try:
+            got = []
+            for w in range(1, nwaves):
+                assert time.time() < deadline
+                asked[0] = w
+                got.append(stagers.take(w)[0])
+        finally:
+            stagers.close()
+    finally:
+        sys.setswitchinterval(interval)
+    assert got == list(range(1, nwaves))
+    assert sorted(begun) == got
+    assert max(ahead) <= depth + 1
+    assert _prefetch_threads() == []
