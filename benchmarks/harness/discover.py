@@ -5,7 +5,9 @@
                                     its ``pipeline`` key (default
                                     ``pipeline.py``, relative to the file)
                                     names the data generator, job builder
-                                    and plain reference beside it
+                                    and plain reference beside it; its
+                                    ``compare`` block the tables compared
+                                    within a tolerance (harness/compare.py)
     traffic ``<name>``           -> ``<path>/traffic/<name>.json``
     metric ``<name>``            -> ``<path>/metrics/<name>.py`` with
                                     ``read(reading) -> number | None``
@@ -22,6 +24,8 @@ import importlib.util
 import json
 import os
 
+from . import compare
+
 
 class NotFound(LookupError):
     pass
@@ -33,6 +37,7 @@ class Cell:
     chips: int
     config_name: str
     cfg: dict            # the configuration as it is run
+    tolerances: dict     # table -> compare.Tolerance; the rest is exact
     pipeline: object     # module: make_data / Job / reference / ...
     traffic_name: str
     traffic: dict
@@ -79,7 +84,9 @@ def _in_cell(metric: dict, cell_name: str) -> bool:
 def find_cell(root: str, name: str, rehearsal: bool = False) -> Cell:
     """Everything ``name`` needs, from ``<root>/BENCHMARK.json`` down.
     ``rehearsal`` overlays the configuration's own ``rehearsal`` sizes
-    (tiny, for a CPU run that proves control flow only)."""
+    (tiny, for a CPU run that proves control flow only). A ``compare``
+    block that ``compare.tolerances`` refuses raises ``ValueError``
+    here, before any work."""
     bench = _load_json(os.path.join(root, "BENCHMARK.json"))
     entry = _by_name(bench["workloads"], name, "workload")
     config = _by_name(bench["configs"], entry["config"], "config")
@@ -87,6 +94,7 @@ def find_cell(root: str, name: str, rehearsal: bool = False) -> Cell:
     cfg = _load_json(cfg_path)
     if rehearsal:
         cfg = {**cfg, **cfg.get("rehearsal", {})}
+    tolerances = compare.tolerances(cfg)
     pipeline = _load_module(
         os.path.join(os.path.dirname(cfg_path),
                      cfg.get("pipeline", "pipeline.py")),
@@ -104,7 +112,8 @@ def find_cell(root: str, name: str, rehearsal: bool = False) -> Cell:
 
     return Cell(
         name=name, chips=int(entry["chips"]),
-        config_name=entry["config"], cfg=cfg, pipeline=pipeline,
+        config_name=entry["config"], cfg=cfg, tolerances=tolerances,
+        pipeline=pipeline,
         traffic_name=entry["traffic"], traffic=traffic,
         end_to_end=readers(bench["end_to_end"]),
         per_layer=readers(bench["per_layer"]),

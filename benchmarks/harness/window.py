@@ -298,11 +298,15 @@ def run_window(cell, seed: int, seconds: float, trace_dir, t_process,
     finally:
         pipeline.close(data)
     every = d.setup_jobs + d.jobs
+    tols = cell.tolerances
     wrong = rows = compared = 0
+    margin = {}
     for rec in every:
         if rec.error:
             continue
-        w, r = compare.compare_answers(rec.answers, want)
+        w, r = compare.compare_answers(rec.answers, want, tols)
+        for table, m in compare.margins(rec.answers, want, tols).items():
+            margin[table] = max(m, margin.get(table, 0.0))
         if w:
             rec.error = f"{w} of {r} rows differ from the reference"
         wrong, rows, compared = wrong + w, rows + r, compared + 1
@@ -315,6 +319,10 @@ def run_window(cell, seed: int, seconds: float, trace_dir, t_process,
         "off_mesh": {"value": len(off_mesh), "limit": 0},
         "jobs_uncompared": {"value": len(every) - compared, "limit": 0},
     }
+    if tols:
+        notes["margin_by_table"] = margin
+        checks["tolerance_margin"] = {
+            "value": max(margin.values(), default=0.0), "limit": 1.0}
     return WindowResult(
         setup_s=d.setup_s, first_job_s=d.setup_jobs[0].seconds,
         jobs=d.jobs, setup_jobs=d.setup_jobs, window_s=d.window_s,

@@ -53,6 +53,9 @@ def test_rehearsal_runs_each_cell_and_never_prints_the_contract_line(
     assert last["correct"] is True and last["failed"] == 0
     assert last["attempted"] >= 1
     assert list(last)[-1] == "checks"
+    # Integer answers, compared exactly: no tolerance, no margin.
+    assert list(last["checks"]) == ["wrong_rows", "jobs_failed",
+                                    "off_mesh", "jobs_uncompared"]
     assert err.strip().splitlines()[-1].startswith("check ")
     bench = json.load(open(os.path.join(full_root, "BENCHMARK.json")))
     if trace == 0:
@@ -80,6 +83,133 @@ def test_a_cell_added_as_files_only_is_found_and_run(capsys, tmp_path):
     # Its own per-layer metric, and not the one listed for another cell.
     assert lines[-1]["rehearsal"]["metrics"] == {
         "dummy_metric": {"value": 42.0, "unit": "count"}}
+
+
+# ------------------------------------- a float answer within a tolerance
+
+FLOAT = "dummy.float"
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3, 4, 2147483659])
+def test_a_float_cell_is_correct_within_its_tolerance(capsys, tmp_path,
+                                                      seed):
+    dummy_files.write(str(tmp_path))
+    rc, lines, err = rehearse(capsys, FLOAT, str(tmp_path), seed=seed)
+    last = lines[-1]["rehearsal"]
+    assert rc == 0 and last["correct"] is True
+    assert last["checks"]["wrong_rows"]["value"] == 0
+    assert list(last["checks"])[-1] == "tolerance_margin"
+    margin = last["checks"]["tolerance_margin"]
+    assert margin["limit"] == 1.0 and 0 < margin["value"] < 1
+    assert err.strip().splitlines()[-1].startswith("check tolerance_margin")
+
+
+def _one_element_past_its_tolerance(job_cls, tol):
+    """The first job's answer has one element of one vector moved by ten
+    times its tolerance where the answer is produced."""
+    class Moved(job_cls):
+        moved = False
+
+        def _run(self):
+            super()._run()
+            if Moved.moved:
+                return
+            Moved.moved = True
+            k, v = self.answers["sums"]
+            v = np.array(v)
+            v[1, 3] += 10 * (tol.atol + tol.rtol * abs(v[1, 3]))
+            self.answers["sums"] = (k, v)
+    return Moved
+
+
+def _float_cell_with(monkeypatch, change):
+    real = discover.find_cell
+
+    def changed(*a, **kw):
+        cell = real(*a, **kw)
+        change(cell)
+        return cell
+
+    monkeypatch.setattr(discover, "find_cell", changed)
+
+
+def test_a_float_answer_past_its_tolerance_is_not_correct(
+        capsys, monkeypatch, tmp_path):
+    dummy_files.write(str(tmp_path))
+
+    def plant(cell):
+        cell.pipeline.Job = _one_element_past_its_tolerance(
+            cell.pipeline.Job, cell.tolerances["sums"])
+
+    _float_cell_with(monkeypatch, plant)
+    rc, lines, err = rehearse(capsys, FLOAT, str(tmp_path))
+    last = lines[-1]["rehearsal"]
+    assert rc == 0 and last["correct"] is False
+    assert last["checks"]["wrong_rows"]["value"] == 1
+    assert last["checks"]["tolerance_margin"]["value"] == \
+        pytest.approx(10, rel=1e-3)
+    assert "check wrong_rows = 1" in err
+
+
+def test_the_float_cells_lower_precision_control_is_caught(capsys,
+                                                           tmp_path):
+    dummy_files.write(str(tmp_path))
+    rc = control.main(["--workload", FLOAT, "--seeds", "1,2,4",
+                       "--seconds", "0.3", "--cpu-rehearsal"],
+                      root=str(tmp_path))
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+    assert rc == 0 and lines[-1]["all_as_expected"] is True
+    for ln in lines[:-1]:
+        assert ln["program_correct"] is True
+        assert ln["control_wrong_rows"]["lower_precision_bf16"] >= 1
+    readings = lines[-1]["tolerance"]["sums"]
+    assert readings["program_max_margin"] < 1
+    assert readings["control_min_margin"]["lower_precision_bf16"] > 1
+
+
+def test_control_refuses_a_float_cell_without_a_lower_precision_control(
+        capsys, monkeypatch, tmp_path):
+    dummy_files.write(str(tmp_path))
+
+    def drop(cell):
+        controls = cell.pipeline.controls
+        cell.pipeline.controls = lambda cfg, data: {
+            n: a for n, a in controls(cfg, data).items()
+            if not n.startswith("lower_precision")}
+
+    _float_cell_with(monkeypatch, drop)
+    rc = control.main(["--workload", FLOAT, "--seeds", "1",
+                       "--seconds", "0.3", "--cpu-rehearsal"],
+                      root=str(tmp_path))
+    out = capsys.readouterr()
+    assert rc == 2
+    assert "no control named lower_precision" in out.err
+    assert not out.out.strip()              # refused before any window
+
+
+@pytest.mark.parametrize("entry", [
+    {"rtol": 2e-3, "atol": 0, "why": "over the ceiling"},
+    {"rtol": 1e-5, "atol": 0, "why": ""},
+])
+def test_a_compare_block_out_of_bounds_is_refused_before_any_work(
+        tmp_path, entry):
+    dummy_files.write(str(tmp_path))
+    path = tmp_path / "bm" / "configs" / "dummyf" / "config.json"
+    cfg = json.loads(path.read_text())
+    path.write_text(json.dumps({**cfg, "compare": {"sums": entry}}))
+    with pytest.raises(ValueError):
+        discover.find_cell(str(tmp_path), FLOAT, rehearsal=True)
+
+
+def test_a_tolerance_on_integer_answers_fails_the_run(capsys, tmp_path):
+    dummy_files.write(str(tmp_path))
+    path = tmp_path / "bm" / "configs" / "dummy" / "config.json"
+    cfg = json.loads(path.read_text())
+    path.write_text(json.dumps({**cfg, "compare": {"counts": {
+        "rtol": 1e-5, "atol": 0, "why": "counts are never approximate"}}}))
+    with pytest.raises(ValueError, match="floating"):
+        rehearse(capsys, "dummy.cell", str(tmp_path))
+    assert "correct" not in capsys.readouterr().out
 
 
 def test_an_unknown_name_is_an_error_not_a_default(tmp_path):
