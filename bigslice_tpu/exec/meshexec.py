@@ -821,7 +821,11 @@ class MeshExecutor:
         self._groups: Dict[Tuple, _GroupState] = {}
         self._outputs: Dict[Tuple, DeviceGroupOutput] = {}
         self._task_index: Dict[TaskName, Tuple[Tuple, Task]] = {}
-        self._programs: Dict[Tuple, Tuple[object, list]] = {}
+        # Compiled programs by key: (program, what else the entry
+        # holds). A group program's is (its stage functions' weakrefs,
+        # the dense tables its trace fills — dense.recording_tables —
+        # which the op's ``table`` telemetry block counts a wave).
+        self._programs: Dict[Tuple, Tuple[object, tuple]] = {}
         # Adapted shuffle slack per op (see _execute_wave): overflow
         # probes run once per op, not once per wave/run.
         self._slack_memo: Dict[str, float] = {}
@@ -2252,6 +2256,18 @@ class MeshExecutor:
             wide_columns=sum(is_wide(ct.dtype)
                              for ct in task0.schema.values))
 
+    def _telemetry_table(self, task0: Task, tables: list) -> None:
+        """One dispatched wave's dense tables, for the per-op ``table``
+        block: what its program's trace recorded (``_program``), host
+        data all."""
+        hub = self._telemetry_hub()
+        if hub is None or not tables:
+            return
+        try:
+            hub.record_table(task0.name.op, task0.name.inv_index, tables)
+        except Exception:
+            pass
+
     def _telemetry_merge(self, task0, caps, full, bounds) -> None:
         """One cross-wave merge, for the per-op ``merge`` block: the
         slots it read a wave (``caps``) against the waves' capacities
@@ -3019,9 +3035,8 @@ class MeshExecutor:
                 self._wave_arrays(inputs)
             )
             slack = self._wave_slack(task0)
-            program, stages = self._program(task0, caps, slack,
-                                            subids=subids,
-                                            donate=donate)
+            program, stages, tables = self._program(
+                task0, caps, slack, subids=subids, donate=donate)
             sp.set(program=_program_name(
                 "group", tuple(k for k, _, _ in stages)))
             extras = [
@@ -3044,6 +3059,7 @@ class MeshExecutor:
                 # Every dispatched attempt (first run and slack retries
                 # alike) put its buckets on the wire.
                 self._telemetry_exchange(task0, wave, inputs, slack)
+            self._telemetry_table(task0, tables)
         self._telemetry_wave_host(task0, "dispatch_s", sp.seconds,
                                   enqueue_s=enq.seconds)
         return raw, stages, slack
@@ -4236,11 +4252,11 @@ class MeshExecutor:
         with self._lock:
             cached = self._programs.get(key)
             if cached is not None:
-                prog, refs = cached
+                prog, (refs, tables) = cached
                 if len(refs) == len(fns) and all(
                     r is None or r() is f for r, f in zip(refs, fns)
                 ):
-                    return prog, stages
+                    return prog, stages, tables
 
         import jax
         import jax.numpy as jnp
@@ -4809,6 +4825,19 @@ class MeshExecutor:
                 signals = jnp.concatenate([signals, joined])
             return out_n.reshape(1), signals, tuple(cols)
 
+        from bigslice_tpu.parallel import dense as dense_mod
+
+        # Host data from the trace, never a device read: every table the
+        # program fills, each (slots, its value columns' lowerings). A
+        # program served from the cross-session program cache is not
+        # traced in this session, and its list stays empty.
+        tables: list = []
+
+        def traced(*args):
+            tables.clear()
+            with dense_mod.recording_tables(tables):
+                return stepped(*args)
+
         if stages and stages[0][0] == "cogroup":
             # Device view of the ragged output: keys, then per input
             # its value matrices and a count column (decoded to the
@@ -4840,7 +4869,7 @@ class MeshExecutor:
                 off += nc
         prog = jit_maybe_donate(
             shard_map(
-                _named(stepped, "group",
+                _named(traced, "group",
                        tuple(k for k, _, _ in stages)),
                 mesh=self.mesh, in_specs=in_specs,
                 out_specs=out_specs, check_rep=False),
@@ -4882,10 +4911,10 @@ class MeshExecutor:
         # Concurrent _run_group threads insert/evict under the lock
         # (pop-first is not atomic against another thread's pop).
         with self._lock:
-            self._programs[key] = (prog, tuple(refs))
+            self._programs[key] = (prog, (tuple(refs), tables))
             while len(self._programs) > _PROGRAM_CACHE_MAX:
                 self._programs.pop(next(iter(self._programs)))
-        return prog, stages
+        return prog, stages, tables
 
     @staticmethod
     def _stage_struct(stages) -> tuple:

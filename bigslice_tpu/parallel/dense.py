@@ -5,8 +5,8 @@ encodings (frame/dictenc.py), categorical ids, bucketed features — the
 sort-dominated combine+shuffle pipeline (parallel/shuffle.py
 make_combine_shuffle_fn) collapses to:
 
-  1. per-shard dense value tables, one scatter-accumulate pass over the
-     rows (no sorts, no overflow slack, no retries);
+  1. per-shard dense value tables, one accumulate pass over the rows
+     (no sorts, no overflow slack, no retries);
   2. ONE all_to_all of the tables, pre-gathered through a *static*
      routing permutation so each device receives exactly the table
      slots of its own partition;
@@ -34,7 +34,9 @@ comparison sorts.
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
 from typing import Optional, Sequence, Tuple
 
 import numpy as np
@@ -203,31 +205,77 @@ def _identity(op: str, dtype) -> np.generic:
     raise ValueError(op)
 
 
-def _scatter_tables(idx, vals, ops, idents, size: int):
-    """The shared table pass: identity-initialized [size(, ...trailing)]
-    tables, one scatter-accumulate per value column — vector value
-    columns scatter whole rows (idx == size-1 may serve as the caller's
-    drop lane). Returns (present bool[size], tables)."""
+def table_column_lowering(size: int, shape) -> str:
+    """How the table pass fills one value column of a ``size``-slot
+    table: ``"compare"`` (a masked reduction over the [size, rows]
+    compare: a scalar column of a table of at most ``SMALL_TABLE``
+    slots) or ``"scatter"`` (any vector column, and every column of a
+    larger table). ``shape`` is the column's per-row shape. A float32
+    vector column keeps its scatter: a one-hot contraction on a v5e's
+    MXU does not sum as float32 does — at HIGHEST precision, or over
+    three exact bfloat16 parts, the sums lean low (PERF.md §6), which
+    ``chip_smoke.py``'s ``dense-table`` phase would catch. ONE source of
+    truth: ``_scatter_tables`` branches on it and the ``table``
+    telemetry block counts it."""
+    if size <= SMALL_TABLE and not tuple(shape):
+        return "compare"
+    return "scatter"
+
+
+# The tables a trace fills, for the group program being traced
+# (``recording_tables``): each a (slots, per-column lowerings) pair.
+_SINK = threading.local()
+
+
+@contextlib.contextmanager
+def recording_tables(sink: list):
+    """Within the block, every table this thread's trace fills is
+    appended to ``sink`` as ``(slots, lowerings)``: host data the
+    executor's ``table`` telemetry block counts a wave, with no device
+    read."""
+    prev = getattr(_SINK, "tables", None)
+    _SINK.tables = sink
+    try:
+        yield
+    finally:
+        _SINK.tables = prev
+
+
+def _scatter_column(idx, v, op, ident, size: int):
+    """One value column by scatter-accumulate into an
+    identity-initialized [size(, ...trailing)] table."""
     import jax.numpy as jnp
 
-    if size <= SMALL_TABLE and all(v.ndim == 1 for v in vals):
+    upd = jnp.full((size,) + tuple(v.shape[1:]), ident, v.dtype).at[idx]
+    return (upd.add(v) if op == "add"
+            else upd.max(v) if op == "max"
+            else upd.min(v))
+
+
+def _scatter_tables(idx, vals, ops, idents, size: int):
+    """The shared table pass: (present bool[size], tables), one
+    [size(, ...trailing)] table a value column (idx == size-1 may serve
+    as the caller's drop lane). Each column takes the lowering
+    ``table_column_lowering`` picks; in a table of at most
+    ``SMALL_TABLE`` slots ``present`` is the compare's any."""
+    import jax.numpy as jnp
+
+    how = [table_column_lowering(size, v.shape[1:]) for v in vals]
+    sink = getattr(_SINK, "tables", None)
+    if sink is not None:
+        sink.append((size, tuple(how)))
+    if size > SMALL_TABLE:
+        present = jnp.zeros((size,), bool).at[idx].set(True)
+    else:
         # Few slots: each is a masked reduction over the rows.
         hit = idx[None, :] == jnp.arange(size, dtype=np.int32)[:, None]
-        reduce_ = {"add": jnp.sum, "max": jnp.max, "min": jnp.min}
-        return hit.any(axis=1), [
-            reduce_[op](jnp.where(hit, v[None, :], ident),
-                        axis=1).astype(v.dtype)
-            for v, op, ident in zip(vals, ops, idents)]
-    present = jnp.zeros((size,), bool).at[idx].set(True)
-    tables = []
-    for v, op, ident in zip(vals, ops, idents):
-        t = jnp.full((size,) + tuple(v.shape[1:]), ident, v.dtype)
-        upd = t.at[idx]
-        t = (upd.add(v) if op == "add"
-             else upd.max(v) if op == "max"
-             else upd.min(v))
-        tables.append(t)
-    return present, tables
+        present = hit.any(axis=1)
+    reduce_ = {"add": jnp.sum, "max": jnp.max, "min": jnp.min}
+    return present, [
+        reduce_[op](jnp.where(hit, v[None, :], ident),
+                    axis=1).astype(v.dtype)
+        if h == "compare" else _scatter_column(idx, v, op, ident, size)
+        for v, op, ident, h in zip(vals, ops, idents, how)]
 
 
 @functools.lru_cache(maxsize=32)

@@ -191,6 +191,9 @@ class _OpRecord:
         # -- the cross-wave merges of a waved shuffle's outputs
         # (record_merge): None for an op that merged nothing
         self.merge: Optional[dict] = None
+        # -- the dense tables its waves filled (record_table): None for
+        # an op whose programs fill none
+        self.table: Optional[dict] = None
 
 
 class DeadlineStats:
@@ -741,6 +744,27 @@ class TelemetryHub:
             m["slots_full"] += int(slots_full)
             m["rows_bound"] += int(rows_bound)
 
+    def record_table(self, op: str, inv: Optional[int],
+                     tables) -> None:
+        """One dispatched wave of a group program that fills dense
+        tables (``parallel/dense.py``'s table pass), from the host data
+        its trace recorded: per table ``(slots, lowerings)``, one
+        lowering a value column as ``dense.table_column_lowering``
+        picked it. Sums since the session began, so a window reads
+        them as deltas."""
+        with self._lock:
+            rec = self._op(op, inv)
+            if rec.table is None:
+                rec.table = {"waves": 0, "slots": 0,
+                             "columns_compare": 0,
+                             "columns_scatter": 0}
+            t = rec.table
+            t["waves"] += 1
+            for slots, lowerings in tables:
+                t["slots"] += int(slots)
+                for how in lowerings:
+                    t["columns_" + how] += 1
+
     def record_deadline(self, outcome: str, tenant: str = "",
                         deadline_s=None, source: str = "") -> None:
         """One deadline-ladder outcome (met / expired /
@@ -1080,6 +1104,9 @@ class TelemetryHub:
                 if rec.merge is not None:
                     # The op's cross-wave merges (record_merge).
                     entry["merge"] = dict(rec.merge)
+                if rec.table is not None:
+                    # The dense tables its waves filled (record_table).
+                    entry["table"] = dict(rec.table)
                 ex = exchanged.get(op)
                 if ex and ex["ici_messages"] + ex["dcn_messages"]:
                     # A shuffle whose collective moved something (a

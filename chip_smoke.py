@@ -1013,6 +1013,70 @@ def phase_kmeans(ctx: Ctx):
     return line, None
 
 
+#: Largest mean signed error of a small table's float32 vector sums,
+#: over the sum of the magnitudes they add. On a v5e, over the k-means
+#: points: the scatter +1.2e-7; a one-hot contraction over three exact
+#: bfloat16 parts of the rows -2.8e-6, an f32 dot at HIGHEST -8.0e-5.
+DENSE_TABLE_MEAN_ERROR = 1e-6
+
+
+def phase_dense_table(ctx: Ctx):
+    """The k-means round's table alone, at the k-means phase's size:
+    its points summed a centroid into a ``k + 1``-slot dense table (the
+    last slot the drop lane) against float64 sums of the same rows.
+    Float32 rounding in any order reads either way and averages out; a
+    lowering whose sums lean one way (a one-hot contraction on the MXU)
+    fails on the mean signed error. The counts and ``present`` are
+    exact."""
+    import jax
+
+    from bigslice_tpu.parallel import dense
+
+    cfg, pipeline = kmeans_configuration()
+    n, d, k = ctx.sizes.kmeans_points, int(cfg["d"]), int(cfg["k"])
+    x = pipeline.make_data({**cfg, "points": n}, ctx.seed).points
+    rng = np.random.default_rng([ctx.seed, 41])
+    key = rng.integers(0, k, n, dtype=np.int32)
+    valid = rng.random(n) >= 1 / 64
+    w = np.ones(n, np.float32)
+    core = dense.make_dense_combine(k, ("add", "add"),
+                                    [np.float32, np.float32])
+    fn = jax.jit(lambda v, c, a, b: core(v, (c,), (a, b)))
+    present, _, (sums, counts) = jax.device_get(fn(valid, key, x, w))
+
+    # float64 sums a slot, over the rows sorted by slot, a block of
+    # columns at a time.
+    kept = np.where(valid, key, k)
+    order = np.argsort(kept, kind="stable")
+    want_n = np.bincount(kept, minlength=k + 1)[:k]
+    starts = np.concatenate([[0], np.cumsum(want_n)[:-1]])
+    want = np.zeros((k, d))
+    scale = np.zeros((k, d))
+    for j in range(0, d, 16):
+        block = np.take(x[:, j:j + 16], order[:want_n.sum()], axis=0)
+        want[:, j:j + 16] = np.add.reduceat(block, starts, axis=0,
+                                            dtype=np.float64)
+        scale[:, j:j + 16] = np.add.reduceat(np.abs(block), starts,
+                                             axis=0, dtype=np.float64)
+    require((want_n > 0).all(), "a slot with no rows")
+    require(np.array_equal(counts, want_n.astype(np.float32))
+            and np.array_equal(present, want_n > 0),
+            "the counts or present differ from the rows'")
+    err = (sums.astype(np.float64) - want) / scale
+    mean_err, max_err = float(err.mean()), float(np.abs(err).max())
+    require(np.isfinite(err).all()
+            and abs(mean_err) <= DENSE_TABLE_MEAN_ERROR,
+            f"the table's sums lean {mean_err:.3g} of their magnitude "
+            f"(limit {DENSE_TABLE_MEAN_ERROR:g}; largest {max_err:.3g})")
+    line = {
+        "phase": "dense-table", "rows": n, "d": d, "slots": k + 1,
+        "ok": True, "lowering": dense.table_column_lowering(k + 1, (d,)),
+        "mean_signed_error": mean_err, "max_abs_error": max_err,
+        "limit": DENSE_TABLE_MEAN_ERROR,
+    }
+    return line, None
+
+
 ONE_CHIP_PHASES = {
     "kernels": phase_kernels,
     "reduce-dense": phase_reduce_dense,
@@ -1020,6 +1084,7 @@ ONE_CHIP_PHASES = {
     "join": phase_join,
     "urls": phase_urls,
     "kmeans": phase_kmeans,
+    "dense-table": phase_dense_table,
 }
 
 #: What exists only across chips: the shuffles as collectives.

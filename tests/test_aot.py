@@ -342,3 +342,34 @@ def test_aot_wide_dense_map_side_wave_compiles_for_tpu(topo, tpu_branches,
     assert "s64[" in text
     assert _mosaic_calls_in(text, pk.HASH_PARTITION_KERNEL) == 1
     assert ("all-to-all" in text) == (chips > 1)
+
+
+def test_aot_small_vector_table_scatters_the_vectors_alone(one_chip):
+    """The k-means round's table at its real size, compiled for one
+    v5e: 2^23 float32 rows of 128 and their counts into 64 slots and the
+    drop lane. ``present`` and the counts are the compare's reductions;
+    the one scatter is the vector sums', and no contraction stands in
+    for it (on the chip an MXU contraction's sums lean low: PERF.md
+    §6). The compare is read where it is made, not stored: the
+    temporaries stay small."""
+    import jax
+
+    from bigslice_tpu.parallel import dense
+
+    n, d = 1 << 23, 128
+    core = dense.make_dense_combine(64, ("add", "add"),
+                                    [np.float32, np.float32])
+
+    def fn(valid, key, x, w):
+        return core(valid, (key,), (x, w))
+
+    S = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, np.dtype(dt), sharding=one_chip)
+    compiled = jax.jit(fn).lower(
+        S((n,), np.bool_), S((n,), np.int32), S((n, d), np.float32),
+        S((n,), np.float32)).compile()
+    text = compiled.as_text()
+    assert " convolution(" not in text and " dot(" not in text
+    scatters = re.findall(r"= (\S+) scatter\(", text)
+    assert len(scatters) == 1 and scatters[0].startswith("f32[65,128]")
+    assert compiled.memory_analysis().temp_size_in_bytes <= 64 << 20

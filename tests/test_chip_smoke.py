@@ -96,6 +96,24 @@ def test_phase_fails_when_work_leaves_the_device(tmp_path, capsys):
     assert "probation" in line["error"], line
 
 
+def test_dense_table_phase_fails_on_sums_that_lean(tmp_path, capsys,
+                                                  monkeypatch):
+    """The CPU computes any precision exactly, so the chip's lean can
+    only be planted here: vector sums 2.8e-6 low, as a one-hot
+    contraction over three exact bfloat16 parts reads on a v5e, fail
+    the ``dense-table`` phase."""
+    from bigslice_tpu.parallel import dense
+
+    exact = dense._scatter_column
+    monkeypatch.setattr(
+        dense, "_scatter_column",
+        lambda *a: exact(*a) * np.float32(1 - 2.8e-6))
+    ok, _ = cs.run_phase("dense-table", _ctx(1, tmp_path))
+    (line,) = _lines(capsys)
+    assert not ok and not line["ok"]
+    assert "lean" in line["error"], line
+
+
 def _run_script(*args, env=None):
     return subprocess.run(
         [sys.executable, os.path.join(REPO, "chip_smoke.py"), *args],

@@ -348,6 +348,169 @@ def test_dense_vector_value_columns(mesh):
                                    rtol=1e-4, atol=1e-4)
 
 
+# ------------------------------------------- the table pass, per column
+
+def _table(idx, vals, ops, size):
+    """``_scatter_tables`` jitted, as a group program traces it."""
+    idents = [dense._identity(op, v.dtype) for v, op in zip(vals, ops)]
+    fn = jax.jit(lambda i, *vs: dense._scatter_tables(
+        i, list(vs), list(ops), idents, size))
+    present, tables = fn(idx, *vals)
+    return np.asarray(present), [np.asarray(t) for t in tables]
+
+
+def _scattered(idx, v, size):
+    """A plain scatter-add of one float column."""
+    out = jnp.zeros((size,) + v.shape[1:], v.dtype).at[idx].add(v)
+    return np.asarray(out)
+
+
+@pytest.mark.parametrize("size,d", [
+    (2, 1), (2, 128), (17, 8), (17, 128), (65, 1), (65, 8), (65, 128),
+    (128, 8), (128, 128),
+])
+def test_small_table_vector_sums_match_float64_oracle(size, d):
+    """A float32 vector column and a scalar column into a small table,
+    the last slot the drop lane: the vector sums by scatter and the
+    counts by the compare agree with a float64 oracle, ``present``
+    exactly. The coordinates are positive, as the k-means points are,
+    so that float32's own rounding of a sum, in any order, stays under
+    the tolerance (a sum that cancels to near 0 would not)."""
+    rng = np.random.RandomState(size * 1000 + d)
+    n = 3000
+    idx = rng.randint(0, size, n).astype(np.int32)
+    x = rng.rand(n, d).astype(np.float32)
+    w = rng.rand(n).astype(np.float32)
+    assert dense.table_column_lowering(size, (d,)) == "scatter"
+    assert dense.table_column_lowering(size, ()) == "compare"
+    present, (sums, counts) = _table(idx, [x, w], ["add", "add"], size)
+    kept = size - 1
+    want = np.zeros((size, d))
+    np.add.at(want, idx, x.astype(np.float64))
+    want_w = np.zeros(size)
+    np.add.at(want_w, idx, w.astype(np.float64))
+    np.testing.assert_array_equal(
+        present, np.bincount(idx, minlength=size) > 0)
+    assert sums.shape == (size, d) and sums.dtype == np.float32
+    np.testing.assert_allclose(sums[:kept], want[:kept], rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_allclose(counts[:kept], want_w[:kept], rtol=1e-5,
+                               atol=1e-5)
+
+
+def test_small_table_vector_sums_are_exact_to_the_bit():
+    """One row a slot: the table is each row's float32 value to the
+    last bit, over every exponent a row may have (a lowering that
+    rounds the rows on the way, as a bfloat16 pass would, fails)."""
+    rng = np.random.RandomState(25)
+    size, d = 65, 32
+    x = (rng.randn(size, d) * np.exp2(rng.randint(-60, 60, (size, d)))
+         ).astype(np.float32)
+    x[-1] = np.nan          # the drop lane's row: sliced off, never read
+    idx = np.arange(size, dtype=np.int32)
+    _, (sums,) = _table(idx, [x], ["add"], size)
+    np.testing.assert_array_equal(sums[:size - 1].view(np.uint32),
+                                  x[:size - 1].view(np.uint32))
+
+
+@pytest.mark.parametrize("value", [np.nan, np.inf, -np.inf])
+def test_non_finite_row_gets_the_scatter_paths_table(value):
+    """A NaN or inf in one kept row stays in its own slot and element
+    (where a one-hot product would carry it to every slot: 0 * NaN is
+    NaN), and the table is a plain scatter's, bit for bit."""
+    rng = np.random.RandomState(21)
+    size, n, d = 65, 4096, 16
+    idx = rng.randint(0, size - 1, n).astype(np.int32)
+    x = rng.randn(n, d).astype(np.float32)
+    row, col = 123, 5
+    x[row, col] = value
+    _, (sums,) = _table(idx, [x], ["add"], size)
+    np.testing.assert_array_equal(sums, _scattered(idx, x, size))
+    bad = np.argwhere(~np.isfinite(sums))
+    np.testing.assert_array_equal(bad, [[idx[row], col]])
+
+
+def test_non_finite_drop_lane_row_stays_out_of_the_kept_slots():
+    """A NaN in a row sent to the drop lane (masked out, out of range:
+    the caller slices the lane off) reaches no kept slot, and the kept
+    slots hold the sums of the kept rows."""
+    rng = np.random.RandomState(22)
+    size, n, d = 65, 4096, 16
+    idx = rng.randint(0, size, n).astype(np.int32)
+    x = rng.randn(n, d).astype(np.float32)
+    dropped = np.flatnonzero(idx == size - 1)
+    x[dropped[0], 3] = np.nan
+    x[dropped[1], 7] = np.inf
+    _, (sums,) = _table(idx, [x], ["add"], size)
+    assert np.isfinite(sums[:size - 1]).all()
+    np.testing.assert_allclose(sums[:size - 1],
+                               _scattered(idx, x, size)[:size - 1],
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("op,dtype", [
+    ("add", np.float32), ("max", np.float32), ("min", np.float32),
+    ("add", np.int32), ("max", np.int32),
+])
+def test_small_table_vector_columns_scatter(op, dtype):
+    """A vector column of a small table takes its scatter, beside a
+    scalar column's compare, and stays exact (the float32 sums are
+    small integers here, so any order of addition gives them)."""
+    rng = np.random.RandomState(23)
+    size, n, d = 17, 2000, 4
+    idx = rng.randint(0, size, n).astype(np.int32)
+    v = np.round(rng.randn(n, d) * 100).astype(dtype)
+    c = np.ones(n, np.int32)
+    assert dense.table_column_lowering(size, (d,)) == "scatter"
+    ops = [op, "add"]
+    idents = [dense._identity(o, t.dtype) for o, t in zip(ops, (v, c))]
+    text = jax.jit(lambda i, a, b: dense._scatter_tables(
+        i, [a, b], ops, idents, size)).lower(idx, v, c).as_text()
+    assert text.count('"stablehlo.scatter"(') == 1
+    assert "dot_general" not in text
+    present, (table, counts) = _table(idx, [v, c], ops, size)
+    want = np.full((size, d), dense._identity(op, dtype), dtype)
+    ufunc = {"add": np.add, "max": np.maximum, "min": np.minimum}[op]
+    ufunc.at(want, idx, v)
+    np.testing.assert_array_equal(table, want)
+    np.testing.assert_array_equal(counts, np.bincount(idx, minlength=size))
+    np.testing.assert_array_equal(present, counts > 0)
+
+
+@pytest.mark.parametrize("size,shape,want", [
+    (65, (), "compare"),
+    (7, (), "compare"),
+    (dense.SMALL_TABLE, (), "compare"),
+    (65, (128,), "scatter"),
+    (2, (1,), "scatter"),
+    (128, (4, 2), "scatter"),
+    (129, (), "scatter"),
+    (129, (128,), "scatter"),
+])
+def test_table_column_lowering_choices(size, shape, want):
+    """The one source of truth for a column's lowering: the compare for
+    a scalar column of a table of at most ``SMALL_TABLE`` slots, a
+    scatter for a vector column and for every column of a larger
+    table."""
+    assert dense.table_column_lowering(size, shape) == want
+
+
+def test_small_vector_table_present_and_counts_take_no_scatter():
+    """The kmeans table's shape: a float32 vector and a float32 count
+    into 65 slots — ``present`` and the counts are the compare's, so the
+    one scatter is the vector sums'."""
+    rng = np.random.RandomState(24)
+    n = 1024
+    idx = rng.randint(0, 65, n).astype(np.int32)
+    x = rng.randn(n, 8).astype(np.float32)
+    w = np.ones(n, np.float32)
+    text = jax.jit(lambda i, a, b: dense._scatter_tables(
+        i, [a, b], ["add", "add"], [np.float32(0)] * 2, 65)).lower(
+        idx, x, w).as_text()
+    assert text.count('"stablehlo.scatter"(') == 1
+    assert "dot_general" not in text
+
+
 # -------------------------------------------------------------- dense fold
 
 def test_dense_fold_max_matches_oracle(mesh):

@@ -307,7 +307,7 @@ def test_program_cache_guards_recycled_fn_ids(mesh):
     s = bs.Map(bs.Const(8, np.arange(16, dtype=np.int32)),
                lambda x: x + 1)
     task = compile_mod.compile_slice(s)[0]
-    prog1, _ = ex._program(task, (8,))
+    prog1, _, _ = ex._program(task, (8,))
     assert len(ex._programs) == 1
     key = next(iter(ex._programs))
 
@@ -316,8 +316,8 @@ def test_program_cache_guards_recycled_fn_ids(mesh):
 
     dead = weakref.ref(_Tmp())  # dies immediately
     assert dead() is None
-    ex._programs[key] = ("stale", (dead,))
-    prog2, _ = ex._program(task, (8,))
+    ex._programs[key] = ("stale", ((dead,), []))
+    prog2, _, _ = ex._program(task, (8,))
     assert prog2 != "stale"
 
 

@@ -352,6 +352,68 @@ def test_waved_shuffle_feeds_the_merge_block_from_its_settles(ndev):
     assert first == second
 
 
+def test_table_record_sums_waves_slots_and_columns_by_lowering():
+    """``record_table``: one call a dispatched wave, from the tables
+    its program's trace recorded; sums since the session began, and an
+    op whose programs fill no table has no block."""
+    hub = telemetry_mod.TelemetryHub()
+    hub.record_wave_compute("map@x", 1, 0, 0.5)
+    wave = [(65, ("scatter", "compare"))]
+    hub.record_table("reduce@x", 1, wave)
+    hub.record_table("reduce@x", 1, wave)
+    hub.record_table("join@x", 2, [(9, ("compare",)),
+                                   (9, ("scatter", "compare"))])
+    ops = hub.summary()["ops"]
+    assert ops["reduce@x"]["table"] == {
+        "waves": 2, "slots": 130, "columns_compare": 2,
+        "columns_scatter": 2}
+    assert ops["join@x"]["table"] == {
+        "waves": 1, "slots": 18, "columns_compare": 2,
+        "columns_scatter": 1}
+    assert "table" not in ops["map@x"]
+    json.dumps(ops)
+
+
+@pytest.mark.parametrize("slots,shape,want", [
+    # The k-means round's table: float32 vectors and their counts.
+    (65, (16,), {"columns_scatter": 1, "columns_compare": 1}),
+    # TPC-H Q1's: six scalar sums into 6 keys and the drop lane.
+    (7, (), {"columns_scatter": 0, "columns_compare": 6}),
+])
+def test_dense_reduce_feeds_the_table_block_a_wave(slots, shape, want):
+    """A dense Reduce on the mesh: every wave that fills a table counts
+    its slots and each value column's lowering, as
+    ``dense.table_column_lowering`` picks it."""
+    rng = np.random.default_rng(slots)
+    n, K = 4096, slots - 1
+    keys = rng.integers(0, K, n).astype(np.int32)
+    if shape:
+        cols = (rng.random((n,) + shape, dtype=np.float32),
+                np.ones(n, np.float32))
+    else:
+        cols = tuple(rng.integers(0, 100, n).astype(np.int32)
+                     for _ in range(6))
+    sess = _mesh_session()
+    try:
+        res = sess.run(bs.Reduce(
+            bs.Const(4, keys, *cols),
+            lambda a, b: tuple(x + y for x, y in zip(a, b)),
+            dense_keys=K))
+        assert len(res.rows()) == K
+        blocks = [rec["table"] for rec in
+                  sess.telemetry_summary()["ops"].values()
+                  if "table" in rec]
+    finally:
+        sess.shutdown()
+    assert blocks
+    for t in blocks:
+        waves = t["waves"]
+        assert waves >= 1
+        assert t == {"waves": waves, "slots": slots * waves,
+                     "columns_scatter": 0,
+                     **{k: v * waves for k, v in want.items()}}
+
+
 # ---------------------------------------------- monitor hardening
 
 def test_raising_monitor_does_not_break_evaluation(capsys):
